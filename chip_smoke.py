@@ -1,0 +1,383 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process drives the training path through the entry points a user
+calls, at BERT-base's full width on random seeded weights, then gives
+serving's device arm a short leg:
+
+  A     seq 128, batch 64: the XLA dense attention arm, 10 LAMB steps.
+  mesh  with >= 4 devices: phase A's batch over make_mesh({"dp": 4}) and
+        over {"dp": 2, "tp": 2}; the dp=4 step-0 loss must match one chip.
+  B     seq 512, batch 32, remat, TPUMX_ATTENTION=flash: the Pallas flash
+        kernel on the normal path.
+  C     Server(TinyLM) on the fused paged-decode arm (a SMALL model: 2
+        layers, 512 wide — the only serving model there is), its tokens
+        compared with the XLA twin of the paged kernel on the same device.
+
+    python chip_smoke.py              # needs a TPU; exits non-zero without
+    python chip_smoke.py --tiny-cpu   # same control flow, toy sizes, CPU
+
+It exits non-zero if any phase raises or any check fails, and prints as its
+last line {"ok": true, "device": {...}} only when everything passed.  The
+times it prints are facts for CHANGES.md, not benchmark metrics.
+"""
+import argparse
+import importlib.metadata
+import json
+import logging
+import math
+import os
+import statistics
+import sys
+import time
+from unittest import mock
+
+SEED = 0
+# Phase A overfits ONE fixed batch, the example's own learn-signal
+# (examples/bert/pretrain.py): 10 steps at the example's overfit rate.
+STEPS_A, LR = 10, 1e-3
+# bf16 activations: two layouts of the same step differ by the order of
+# each matmul's partial sums, ~2^-9 relative per rounding, averaged over
+# ~1200 masked positions — far inside 0.05 on a loss of ~10.3, while a
+# shard that saw the wrong rows or weights moves the loss by whole units.
+MESH_LOSS_TOL = 0.05
+
+
+def check(ok, what):
+    if not ok:
+        raise AssertionError(what)
+    print(f"  ok: {what}")
+
+
+def env(**pins):
+    """Pin environment knobs for one phase (they are read at trace time)."""
+    return mock.patch.dict(os.environ, pins)
+
+
+class Compiles:
+    """Programs this process had XLA build, compiled or loaded from the
+    persistent cache (jax.monitoring's backend-compile event covers
+    both)."""
+
+    def __init__(self):
+        import jax.monitoring
+        self.n = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, duration, **kwargs):
+        if event.endswith("backend_compile_duration"):
+            self.n += 1
+
+
+def cache_entries(directory):
+    return len(os.listdir(directory)) \
+        if directory and os.path.isdir(directory) else 0
+
+
+def bert_config(tiny, seq_len):
+    from tpu_mx.models.bert import bert_base_config
+    if not tiny:
+        return bert_base_config(max_len=seq_len)
+    cfg = bert_base_config(vocab_size=1000, max_len=seq_len)
+    cfg.update(num_layers=2, units=128, hidden_size=512, num_heads=2)
+    return cfg
+
+
+def mlm_batch(cfg, batch, seq_len):
+    """One fixed synthetic MLM batch: the vocab head runs only on the 15%
+    masked positions, as in bench.py's BERT legs."""
+    import numpy as np
+    rng = np.random.RandomState(SEED)
+    tokens = rng.randint(4, cfg["vocab_size"], (batch, seq_len)).astype(
+        np.int32)
+    types = np.zeros((batch, seq_len), np.int32)
+    n_masked = max(1, int(0.15 * seq_len))
+    positions = np.stack([rng.choice(seq_len, n_masked, replace=False)
+                          for _ in range(batch)]).astype(np.int32)
+    labels = np.take_along_axis(tokens, positions, axis=1)
+    return tokens, types, positions, labels
+
+
+def train(tag, cfg, batch, seq_len, steps, compiles, remat=False,
+          mesh_axes=None, presharded=False):
+    """Build BERT + LAMB + CompiledTrainStep the way examples/bert/
+    pretrain.py and bench.py do and take `steps` steps on one fixed batch.
+    Returns (losses, step)."""
+    import jax
+    import tpu_mx as mx
+    from tpu_mx import gluon, nd
+    from tpu_mx.models.bert import (BERTModel, bert_data_specs,
+                                    bert_sharding_rules)
+    from tpu_mx.parallel import (CompiledTrainStep, NamedSharding, P,
+                                 make_mesh)
+
+    class MLMLoss(gluon.loss.Loss):
+        def __init__(self, **kw):
+            super().__init__(weight=None, batch_axis=0, **kw)
+            self._ce = gluon.loss.SoftmaxCrossEntropyLoss()
+
+        def hybrid_forward(self, F, logits, labels):
+            vocab = logits.shape[-1]
+            return F.mean(self._ce(F.reshape(logits, shape=(-1, vocab)),
+                                   F.reshape(labels, shape=(-1,))))
+
+    mx.random.seed(SEED)  # same weights and dropout keys in every layout
+    net = BERTModel(cfg, dtype="bfloat16", remat=remat)
+    net.initialize()
+    tokens, types, positions, labels = mlm_batch(cfg, batch, seq_len)
+    net.finalize_shapes(nd.array(tokens[:1]), nd.array(types[:1]), None,
+                        nd.array(positions[:1]))
+    opt = mx.optimizer.create("lamb", learning_rate=LR,
+                              multi_precision=True)
+    mesh = rules = data_specs = None
+    if mesh_axes:
+        mesh = make_mesh(mesh_axes, devices=jax.devices()[:4])
+        rules = bert_sharding_rules()
+        # bert_data_specs() names (tokens, token_types, labels); the MLM
+        # batch adds valid_length (None: no leaves) and the masked
+        # positions, which shard like the labels they select
+        tok, typ, lab = bert_data_specs()
+        data_specs = (tok, typ, P(), lab, lab)
+    step = CompiledTrainStep(net, MLMLoss(), opt, mesh=mesh, rules=rules,
+                             data_specs=data_specs)
+    if presharded:
+        put = lambda a: jax.device_put(a, NamedSharding(mesh, P("dp")))
+        args = (put(tokens), put(types), None, put(positions), put(labels))
+        on = {s.device for s in args[0].addressable_shards}
+        check(len(on) == 4, f"{tag}: batch shards sit on 4 devices")
+    else:
+        # the default user path: nd.array puts the batch on device 0 (with
+        # a mesh, the step's in_shardings reshard it every step)
+        args = (nd.array(tokens), nd.array(types), None,
+                nd.array(positions), nd.array(labels))
+
+    losses, seconds, warm = [], [], None
+    for i in range(steps):
+        t0 = time.perf_counter()
+        loss = step.step(*args)
+        loss.wait_to_read()                 # block_until_ready ...
+        losses.append(float(loss.asscalar()))   # ... and a host fetch
+        seconds.append(time.perf_counter() - t0)
+        if i == 0:
+            warm = compiles.n
+    steady = statistics.median(seconds[1:])
+    print(f"  {tag}: first step {seconds[0]:.1f} s (compile + run), steady "
+          f"step {steady * 1e3:.1f} ms (median of {steps - 1})")
+    print(f"  {tag}: losses " + " ".join(f"{l:.3f}" for l in losses))
+    check(all(math.isfinite(l) for l in losses), f"{tag}: every loss finite")
+    check(abs(losses[0] - math.log(cfg["vocab_size"])) < 1.0,
+          f"{tag}: step-0 loss {losses[0]:.3f} within 1 of ln(vocab) = "
+          f"{math.log(cfg['vocab_size']):.2f} (random-init MLM)")
+    check(compiles.n == warm,
+          f"{tag}: {compiles.n - warm} compilations after warm-up")
+    return losses, step
+
+
+def state_platforms(step):
+    import jax
+    leaves = jax.tree_util.tree_leaves(
+        (step.values, step.masters, step.opt_states))
+    return {next(iter(x.devices())).platform for x in leaves}
+
+
+def phase_a(tiny, platform, compiles):
+    from tpu_mx.parallel.ring_attention import dispatch_counts
+    seq_len, batch = 128, (8 if tiny else 64)
+    print(f"phase A: BERT seq {seq_len} batch {batch}, dense arm")
+    before = dict(dispatch_counts)
+    losses, step = train("A", bert_config(tiny, seq_len), batch, seq_len,
+                         STEPS_A, compiles)
+    check(sum(losses[-3:]) < sum(losses[:3]),
+          f"A: mean of last 3 losses below mean of first 3 over {STEPS_A} "
+          f"steps on one fixed batch at lr {LR} (memorization)")
+    check(dispatch_counts["xla_dense"] > before["xla_dense"]
+          and dispatch_counts["pallas_flash"] == before["pallas_flash"],
+          "A: attention dispatched to xla_dense (auto picks dense to kv 512)")
+    check(state_platforms(step) == {platform},
+          f"A: parameters, masters and optimizer state live on {platform}")
+    return losses
+
+
+def phase_mesh(tiny, platform, compiles, one_chip_losses):
+    import numpy as np
+    seq_len, batch, steps = 128, (8 if tiny else 64), 3
+    cfg = bert_config(tiny, seq_len)
+
+    def run(tag, axes, presharded):
+        print(f"phase mesh {tag}: phase A's batch over {axes}, batch "
+              + ("pre-sharded over dp" if presharded
+                 else "fed from device 0 (resharded by the step)"))
+        losses, step = train(tag, cfg, batch, seq_len, steps, compiles,
+                             mesh_axes=axes, presharded=presharded)
+        check(all(len(v.sharding.device_set) == 4
+                  for v in step.values.values()),
+              f"{tag}: every parameter's sharding spans 4 devices")
+        check(state_platforms(step) == {platform},
+              f"{tag}: parameters, masters and optimizer state on "
+              f"{platform}")
+        return step, abs(losses[0] - one_chip_losses[0])
+
+    _, diff = run("dp4", {"dp": 4}, True)
+    check(diff <= MESH_LOSS_TOL,
+          f"dp4: step-0 loss differs from one chip by {diff:.2e} <= "
+          f"{MESH_LOSS_TOL} (bf16 tolerance)")
+
+    step, diff = run("dp2xtp2", {"dp": 2, "tp": 2}, False)
+    print(f"  dp2xtp2: step-0 loss differs from one chip by {diff:.2e}")
+    # under tp each device holds its share of the parameter bytes and no
+    # more: replicated tensors whole, tp-sharded tensors halved
+    held = {}
+    for v in step.values.values():
+        for s in v.addressable_shards:
+            held[s.device] = held.get(s.device, 0) + s.data.nbytes
+    share = sum(int(np.prod(v.sharding.shard_shape(v.shape)))
+                * v.dtype.itemsize for v in step.values.values())
+    total = sum(v.nbytes for v in step.values.values())
+    check(set(held.values()) == {share} and share < total,
+          f"dp2xtp2: each of {len(held)} devices holds {share / 1e6:.1f} "
+          f"MB of {total / 1e6:.1f} MB of parameters")
+
+
+def phase_b(tiny, platform, compiles):
+    from tpu_mx.parallel.ring_attention import dispatch_counts
+    seq_len, batch, steps = 512, (2 if tiny else 32), 4
+    # off the chip the dispatch declines the kernel (interpret mode is
+    # correctness-only), so the tiny CPU mode expects the dense arm here
+    want, other = (("pallas_flash", "xla_dense") if platform == "tpu"
+                   else ("xla_dense", "pallas_flash"))
+    print(f"phase B: BERT seq {seq_len} batch {batch}, remat, "
+          f"TPUMX_ATTENTION=flash, expecting {want}")
+    warnings = []
+    handler = logging.Handler(logging.WARNING)
+    handler.emit = warnings.append
+    logger = logging.getLogger("tpu_mx.parallel.ring_attention")
+    logger.addHandler(handler)
+    before = dict(dispatch_counts)
+    try:
+        with env(TPUMX_ATTENTION="flash"):
+            train("B", bert_config(tiny, seq_len), batch, seq_len, steps,
+                  compiles, remat=True)
+    finally:
+        logger.removeHandler(handler)
+    check(dispatch_counts[want] > before[want]
+          and dispatch_counts[other] == before[other],
+          f"B: attention dispatched to {want}, not {other}")
+    check(not any("dense O(T^2) XLA fallback" in r.getMessage()
+                  for r in warnings),
+          "B: no dense-fallback warning fired")
+
+
+def phase_c(platform, compiles):
+    import jax
+    import numpy as np
+    from tpu_mx import tracing
+    from tpu_mx.serving import Server, TinyLM
+    on_chip = platform == "tpu"
+    print("phase C: Server(TinyLM 2 layers x 512 wide, 8 heads of 64), "
+          "fused paged decode — a small model, serving's only one")
+    model = TinyLM(vocab_size=128, embed_dim=512, num_heads=8, num_layers=2,
+                   seed=SEED)
+    prompts = [list(np.random.RandomState(SEED + n).randint(0, 128, size=n))
+               for n in (5, 17, 33, 9, 24, 48)]
+
+    def serve(tag, block_size, want_kernel, precision):
+        """All prompts to "done" through Server; returns their tokens."""
+        with jax.default_matmul_precision(precision):
+            t0 = time.perf_counter()
+            srv = Server(model, block_size=block_size, max_batch=8)
+            built, warm = time.perf_counter() - t0, compiles.n
+            event = [e for e in tracing.snapshot()
+                     if e["event"] == "serve.decode_path"][-1]["data"]
+            check((event["path"], event["storage"], event["fused"])
+                  == ("paged", "device", True),
+                  f"C {tag}: decode path paged, storage device, fused")
+            check(srv.engine.jax_model.use_kernel is want_kernel,
+                  f"C {tag}: fused step built with use_kernel="
+                  f"{want_kernel}")
+            t0 = time.perf_counter()
+            reqs = [srv.submit(p, max_new_tokens=16) for p in prompts]
+            srv.run_until_idle()
+            served = time.perf_counter() - t0
+        check(all(r.state == "done" and len(r.tokens) == 16 for r in reqs),
+              f"C {tag}: {len(reqs)} requests done, 16 tokens each")
+        n_tok = sum(len(r.tokens) for r in reqs)
+        print(f"  C {tag}: server built and warmed in {built:.1f} s, "
+              f"{n_tok} tokens in {served:.2f} s, compilations after "
+              f"warm-up {compiles.n - warm}")
+        return [list(r.tokens) for r in reqs]
+
+    with env(TPUMX_PAGED_DECODE="1", TPUMX_FUSED_DECODE="1"):
+        # the normal path: the kernel's own gate takes head_dim 64 and
+        # block 16 on a TPU; off the chip the same knobs give the XLA twin
+        serve("normal path", 16, on_chip, "default")
+        # f32 matmuls run as bf16 passes on the MXU by default, and the
+        # kernel's VPU dots do not, so tokens are compared at "highest".
+        # Block size 12 fails the kernel's sublane gate, which is how the
+        # public knobs select its XLA twin (window_walk, the body of
+        # paged_attention_reference) on the same device; block size does
+        # not enter the math.
+        kernel = serve("normal path at highest", 16, on_chip, "highest")
+        twin = serve("XLA twin at highest", 12, False, "highest")
+    check(kernel == twin, "C: kernel arm and XLA twin emit the same tokens")
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tiny-cpu", action="store_true",
+                    help="toy sizes on the CPU: checks this script's "
+                         "control flow, NOT the chip")
+    args = ap.parse_args()
+
+    # Gate first, before any model code: jax falls back to the CPU with
+    # only a warning when libtpu cannot start, and every dispatch in the
+    # tree would then quietly take its off-chip arm and the run would pass.
+    import jax
+    import jaxlib
+    if args.tiny_cpu:
+        jax.config.update("jax_platforms", "cpu")
+    devices = jax.devices()
+    platform = devices[0].platform
+    if args.tiny_cpu:
+        print("NOT A CHIP RUN: --tiny-cpu runs toy sizes on the CPU to "
+              "check this script's control flow")
+    elif platform != "tpu":
+        sys.exit(f"chip_smoke: no accelerator — jax.devices()[0].platform "
+                 f"is {platform!r}, not 'tpu' (JAX_PLATFORMS="
+                 f"{os.environ.get('JAX_PLATFORMS')!r}); --tiny-cpu runs "
+                 "the control flow on the CPU")
+    # the program: a directory that holds only this script stops here,
+    # before a chip run has printed anything
+    from tpu_mx.runtime import enable_shared_compilation_cache
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    device = {"platform": platform, "kind": devices[0].device_kind,
+              "count": len(devices)}
+    print(f"platform={platform} device_kind={device['kind']!r} "
+          f"count={device['count']} jax={jax.__version__} "
+          f"jaxlib={jaxlib.__version__} libtpu={libtpu}")
+
+    cache_dir = enable_shared_compilation_cache()
+    entries0 = cache_entries(cache_dir)
+    print(f"compile cache: {cache_dir} ({entries0} entries)")
+    compiles = Compiles()
+
+    t0 = time.perf_counter()
+    losses = phase_a(args.tiny_cpu, platform, compiles)
+    if len(devices) >= 4:
+        phase_mesh(args.tiny_cpu, platform, compiles, losses)
+    else:
+        print(f"phase mesh: skipped, {len(devices)} device(s) < 4")
+    phase_b(args.tiny_cpu, platform, compiles)
+    phase_c(platform, compiles)
+    print(f"all phases passed in {time.perf_counter() - t0:.0f} s; "
+          f"{compiles.n} compilations; compile cache {cache_dir}: "
+          f"{entries0} -> {cache_entries(cache_dir)} entries")
+    result = {"ok": True, "device": device}
+    if args.tiny_cpu:
+        result["tiny_cpu"] = True
+    print(json.dumps(result))
+
+
+if __name__ == "__main__":
+    main()

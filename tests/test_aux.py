@@ -339,12 +339,40 @@ def test_speedometer_and_do_checkpoint(tmp_path, caplog):
         np.testing.assert_allclose(arg2[k].asnumpy(), args[k].asnumpy())
 
 
-def test_shared_compilation_cache_env_gate(monkeypatch, tmp_path):
+def test_shared_compilation_cache_env_gate(monkeypatch):
     """enable_shared_compilation_cache: one env knob disables the cache
-    for ALL on-chip tools; enabled path points at the repo .jax_cache."""
+    for ALL on-chip tools."""
     from tpu_mx import runtime
     monkeypatch.setenv("BENCH_COMPILE_CACHE", "0")
     assert runtime.enable_shared_compilation_cache() is None
-    monkeypatch.setenv("BENCH_COMPILE_CACHE", "1")
-    d = runtime.enable_shared_compilation_cache()
-    assert d is not None and d.endswith(".jax_cache")
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_shared_compilation_cache_placement(tmp_path, from_env):
+    """Where JAX_COMPILATION_CACHE_DIR is set the cache directory is the
+    environment's (jax reads it at import; the program sets none in code);
+    unset, it is the fixed <checkout>/.jax_cache.  A fresh interpreter,
+    because jax reads the variable once, at import."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    env.pop("BENCH_COMPILE_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "elsewhere")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\n"
+         "from tpu_mx import runtime\n"
+         "d = runtime.enable_shared_compilation_cache()\n"
+         "print(d)\n"
+         "print(jax.config.jax_compilation_cache_dir)\n"
+         "print(jax.config.jax_persistent_cache_min_entry_size_bytes)\n"],
+        env=env, capture_output=True, text=True, timeout=120, cwd=tmp_path)
+    assert out.returncode == 0, out.stderr[-2000:]
+    returned, configured, min_bytes = out.stdout.split()
+    want = str(tmp_path / "elsewhere") if from_env \
+        else os.path.join(repo, ".jax_cache")
+    assert returned == configured == want
+    assert min_bytes == "0"  # thresholds applied on both branches

@@ -830,3 +830,29 @@ def test_device_prefetch_normalize_nchw_axis(rec_file):
     a1 = it_f32.next().data[0].asnumpy()
     a2 = it_u8.next().data[0].asnumpy()
     np.testing.assert_allclose(a1, a2, atol=1e-5)
+
+
+def test_native_library_keyed_by_content(tmp_path, monkeypatch):
+    """The built library's name carries a hash of the source and the
+    compiler command line: a second build from the same source lands on
+    the same name, edited source on another, and a foreign .so under the
+    old unkeyed name (e.g. copied in from another machine) is never
+    loaded."""
+    import ctypes
+    from tpu_mx import lib
+    src = os.path.abspath(lib._SRC)
+    in_tree = lib.ensure_built()
+    assert lib.build_key(src) in os.path.basename(in_tree)
+    edited = tmp_path / "edited.cpp"
+    with open(src, "rb") as f:
+        edited.write_bytes(f.read() + b"\n// edited\n")
+    assert lib.build_key(str(edited)) != lib.build_key(src)
+
+    monkeypatch.setattr(lib, "_LIB_DIR", str(tmp_path))
+    foreign = tmp_path / "libtpumx_io.so"
+    foreign.write_bytes(b"built for another machine")
+    rebuilt = lib.ensure_built()
+    assert os.path.dirname(rebuilt) == str(tmp_path)
+    assert os.path.basename(rebuilt) == os.path.basename(in_tree)
+    assert rebuilt != str(foreign)
+    ctypes.CDLL(rebuilt)

@@ -500,6 +500,28 @@ def test_compression_rejects_bad_configs():
                           gradient_compression={"type": "int8"})
 
 
+def test_data_specs_drop_axes_the_mesh_lacks():
+    """bert_data_specs() names dp × sp; on a dp × tp mesh the step drops
+    `sp` as apply_rules does for parameters instead of NamedSharding
+    raising "Resource axis: sp ... not found in mesh"."""
+    from tpu_mx.models.bert import bert_data_specs
+    from tpu_mx.parallel import CompiledTrainStep, P
+
+    net = nn.HybridSequential()
+    net.add(nn.Dense(4, in_units=8, flatten=False))
+    net.initialize()
+    net(nd.ones((1, 2, 8)))
+    step = CompiledTrainStep(net, gluon.loss.L2Loss(),
+                             mx.optimizer.create("sgd"),
+                             mesh=_mesh(dp=4, tp=2),
+                             data_specs=(bert_data_specs()[0],
+                                         P(("dp", "sp"))))
+    assert step._data_specs == (P("dp", None), P(("dp",)))
+    loss = step.step(nd.array(np.ones((8, 2, 8), np.float32)),
+                     nd.array(np.zeros((8, 2, 4), np.float32)))
+    assert np.isfinite(float(loss.asscalar()))
+
+
 def test_bert_masked_positions_match_full_logits():
     """masked_positions must equal gathering the full-T logits at those
     positions (the reference pretraining head contract) and train through

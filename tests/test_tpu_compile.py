@@ -1,0 +1,101 @@
+"""Compile the Pallas kernels for a TPU v5e without having one.
+
+libtpu can describe a chip topology that is not attached
+(`jax.experimental.topologies.get_topology_desc`) and compile for its
+devices: the real XLA:TPU and Mosaic compilers run, nothing executes.  So a
+kernel Mosaic refuses is caught here on the CPU, before chip time is spent;
+what only execution shows (numerics, placement) stays with the on-chip tier
+(tests/test_tpu_chip.py) and chip_smoke.py.
+
+The kernels pick interpret mode from `jax.default_backend()` at trace time;
+the fixture turns that off for the kernels under test, so what is lowered is
+what a TPU process lowers.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import pallas as pl
+from jax.sharding import SingleDeviceSharding
+
+from tpu_mx.kernels import flash_attention as fa
+from tpu_mx.kernels import paged_attention as pa
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One compile-only device of a v5e 2x2 host."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or one that cannot do this
+        pytest.skip("libtpu cannot make a compile-only v5e:2x2 topology: "
+                    f"{type(e).__name__}: {e}"[:300])
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices[0]
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Lower the real kernels, not their interpret-mode stand-ins."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    pa._kernel_call.cache_clear()
+    yield
+    pa._kernel_call.cache_clear()
+
+
+def _compile(fn, device, *shapes_dtypes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=SingleDeviceSharding(device))
+            for s, d in shapes_dtypes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _mosaic_calls(compiled):
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def test_mosaic_really_runs(v5e):
+    """A kernel Mosaic is known to refuse must fail here too — otherwise a
+    green run below proves nothing."""
+    def bad_kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...].reshape(128, 8)
+
+    bad = pl.pallas_call(
+        bad_kernel, out_shape=jax.ShapeDtypeStruct((128, 8), jnp.float32))
+    with pytest.raises(Exception, match="Mosaic failed to compile"):
+        _compile(bad, v5e, ((8, 128), jnp.float32))
+
+
+@pytest.mark.parametrize("masked_dropout", [False, True])
+def test_flash_fwd_bwd_compiles_for_v5e(v5e, mosaic, masked_dropout):
+    """BERT-base's flash shape (12 heads of 64, T=512, bf16), forward and
+    backward; the second case adds the key-padding mask and the in-kernel
+    dropout (TPU PRNG — no interpret lowering, so never traced on CPU)."""
+    b, h, t, d = 8, 12, 512, 64
+    qkv = ((b, h, t, d), jnp.bfloat16)
+    assert fa.supported(qkv[0], qkv[1], kv_len=t,
+                        dropout_rate=0.1 if masked_dropout else 0.0)
+
+    def loss(q, k, v, valid_length, seed):
+        kw = dict(valid_length=valid_length, dropout_rate=0.1,
+                  dropout_seed=seed) if masked_dropout else {}
+        return fa.mha_flash_attention(q, k, v, **kw).astype(
+            jnp.float32).sum()
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e, qkv, qkv,
+                        qkv, ((b,), jnp.int32), ((1,), jnp.int32))
+    assert _mosaic_calls(compiled) >= 3  # forward, dq, dk/dv
+
+
+@pytest.mark.parametrize("tq", [1, 4])
+def test_paged_decode_compiles_for_v5e(v5e, mosaic, tq):
+    """The paged decode kernel at a decoder-sized geometry (16 heads of
+    128, bf16 pool): one-token decode and a 4-wide draft window."""
+    b, h, d, nb, block_size, num_blocks = 8, 16, 128, 8, 16, 64
+    assert pa.supported(d, jnp.bfloat16, block_size)
+    pool = ((num_blocks, block_size, h, d), jnp.bfloat16)
+    compiled = _compile(pa.paged_attention, v5e,
+                        ((b, tq, h, d), jnp.bfloat16), pool, pool,
+                        ((b, nb), jnp.int32), ((b,), jnp.int32))
+    assert _mosaic_calls(compiled) >= 1

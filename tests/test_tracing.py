@@ -117,10 +117,17 @@ def test_span_endpoints_fill_seconds():
     assert rec["data"]["seconds"] == pytest.approx(0.25)
 
 
-def test_events_merge_into_profiler_with_qualified_names():
+def test_events_merge_into_profiler_with_qualified_names(tmp_path,
+                                                         monkeypatch):
     """Chrome-trace merge: the span name carries the categorical field
     — five phases must not collapse into one aggregate row."""
     from tpu_mx import profiler
+    # the session's chrome trace and XLA trace go under tmp_path, not
+    # the working directory
+    monkeypatch.setitem(profiler._state, "filename",
+                        str(tmp_path / "profile.json"))
+    monkeypatch.setitem(profiler._state, "trace_dir",
+                        str(tmp_path / "profile_xla_trace"))
     profiler.set_state("run")
     try:
         t0 = time.perf_counter()

@@ -34,9 +34,6 @@ sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
-# exported for tpu_watch's done-predicate (the drift-proofing pattern:
-# hand-maintained copies of a tool's coverage once cost a 90-min rerun
-# loop); module top stays stdlib-only so the watcher can import it
 DEFAULT_LENS = (128, 256, 512, 1024)
 
 

@@ -30,7 +30,6 @@ sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
-# exported for tpu_watch's done-predicate (drift-proofing)
 ARMS = ("f32", "bf16", "int8")
 
 

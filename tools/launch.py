@@ -22,8 +22,14 @@ Env protocol handed to each worker (mirrors DMLC_* in spirit):
     TPUMX_NUM_PROC      world size
     TPUMX_PROC_ID       this process's rank
 A worker calls `tpu_mx.kvstore.dist_init()` (or jax.distributed.initialize
-directly) to join.  For CPU-simulated multi-worker tests the spawned
-processes default to the CPU backend with JAX_PLATFORMS=cpu.
+directly) to join.
+
+Local mode is a CPU simulation of a multi-worker job: a chip belongs to one
+process at a time, N processes on one machine would each open every chip,
+and the launcher binds no rank to a chip.  Local workers therefore run with
+JAX_PLATFORMS=cpu, and a JAX_PLATFORMS that names an accelerator is refused
+with more than one local worker.  Real multi-host jobs go through
+`--launcher ssh`, one process per host.
 
 Elastic fleets (`--supervise`, ISSUE 17): the launcher doubles as the
 fleet CONTROLLER.  It opens membership epoch 1 admitting ranks 0..N-1,
@@ -110,12 +116,33 @@ def build_ssh_commands(hosts, num_proc, coord, command, env_extra=(),
     return cmds
 
 
+def local_platform(num_workers, requested=None):
+    """The JAX_PLATFORMS value local workers run under (see the module
+    docstring): "cpu" unless the caller's environment names something
+    else, which is only honoured for a single worker (pure —
+    unit-testable)."""
+    if requested is None:
+        requested = os.environ.get("JAX_PLATFORMS", "")
+    names = {p.strip().lower() for p in requested.split(",") if p.strip()}
+    if not names:
+        return "cpu"
+    if names != {"cpu"} and num_workers > 1:
+        raise SystemExit(
+            f"launch.py: JAX_PLATFORMS={requested!r} with {num_workers} "
+            "local workers — local mode is a CPU simulation: every local "
+            "process would open every chip, and a chip belongs to one "
+            "process.  Leave JAX_PLATFORMS unset (or cpu), or use "
+            "--launcher ssh with one process per host.")
+    return requested
+
+
 def launch_local(args, coord):
+    platform = local_platform(args.num_workers)
     procs = []
     for rank in range(args.num_workers):
         env = dict(os.environ)
         env.update(worker_env(coord, args.num_workers, rank, args.env))
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = platform
         procs.append(subprocess.Popen(args.command, env=env))
     return procs
 
@@ -123,9 +150,12 @@ def launch_local(args, coord):
 def _import_fleet():
     """Import the fleet runtime into the LAUNCHER process.  tools/ is not a
     package, so put the repo root on sys.path; force the CPU backend before
-    tpu_mx pulls in jax (the launcher must never grab an accelerator the
-    workers need)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    importing tpu_mx, which creates its global PRNG key — and with it the
+    backend client — at import (the launcher must never hold an
+    accelerator the workers need).  Through jax.config, so the workers'
+    environment is left as it was."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
@@ -149,6 +179,7 @@ def supervise(args, coord):
     """Fleet-supervising local tracker: spawn N workers under the
     membership-epoch protocol, evict/restart/admit on churn, degrade when
     a worker's restart budget runs out.  Returns the process exit code."""
+    platform = local_platform(args.num_workers)
     fleet_mod, fleet_obs, _telemetry, _tracing = _import_fleet()
     fleet_dir = args.fleet_dir or tempfile.mkdtemp(prefix="tpumx_fleet_")
     fleet = fleet_mod.Fleet(fleet_dir, member=None, controller=True,
@@ -163,7 +194,7 @@ def supervise(args, coord):
     def spawn(rank, *, fresh=False):
         env = dict(os.environ)
         env.update(worker_env(coord, args.num_workers, rank, args.env))
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = platform
         env[fleet_mod.ENV_DIR] = fleet_dir
         env[fleet_mod.ENV_MEMBER] = str(rank)
         env[fleet_mod.ENV_LEASE] = str(args.lease)

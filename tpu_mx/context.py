@@ -57,10 +57,7 @@ class Context:
                     f"device id {self.device_id} out of range ({len(devs)} accelerator(s))"
                 )
             return devs[self.device_id]
-        try:
-            return jax.local_devices(backend="cpu")[self.device_id]
-        except RuntimeError:
-            return jax.local_devices()[0]  # CPU backend absent: use default
+        return jax.local_devices(backend="cpu")[self.device_id]
 
     # -- `with ctx:` ---------------------------------------------------------
     def __enter__(self):

@@ -51,10 +51,7 @@ def _to_ctx_device(data, ctx):
     """Place `data` on ctx's device if it isn't already there."""
     if ctx is None:
         return data
-    try:
-        dev = ctx.jax_device()
-    except RuntimeError:
-        return data
+    dev = ctx.jax_device()
     try:
         cur = list(data.devices())
         if len(cur) == 1 and cur[0] == dev:

@@ -168,14 +168,16 @@ fix = globals()["trunc"]  # numpy fix == round toward zero (jnp.fix removed)
 def frexp(x):
     """Mantissa/exponent decomposition with a DIFFERENTIABLE mantissa.
 
-    jnp.frexp is built from bitwise ops, so d(mantissa)/dx is silently
-    zero even in raw jax.  The exponent is piecewise constant in x, so
-    the true derivative is ``d(m)/dx = 2**-e``; it is attached
-    STRAIGHT-THROUGH: the returned VALUES are exactly jnp.frexp's bits
-    (the gradient path contributes an exact zero, clamped so inf/nan
-    inputs cannot leak a nan through ``inf - inf``), while the gradient
-    flows via ``x * 2**-e`` computed as two half-power scalings so
-    neither factor overflows across the full exponent range.  Subnormal
+    The exponent is piecewise constant in x, so the true derivative is
+    ``d(m)/dx = 2**-e``; it is attached STRAIGHT-THROUGH: the returned
+    VALUES are exactly jnp.frexp's bits (the gradient path contributes an
+    exact zero, clamped so inf/nan inputs cannot leak a nan through
+    ``inf - inf``), while the gradient flows via ``x * 2**-e`` computed
+    as two half-power scalings so neither factor overflows across the
+    full exponent range.  jnp.frexp's own mantissa derivative is cut
+    (jax 0.9 gives it one, with a derivative of 1 at inf; earlier
+    versions gave none), so the straight-through term is the only
+    gradient.  Subnormal
     inputs follow the backend's flush-to-zero arithmetic — divergence
     #26 in docs/DIVERGENCES.md."""
     import jax as _jax
@@ -191,7 +193,8 @@ def frexp(x):
         # zero (not nan) straight-through delta for inf/nan inputs: the
         # value must stay m_exact's bits there, with no gradient
         scaled = _jnp.where(_jnp.isfinite(scaled), scaled, 0)
-        m = m_exact + (scaled - _jax.lax.stop_gradient(scaled))
+        m = _jax.lax.stop_gradient(m_exact) \
+            + (scaled - _jax.lax.stop_gradient(scaled))
         return m, e
     return _ops._apply(call, [x], "frexp")
 

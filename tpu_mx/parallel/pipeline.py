@@ -58,7 +58,6 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, axis_name="pp",
     B = x.shape[0]
     if B % M:
         raise ValueError(f"batch {B} not divisible into {M} microbatches")
-    from jax.experimental.shard_map import shard_map
 
     xs = x.reshape((M, B // M) + x.shape[1:])
     dspec = tuple(data_spec) if data_spec is not None else ()
@@ -67,8 +66,8 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, axis_name="pp",
 
     body = functools.partial(_pipeline_body, stage_fn=stage_fn,
                              axis_name=axis_name, n_stages=S, n_micro=M)
-    fn = shard_map(body, mesh=mesh, in_specs=(p_spec, x_spec),
-                   out_specs=x_spec, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(p_spec, x_spec),
+                       out_specs=x_spec, check_vma=False)
     out = fn(stacked_params, xs)
     return out.reshape((B,) + out.shape[2:])
 

@@ -240,7 +240,6 @@ def ring_attention(q, k, v, mesh, axis_name="sp", causal=False,
     bias: (B|1, H|1, T, T) additive attention bias (ALiBi, relative
     position, …) — rows shard with q over `axis_name`, columns stay whole
     and are sliced per ring step to match the rotating K block."""
-    from jax.experimental.shard_map import shard_map
 
     def present(ax):
         return ax in mesh.axis_names
@@ -273,13 +272,13 @@ def ring_attention(q, k, v, mesh, axis_name="sp", causal=False,
               spec[1] if biased and bias_arr.shape[1] > 1 else None,
               spec[2], None)
     key_axes = tuple(ax for ax in spec if ax is not None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_body, axis_name=axis_name, causal=causal,
                           scale=scale, rate=float(dropout_rate),
                           masked=masked, dropped=dropped, biased=biased,
                           key_axes=key_axes, step_chunk=step_chunk),
         mesh=mesh, in_specs=(spec, spec, spec, vspec, P(None), bspec),
-        out_specs=spec, check_rep=False)
+        out_specs=spec, check_vma=False)
     return fn(q, k, v, valid, seed, bias_arr)
 
 

@@ -64,15 +64,24 @@ def _shape_signature(raw):
     return ";".join(parts)
 
 
+def _drop_absent_axes(spec, mesh):
+    """`spec` without the axes `mesh` lacks, so one set of rules or data
+    specs written for dp×tp×sp serves every smaller mesh."""
+    def keep(ax):
+        if isinstance(ax, tuple):
+            return tuple(a for a in ax if a in mesh.axis_names) or None
+        return ax if ax in mesh.axis_names else None
+    return tuple(keep(ax) for ax in spec)
+
+
 def apply_rules(name, shape, rules, mesh):
     """First matching (regex → PartitionSpec) rule wins; axes not in the mesh
     are dropped from the spec; default replicated."""
     if rules:
         for pattern, spec in rules:
             if re.search(pattern, name):
-                cleaned = tuple(
-                    (ax if (ax is not None and ax in mesh.axis_names) else None)
-                    for ax in spec) if mesh is not None else ()
+                cleaned = _drop_absent_axes(spec, mesh) \
+                    if mesh is not None else ()
                 # drop trailing Nones beyond rank
                 cleaned = cleaned[:len(shape)]
                 return P(*cleaned)
@@ -91,7 +100,8 @@ class CompiledTrainStep:
     optimizer  — tpu_mx optimizer (its pure update_core is traced in)
     mesh       — jax.sharding.Mesh or None
     rules      — [(regex, PartitionSpec)] parameter sharding rules
-    data_specs — PartitionSpecs for the batch inputs (default P('dp') on axis0)
+    data_specs — PartitionSpecs for the batch inputs (default P('dp') on
+                 axis0); axes the mesh lacks are dropped, as for rules
     n_loss_args — how many TRAILING step() args go to the loss instead of
                   the network forward (default 1: the label; 2 for e.g.
                   (label, sample_weight) losses)
@@ -150,6 +160,11 @@ class CompiledTrainStep:
         self._t = 0
         self._specs = {k: apply_rules(k, v.shape, rules, mesh)
                        for k, v in self.values.items()}
+        # like the rules: axes the mesh lacks are dropped, so
+        # bert_data_specs() (dp × sp) serves a dp or dp × tp mesh
+        if data_specs and mesh is not None:
+            data_specs = tuple(P(*_drop_absent_axes(s, mesh))
+                               for s in data_specs)
         self._data_specs = data_specs
         self._donate = donate
         if n_loss_args < 1:
@@ -364,7 +379,6 @@ class CompiledTrainStep:
             the reduction is a psum of the QUANTIZED values (the EQuARX-
             style in-collective compression the reference could only do on
             the kvstore wire)."""
-            from jax.experimental.shard_map import shard_map
             from ..contrib.compression import (quantize_2bit_core,
                                                quantize_fp8_core,
                                                quantize_int8_core)
@@ -399,10 +413,10 @@ class CompiledTrainStep:
             gacc_arg = gacc if gacc is not None else \
                 {k: jnp.zeros((ndp,) + (1,) * diff_vals[k].ndim,
                               jnp.float32) for k in diff_keys}
-            fn = shard_map(
+            fn = jax.shard_map(
                 per_shard, mesh=mesh,
                 in_specs=(P(), P(), P("dp"), P("dp"), P()) + tuple(dspecs),
-                out_specs=(P(), P(), P("dp"), P()), check_rep=False)
+                out_specs=(P(), P(), P("dp"), P()), check_vma=False)
             return fn(diff_vals, const_vals, efs, gacc_arg, key, *batch)
 
         K = self._accum
@@ -531,7 +545,6 @@ class CompiledTrainStep:
             NO quantization here; both happen exactly once in the apply
             step (compress-once-per-update).  BN aux updates are pmean'd
             and applied every microbatch as usual."""
-            from jax.experimental.shard_map import shard_map
             diff_vals = {k: values[k] for k in diff_keys}
             const_vals = {k: v for k, v in values.items()
                           if k not in set(diff_keys)}
@@ -544,10 +557,10 @@ class CompiledTrainStep:
                     for k in diff_keys}
                 return loss, new_gacc, updates
 
-            sm = shard_map(
+            sm = jax.shard_map(
                 per_shard, mesh=mesh,
                 in_specs=(P(), P(), P("dp"), P()) + tuple(dspecs),
-                out_specs=(P(), P("dp"), P()), check_rep=False)
+                out_specs=(P(), P("dp"), P()), check_vma=False)
             loss, new_gacc, updates = sm(diff_vals, const_vals, gacc, key,
                                          *batch)
             new_vals = dict(values)

@@ -105,7 +105,6 @@ def ulysses_attention(q, k, v, mesh, axis_name="sp", causal=False,
     over `axis_name`; returns output with the same sharding.  Requires
     H % mesh.shape[axis_name] == 0 (raises otherwise — `attention()`
     falls back to ring for such models)."""
-    from jax.experimental.shard_map import shard_map
 
     def present(ax):
         # size-1 axes shard nothing — treat as absent so e.g. tp=1 meshes
@@ -143,11 +142,11 @@ def ulysses_attention(q, k, v, mesh, axis_name="sp", causal=False,
     bspec = P(spec[0] if biased and bias_arr.shape[0] > 1 else None,
               None, None, None)
     key_axes = tuple(ax for ax in (spec[0],) if ax is not None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_body, axis_name=axis_name, causal=causal,
                           rate=float(dropout_rate),
                           masked=masked, dropped=dropped, biased=biased,
                           key_axes=key_axes),
         mesh=mesh, in_specs=(spec, spec, spec, vspec, P(None), bspec),
-        out_specs=spec, check_rep=False)
+        out_specs=spec, check_vma=False)
     return fn(q, k, v, valid, seed, bias_arr)
