@@ -87,8 +87,8 @@ FLEET_SECTION_FORMAT = "tpu_mx-fleet-section-v1"
 
 #: the phases cross-rank attribution correlates (the host-side stations
 #: of the compiled train step, tracing.TRAIN_STEP_PHASES)
-ATTRIBUTION_PHASES = ("data_wait", "recompile", "dispatch",
-                      "loss_readback", "optimizer_update")
+ATTRIBUTION_PHASES = ("data_wait", "recompile", "rng_key", "dispatch",
+                      "optimizer_update", "record", "loss_readback")
 
 _RANK_JSONL = re.compile(r"^rank-(\d+)\.jsonl$")
 _RANK_EVENTS = re.compile(r"^rank-(\d+)-events\.json$")

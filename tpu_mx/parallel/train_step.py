@@ -36,6 +36,15 @@ __all__ = ["CompiledTrainStep", "fsdp_rules", "sharding_for", "apply_rules"]
 
 _logger = logging.getLogger(__name__)
 
+# jax.named_scope names inside the one XLA program (HLO metadata only): a
+# device trace splits the step by them (docs/observability.md; read by
+# benchmark/scopes.py).  Forward is train_step.grad/jvp(...), backward
+# train_step.grad/transpose(jvp(...)).
+STEP_SCOPES = ("train_step.grad", "train_step.grad_sync",
+               "train_step.grad_accum", "train_step.optimizer",
+               "train_step.fingerprint")
+_GRAD, _GRAD_SYNC, _GRAD_ACCUM, _OPTIMIZER, _FINGERPRINT = STEP_SCOPES
+
 
 def _fingerprint_on():
     """``TPUMX_FINGERPRINT`` gates the device-side SDC fingerprint
@@ -365,11 +374,13 @@ class CompiledTrainStep:
             once equivalence depends on it)."""
             key = jax.random.fold_in(key, jax.lax.axis_index("dp"))
             dat, lar = b_local[:-n_loss], b_local[-n_loss:]
-            (loss, updates), grads = jax.value_and_grad(
-                make_lfn(cv, key, dat, lar), has_aux=True)(dv)
-            loss = jax.lax.pmean(loss, "dp")
-            updates = {uk: jax.lax.pmean(uv, "dp")
-                       for uk, uv in updates.items()}
+            with jax.named_scope(_GRAD):
+                (loss, updates), grads = jax.value_and_grad(
+                    make_lfn(cv, key, dat, lar), has_aux=True)(dv)
+            with jax.named_scope(_GRAD_SYNC):
+                loss = jax.lax.pmean(loss, "dp")
+                updates = {uk: jax.lax.pmean(uv, "dp")
+                           for uk, uv in updates.items()}
             return loss, updates, grads
 
         def compressed_grads(diff_vals, const_vals, efs, key, batch,
@@ -398,7 +409,8 @@ class CompiledTrainStep:
                         # microbatch into the LOCAL accumulated mean; the
                         # quantized psum below is the update's only
                         # collective and only quantization
-                        g = g / K + gacc_l[k][0]
+                        with jax.named_scope(_GRAD_ACCUM):
+                            g = g / K + gacc_l[k][0]
                     ef = efs_l[k][0]
                     if ctype == "2bit":
                         deq, new_ef = quantize_2bit_core(g, ef, threshold)
@@ -406,7 +418,8 @@ class CompiledTrainStep:
                         deq, new_ef = quantize_fp8_core(g, ef)
                     else:
                         deq, new_ef = quantize_int8_core(g, ef)
-                    red[k] = jax.lax.psum(deq, "dp") / ndp
+                    with jax.named_scope(_GRAD_SYNC):
+                        red[k] = jax.lax.psum(deq, "dp") / ndp
                     new_efs[k] = new_ef[None]
                 return loss, red, new_efs, updates
 
@@ -429,9 +442,10 @@ class CompiledTrainStep:
             diff_vals = {k: values[k] for k in diff_keys}
             const_vals = {k: v for k, v in values.items()
                           if k not in set(diff_keys)}
-            (loss, updates), grads = jax.value_and_grad(
-                make_lfn(const_vals, key, data_args, loss_args),
-                has_aux=True)(diff_vals)
+            with jax.named_scope(_GRAD):
+                (loss, updates), grads = jax.value_and_grad(
+                    make_lfn(const_vals, key, data_args, loss_args),
+                    has_aux=True)(diff_vals)
             new_vals = dict(values)
             for k, v in updates.items():
                 if k in new_vals:
@@ -455,8 +469,9 @@ class CompiledTrainStep:
                 new_efs = efs
             if K > 1 and not compression:
                 # fold the final microbatch into the accumulated mean
-                grads = {k: grads[k].astype(jnp.float32) / K + gacc[k]
-                         for k in diff_keys}
+                with jax.named_scope(_GRAD_ACCUM):
+                    grads = {k: grads[k].astype(jnp.float32) / K + gacc[k]
+                             for k in diff_keys}
                 new_gacc = {k: jnp.zeros_like(v) for k, v in gacc.items()}
             elif K > 1:
                 # compression already folded gacc inside the shard_map
@@ -466,56 +481,56 @@ class CompiledTrainStep:
             new_vals = aux_vals  # starts from the BN-stat-updated copy
             new_masters = {}
             new_states = {}
-            for ks in fuse_groups:
-                is_mp = ks[0] in mp_keys
-                srcs = [masters[k] if is_mp else values[k] for k in ks]
-                flat_w = jnp.concatenate([s.ravel() for s in srcs])
-                flat_g = jnp.concatenate(
-                    [grads[k].astype(flat_w.dtype).ravel() for k in ks])
-                leaves0, st_def = jax.tree_util.tree_flatten(
-                    opt_states[ks[0]])
-                flat_state = jax.tree_util.tree_unflatten(st_def, [
-                    jnp.concatenate(
-                        [jax.tree_util.tree_flatten(opt_states[k])[0][i]
-                         .ravel() for k in ks])
-                    for i in range(len(leaves0))])
-                w, s = opt.update_core(
-                    flat_w, flat_g, flat_state, lr * lr_mults[ks[0]],
-                    base_wd * wd_mults[ks[0]], t)
-                s_leaves, s_def = jax.tree_util.tree_flatten(s)
-                off = 0
-                for k, src in zip(ks, srcs):
-                    n = src.size
-                    piece = w[off:off + n].reshape(src.shape)
-                    if is_mp:
-                        new_masters[k] = piece
-                    new_vals[k] = piece.astype(values[k].dtype)
-                    new_states[k] = jax.tree_util.tree_unflatten(
-                        s_def,
-                        [sl[off:off + n].reshape(src.shape)
-                         for sl in s_leaves])
-                    off += n
-            for k in diff_keys:
-                if k in fused_keys:
-                    continue
-                if k in mp_keys:
-                    # update in f32 master space; forward weight is a cast
+            with jax.named_scope(_OPTIMIZER):
+                for ks in fuse_groups:
+                    is_mp = ks[0] in mp_keys
+                    srcs = [masters[k] if is_mp else values[k] for k in ks]
+                    flat_w = jnp.concatenate([s.ravel() for s in srcs])
+                    flat_g = jnp.concatenate(
+                        [grads[k].astype(flat_w.dtype).ravel() for k in ks])
+                    leaves0, st_def = jax.tree_util.tree_flatten(
+                        opt_states[ks[0]])
+                    flat_state = jax.tree_util.tree_unflatten(st_def, [
+                        jnp.concatenate(
+                            [jax.tree_util.tree_flatten(opt_states[k])[0][i]
+                             .ravel() for k in ks])
+                        for i in range(len(leaves0))])
                     w, s = opt.update_core(
-                        masters[k], grads[k].astype(jnp.float32),
-                        opt_states[k], lr * lr_mults[k],
-                        base_wd * wd_mults[k], t)
-                    new_masters[k] = w
-                    new_vals[k] = w.astype(values[k].dtype)
-                else:
-                    # match the param dtype regardless of path (the K>1
-                    # fold and compression accumulate in f32)
-                    w, s = opt.update_core(values[k],
-                                           grads[k].astype(values[k].dtype),
-                                           opt_states[k],
-                                           lr * lr_mults[k],
-                                           base_wd * wd_mults[k], t)
-                    new_vals[k] = w.astype(values[k].dtype)
-                new_states[k] = s
+                        flat_w, flat_g, flat_state, lr * lr_mults[ks[0]],
+                        base_wd * wd_mults[ks[0]], t)
+                    s_leaves, s_def = jax.tree_util.tree_flatten(s)
+                    off = 0
+                    for k, src in zip(ks, srcs):
+                        n = src.size
+                        piece = w[off:off + n].reshape(src.shape)
+                        if is_mp:
+                            new_masters[k] = piece
+                        new_vals[k] = piece.astype(values[k].dtype)
+                        new_states[k] = jax.tree_util.tree_unflatten(
+                            s_def,
+                            [sl[off:off + n].reshape(src.shape)
+                             for sl in s_leaves])
+                        off += n
+                for k in diff_keys:
+                    if k in fused_keys:
+                        continue
+                    if k in mp_keys:
+                        # update in f32 master space; forward weight is a cast
+                        w, s = opt.update_core(
+                            masters[k], grads[k].astype(jnp.float32),
+                            opt_states[k], lr * lr_mults[k],
+                            base_wd * wd_mults[k], t)
+                        new_masters[k] = w
+                        new_vals[k] = w.astype(values[k].dtype)
+                    else:
+                        # match the param dtype regardless of path (the K>1
+                        # fold and compression accumulate in f32)
+                        w, s = opt.update_core(
+                            values[k], grads[k].astype(values[k].dtype),
+                            opt_states[k], lr * lr_mults[k],
+                            base_wd * wd_mults[k], t)
+                        new_vals[k] = w.astype(values[k].dtype)
+                    new_states[k] = s
             # device-side SDC fingerprint (ISSUE 20, parallel/integrity.py):
             # folded over the POST-UPDATE parameter tree INSIDE the same
             # program that applied it, read back beside the loss — the hot
@@ -525,7 +540,8 @@ class CompiledTrainStep:
             # (the overhead A/B's baseline arm).
             if _fingerprint_on():
                 from .integrity import device_fingerprint
-                fp = device_fingerprint(new_vals)
+                with jax.named_scope(_FINGERPRINT):
+                    fp = device_fingerprint(new_vals)
             else:
                 fp = jnp.uint32(0)
             return (new_vals, new_masters, new_states, new_efs, new_gacc,
@@ -535,8 +551,9 @@ class CompiledTrainStep:
             """Microbatch accumulate: grads/K into the f32 buffers, BN-stat
             aux updates applied, NO optimizer step."""
             loss, grads, new_vals = grads_and_updates(values, key, batch)
-            new_gacc = {k: gacc[k] + grads[k].astype(jnp.float32) / K
-                        for k in diff_keys}
+            with jax.named_scope(_GRAD_ACCUM):
+                new_gacc = {k: gacc[k] + grads[k].astype(jnp.float32) / K
+                            for k in diff_keys}
             return new_vals, new_gacc, loss
 
         def compressed_accum_fn(values, gacc, key, *batch):
@@ -552,9 +569,11 @@ class CompiledTrainStep:
 
             def per_shard(dv, cv, gacc_l, key, *b_local):
                 loss, updates, grads = shard_fwd_grads(dv, cv, key, b_local)
-                new_gacc = {
-                    k: gacc_l[k] + grads[k].astype(jnp.float32)[None] / K
-                    for k in diff_keys}
+                with jax.named_scope(_GRAD_ACCUM):
+                    new_gacc = {
+                        k: gacc_l[k]
+                        + grads[k].astype(jnp.float32)[None] / K
+                        for k in diff_keys}
                 return loss, new_gacc, updates
 
             sm = jax.shard_map(
@@ -584,6 +603,9 @@ class CompiledTrainStep:
                          for k, s in shapes.items()},
                 **({"out_shardings": shardings} if shardings else {}))()
 
+        # on a trace's `XLA Modules` line: jit_tpumx_train_step, ..._accum_step
+        fn.__name__ = "tpumx_train_step"
+        accum_fn.__name__ = compressed_accum_fn.__name__ = "tpumx_accum_step"
         donate = (0, 1, 2, 3, 4) if self._donate else ()
         if self.mesh is None:
             self._jitted = jax.jit(fn, donate_argnums=donate)
@@ -666,11 +688,8 @@ class CompiledTrainStep:
                 loss = self._step(batch, lr, expect_gen=gen0)
                 # force the async dispatch to completion INSIDE the
                 # watchdog thread — a hung collective parks here
-                t_read = time.perf_counter()
-                jax.block_until_ready(loss._data)
-                _tracing.emit("train_step.phase", t0=t_read,
-                              t1=time.perf_counter(),
-                              phase="loss_readback")
+                with _tracing.phase("loss_readback"):
+                    jax.block_until_ready(loss._data)
                 return loss
 
             count0 = self._build_count
@@ -684,118 +703,98 @@ class CompiledTrainStep:
         return self._step(batch, lr)
 
     def _step(self, batch, lr, expect_gen=None):
-        from .. import random as _random
         if expect_gen is None:
-            # capture at entry: even un-watchdogged calls (the supervisor's
-            # sup.step(lambda: step.step(*batch)) path runs THIS method on
-            # the watchdog thread) discard their result if a restore
-            # supersedes them mid-flight
+            # capture at entry: un-watchdogged calls too (the supervisor
+            # runs THIS method on its watchdog thread) discard their result
+            # if a restore supersedes them mid-flight
             expect_gen = self._generation
-        t_start = time.perf_counter()
-        # chaos straggler injection (ISSUE 18): the slow_worker delay
-        # must land INSIDE the data_wait window below — an injected
-        # straggler whose delay fell outside every measured phase would
-        # be invisible to the cross-rank phase attribution that is the
-        # point of injecting it (tpu_mx/parallel/fleet_obs.py)
+        # the parent span on the profiler's timeline; the phases tile it in
+        # order (tracing.TRAIN_STEP_PHASES), each an annotation there AND a
+        # train_step.phase event.  The device side is ONE XLA program, so
+        # "dispatch" is its (async) enqueue and "loss_readback" (at the
+        # read sites) the block on its result.
+        with jax.profiler.StepTraceAnnotation("tpu_mx/train_step",
+                                              step_num=self._t + 1):
+            return self._step_phases(batch, lr, expect_gen)
+
+    def _step_phases(self, batch, lr, expect_gen):
+        from .. import random as _random
         from ..contrib import chaos as _chaos
-        _chaos.maybe_slow_worker()
-        # None batch args pass through (optional model inputs like
-        # valid_length); they contribute no leaves to the jitted
-        # signature.  Non-NDArray operands stay RAW (numpy/python): the
-        # jit boundary commits them on the C++ fast path — an eager
-        # jnp.asarray here costs a dispatch per operand per step (the
-        # PR-9 decode cliff; hot-path-purity flags it now)
-        raw = tuple(b._data if isinstance(b, NDArray) else b
-                    for b in batch)
-        # flight-recorder phase events (docs/observability.md): the step
-        # histogram split into its host-side stations — the device-side
-        # forward+backward+optimizer is ONE XLA program, so "dispatch"
-        # covers its (async) enqueue and "loss_readback" (emitted at the
-        # read sites) the block on its result
-        t_data = time.perf_counter()
-        _tracing.emit("train_step.phase", t0=t_start, t1=t_data,
-                      phase="data_wait")
-        if self._jitted is None:
-            self._build(len(raw))
-            self.place()
-            _tracing.emit("train_step.phase", t0=t_data,
-                          t1=time.perf_counter(), phase="recompile")
-        # per-shape-signature compile accounting (ISSUE 14): the first
-        # step at a new operand signature pays jax's retrace + XLA
-        # compile inside the jit call below — count it under the
-        # signature label and observe that call's wall clock as the
-        # compile cost.  Steady-state steps pay one set lookup.
-        sig = _shape_signature(raw)
-        fresh_sig = sig not in self._seen_signatures
-        if fresh_sig:
-            self._seen_signatures.add(sig)
-            _telemetry.counter("train_step.compiles", signature=sig).inc()
-        t_compile = time.perf_counter()
-        key = _random.take_key()
-        if self._accum > 1 and self._micro < self._accum - 1:
-            # microbatch: accumulate grads, no optimizer application
-            t_disp = time.perf_counter()
-            new_vals, new_gacc, loss = self._accum_jit(
-                self.values, self._gacc, key, *raw)
-            _tracing.emit("train_step.phase", t0=t_disp,
-                          t1=time.perf_counter(), phase="dispatch")
+        with _tracing.phase("data_wait") as waited:
+            # chaos straggler injection (ISSUE 18) lands INSIDE data_wait:
+            # outside every phase it would be invisible to the cross-rank
+            # attribution it exists to exercise (parallel/fleet_obs.py)
+            _chaos.maybe_slow_worker()
+            # None batch args pass through (optional inputs like
+            # valid_length: no leaves in the jitted signature).  Non-NDArray
+            # operands stay RAW (numpy/python): the jit boundary commits
+            # them on the C++ fast path — an eager jnp.asarray here costs a
+            # dispatch per operand per step (hot-path-purity flags it)
+            raw = tuple(b._data if isinstance(b, NDArray) else b
+                        for b in batch)
+            # per-shape-signature compile accounting (ISSUE 14): the first
+            # step at a new operand signature pays jax's retrace + XLA
+            # compile inside the jit call — counted under the signature
+            # label, with the dispatch phase's seconds as the compile cost
+            sig = _shape_signature(raw)
+            fresh_sig = sig not in self._seen_signatures
             if fresh_sig:
-                _telemetry.histogram(
-                    "train_step.compile_seconds", signature=sig).observe(
-                        time.perf_counter() - t_compile)
-            with self._state_lock:
-                if self._stale(expect_gen):
-                    return NDArray(loss)
-                self.values, self._gacc = new_vals, new_gacc
-                self._micro += 1
-            self._record_step(raw, t_start)
-            return NDArray(loss)
-        t_next = self._t + 1
-        if lr is None:
-            sched = self.optimizer.lr_scheduler
-            lr = sched(t_next) if sched else self.optimizer.lr
-        gacc = self._gacc if self._accum > 1 else {}
-        t_disp = time.perf_counter()
-        # np scalars, not jnp.asarray: the jit boundary places them —
-        # two fewer eager device commits per step
-        (new_vals, new_masters, new_states, new_efs, gacc,
-         loss, fp) = self._jitted(
-            self.values, self.masters, self.opt_states, self._efs, gacc,
-            np.float32(t_next), np.float32(lr),
-            key, *raw)
-        t_done = time.perf_counter()
-        _tracing.emit("train_step.phase", t0=t_disp, t1=t_done,
-                      phase="dispatch")
+                self._seen_signatures.add(sig)
+                _telemetry.counter("train_step.compiles", signature=sig).inc()
+            # microbatch: accumulate grads, no optimizer application
+            micro = self._accum > 1 and self._micro < self._accum - 1
+            t_next = self._t + 1
+            if lr is None and not micro:
+                sched = self.optimizer.lr_scheduler
+                lr = sched(t_next) if sched else self.optimizer.lr
+        if self._jitted is None:
+            with _tracing.phase("recompile"):
+                self._build(len(raw))
+                self.place()
+        with _tracing.phase("rng_key"):
+            key = _random.take_key()
+        with _tracing.phase("dispatch") as dispatched:
+            if micro:
+                new_vals, new_gacc, loss = self._accum_jit(
+                    self.values, self._gacc, key, *raw)
+            else:
+                # np scalars: the jit boundary places them (no eager commit)
+                (new_vals, new_masters, new_states, new_efs, new_gacc,
+                 loss, fp) = self._jitted(
+                    self.values, self.masters, self.opt_states, self._efs,
+                    self._gacc if self._accum > 1 else {},
+                    np.float32(t_next), np.float32(lr), key, *raw)
         if fresh_sig:
             _telemetry.histogram(
                 "train_step.compile_seconds", signature=sig).observe(
-                    t_done - t_compile)
-        with self._state_lock:
-            if self._stale(expect_gen):
-                return NDArray(loss)
-            (self.values, self.masters, self.opt_states,
-             self._efs) = new_vals, new_masters, new_states, new_efs
-            # the step's device fingerprint commits WITH the state it
-            # digests (still a lazy device scalar — fingerprint() is
-            # where the int conversion happens, off the hot path)
-            self._last_fp = fp
-            self._t = t_next
-            self._micro = 0
-            if self._accum > 1:
-                self._gacc = gacc
-        # the optimizer's device work is inside the fused program; this
-        # phase is the host-side commit of its result (the new train
+                    dispatched.seconds)
+        # the host-side commit of the program's result (the new train
         # state becoming THE state, under the zombie-step lock)
-        _tracing.emit("train_step.phase", t0=t_done,
-                      t1=time.perf_counter(), phase="optimizer_update")
-        # chaos SDC injection (ISSUE 20): flip one bit of the COMMITTED
-        # state, after this step's fingerprint was computed — the flip is
-        # silent until the NEXT published fingerprint disagrees, which is
-        # the detection latency the defense actually promises (≤ K steps)
-        bit = _chaos.maybe_bitflip()
-        if bit is not None:
-            self._apply_bitflip(bit)
-        self._record_step(raw, t_start)
+        with _tracing.phase("optimizer_update"):
+            with self._state_lock:
+                if self._stale(expect_gen):
+                    return NDArray(loss)
+                if micro:
+                    self.values, self._gacc = new_vals, new_gacc
+                    self._micro += 1
+                else:
+                    (self.values, self.masters, self.opt_states,
+                     self._efs) = new_vals, new_masters, new_states, new_efs
+                    # the device fingerprint commits WITH the state it
+                    # digests (a lazy device scalar until fingerprint())
+                    self._last_fp = fp
+                    self._t = t_next
+                    self._micro = 0
+                    if self._accum > 1:
+                        self._gacc = new_gacc
+            # chaos SDC injection (ISSUE 20): flip one bit of the COMMITTED
+            # state, after this step's fingerprint — silent until the NEXT
+            # published fingerprint disagrees (the promised ≤ K steps)
+            bit = None if micro else _chaos.maybe_bitflip()
+            if bit is not None:
+                self._apply_bitflip(bit)
+        with _tracing.phase("record"):
+            self._record_step(raw, waited.t0)
         return NDArray(loss)
 
     def _apply_bitflip(self, bit):

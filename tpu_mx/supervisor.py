@@ -510,10 +510,8 @@ class Supervisor:
             # device read below is where a hung collective actually
             # blocks, and it must block on the watchdog's thread, not the
             # supervisor's
-            t_read = time.perf_counter()
-            obs = _observable(value)
-            _tracing.emit("train_step.phase", t0=t_read,
-                          t1=time.perf_counter(), phase="loss_readback")
+            with _tracing.phase("loss_readback"):
+                obs = _observable(value)
             return value, obs
 
         try:

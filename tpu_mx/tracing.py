@@ -36,6 +36,12 @@ module is the forensic substrate:
   instants, real intervals when ``t0``/``t1`` endpoints are passed), so
   the same timeline is visible in Perfetto next to the XLA annotations.
 
+- **Train-step phases** — :class:`phase` is the one site every host-side
+  phase of the compiled train step goes through: a
+  ``jax.profiler.TraceAnnotation`` (``tpu_mx/train_step/<phase>``, on the
+  profiler's timeline beside the device's operations) around the region,
+  and the ``train_step.phase`` event with the same endpoints on exit.
+
 ``TPUMX_TRACING=0`` disables emission entirely: the disabled path is one
 module-global check per call site (held to the same within-noise bar as
 the telemetry exporter, docs/observability.md).
@@ -57,7 +63,8 @@ import time
 from collections import deque
 
 __all__ = ["KNOWN_EVENTS", "BLACKBOX_FORMAT", "TRAIN_STEP_PHASES",
-           "enabled", "configure", "emit", "set_context", "get_context",
+           "enabled", "configure", "emit", "phase", "set_context",
+           "get_context",
            "snapshot", "stats", "reset", "validate_event",
            "blackbox_doc", "dump_blackbox", "blackbox_path",
            "validate_blackbox"]
@@ -246,9 +253,11 @@ KNOWN_EVENTS = {
 
 # the documented values of train_step.phase's `phase` field (the whole
 # device-side forward+backward+optimizer runs as ONE XLA program, so the
-# phases are the HOST-side stations around it — docs/observability.md)
-TRAIN_STEP_PHASES = ("data_wait", "recompile", "dispatch", "loss_readback",
-                     "optimizer_update")
+# phases are the HOST-side stations around it — docs/observability.md).
+# In step order; all but loss_readback (emitted at the read site, after
+# the step returns) tile CompiledTrainStep._step from entry to return.
+TRAIN_STEP_PHASES = ("data_wait", "recompile", "rng_key", "dispatch",
+                     "optimizer_update", "record", "loss_readback")
 
 _TYPES = {"str": str, "int": int, "float": (int, float), "bool": bool}
 
@@ -447,6 +456,50 @@ def emit(event, t0=None, t1=None, **payload):
         _ring.append(rec)
     _merge_profiler(event, t0, t1, payload)
     return rec
+
+
+_TraceAnnotation = None     # jax's, looked up at first use; False without jax
+
+
+class phase:
+    """One host-side phase of the compiled train step, on both clocks:
+    ``with phase("dispatch"):`` opens the
+    ``jax.profiler.TraceAnnotation`` ``tpu_mx/train_step/dispatch`` (a
+    span on the profiler's timeline beside the device's operations;
+    nanoseconds while no profiler session runs) and, on exit, records the
+    ``train_step.phase`` event with the same ``perf_counter`` endpoints
+    (the flight recorder, the black box and the chrome trace).
+    ``TPUMX_TRACING=0`` skips the event and keeps the annotation; without
+    jax (standalone load) there is no annotation.  ``t0`` and, after exit,
+    ``seconds`` are the interval's start and length."""
+
+    __slots__ = ("name", "t0", "seconds", "_annotation")
+
+    def __init__(self, name):
+        self.name = name
+        self.seconds = 0.0
+
+    def __enter__(self):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            try:
+                from jax.profiler import TraceAnnotation as _TraceAnnotation
+            except ImportError:
+                _TraceAnnotation = False
+        self._annotation = _TraceAnnotation and \
+            _TraceAnnotation("tpu_mx/train_step/" + self.name)
+        if self._annotation:
+            self._annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self.seconds = t1 - self.t0
+        if self._annotation:
+            self._annotation.__exit__(*exc)
+        emit("train_step.phase", t0=self.t0, t1=t1, phase=self.name)
+        return False
 
 
 def _merge_profiler(event, t0, t1, payload):
