@@ -581,38 +581,14 @@ def bench_bert(smoke):
 def bench_bert512(smoke):
     """Phase-2-style BERT-base seq-512 row (VERDICT r4 ask#5): the memory
     regime where flash attention + remat matter, in the official record.
-    The primary value is the production auto-dispatch path; when auto
-    resolves to XLA dense (kv_len 512 sits at the measured crossover), a
-    pinned-flash arm is measured alongside so the Pallas kernel appears
-    in a driver-visible workload number either way."""
+    The value is the production auto-dispatch path, which at kv_len 512
+    is the Pallas flash kernel (PERF.md section 6, PR 26)."""
     ladder = _batch_ladder("BENCH_BERT512_BATCH",
                            (4,) if smoke else (192, 128, 96, 64, 32))
     remat = os.environ.get("BENCH_BERT512_REMAT", "1") == "1"
-    rec = _run_ladder("bert512", ladder,
-                      lambda b: _bert_once(smoke, b, seq_len=512,
-                                           remat=remat))
-    if smoke or rec.get("attention_path") == "pallas_flash":
-        return rec
-    # persist the measured auto-arm record BEFORE the flash arm runs: a
-    # flash arm that raises or is killed must not take the already-
-    # measured number with it
-    log("bert512 record (auto arm): " + json.dumps(rec))
-    persist_lastgood(rec)
-    prior = os.environ.get("TPUMX_ATTENTION")
-    os.environ["TPUMX_ATTENTION"] = "flash"
-    try:
-        frec = _run_ladder("bert512_flash", ladder,
-                           lambda b: _bert_once(smoke, b, seq_len=512,
-                                                remat=remat))
-        rec["flash_arm"] = {k: frec.get(k) for k in
-                            ("value", "unit", "batch", "attention_path",
-                             "mfu", "mfu_source", "mfu_analytic_model")}
-    finally:
-        if prior is None:
-            os.environ.pop("TPUMX_ATTENTION", None)
-        else:
-            os.environ["TPUMX_ATTENTION"] = prior
-    return rec
+    return _run_ladder("bert512", ladder,
+                       lambda b: _bert_once(smoke, b, seq_len=512,
+                                            remat=remat))
 
 
 def _bert_once(smoke, batch, seq_len=128, remat=None):
@@ -1851,16 +1827,9 @@ def inner():
         else f"ssd512_{ssd_backbone}_train_images_per_sec_per_chip"}
 
     def _bert512_complete(rec_):
-        # a carried bert512 record must include the Pallas-flash receipt:
-        # either the auto arm compiled flash, or a healthy pinned flash_arm
-        # rode along.  The auto-arm-only record a failed flash arm
-        # leaves behind must trigger a re-measure, not a 4h carry
-        # (ADVICE r5 medium, bench.py:1083).
-        if rec_.get("attention_path") == "pallas_flash":
-            return True
-        fa = rec_.get("flash_arm")
-        return isinstance(fa, dict) and "error" not in fa and \
-            isinstance(fa.get("value"), (int, float)) and fa["value"] > 0
+        # a carried bert512 record must be one the Pallas kernel made: an
+        # older dense-arm record triggers a re-measure, not a 4h carry
+        return rec_.get("attention_path") == "pallas_flash"
     # bert512 deliberately runs LAST: its remat+flash compile is the
     # largest program this file builds — the riskiest leg must not sit
     # in front of cheap ones

@@ -191,7 +191,7 @@ def phase_a(tiny, platform, compiles):
           f"steps on one fixed batch at lr {LR} (memorization)")
     check(dispatch_counts["xla_dense"] > before["xla_dense"]
           and dispatch_counts["pallas_flash"] == before["pallas_flash"],
-          "A: attention dispatched to xla_dense (auto picks dense to kv 512)")
+          "A: attention dispatched to xla_dense (auto: dense below kv 256)")
     check(state_platforms(step) == {platform},
           f"A: parameters, masters and optimizer state live on {platform}")
     return losses

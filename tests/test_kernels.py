@@ -374,6 +374,50 @@ def test_attention_env_knob(monkeypatch):
     assert out.shape == q.shape
 
 
+@pytest.mark.parametrize("on_tpu", [True, False], ids=["tpu", "cpu"])
+@pytest.mark.parametrize("dropped", [True, False],
+                         ids=["dropout", "no_dropout"])
+@pytest.mark.parametrize("kv_len", [128, 256, 384, 512, 1024])
+def test_auto_dispatch_rule(kv_len, dropped, on_tpu):
+    """'auto' as a pure function of what the call shows (PERF.md section 6,
+    PR 26 holds the two-arm table the crossover was taken from): on a TPU
+    the Pallas kernel from kv 256 where attention dropout is active and
+    from kv 512 where it is not, dense below and off the TPU; a query
+    shorter than the crossover keeps the call dense, and beyond kv 512 the
+    kernel is the choice whatever the query."""
+    from tpu_mx.parallel.ring_attention import _auto_prefers_flash
+    assert _auto_prefers_flash(kv_len, kv_len, dropped, on_tpu) is \
+        (on_tpu and kv_len >= (256 if dropped else 512))
+    assert _auto_prefers_flash(128, kv_len, dropped, on_tpu) is \
+        (on_tpu and kv_len > 512)
+
+
+def test_auto_dispatch_ignores_dense_max_kv(monkeypatch):
+    """TPUMX_DENSE_MAX_KV is gone: set either way it moves no call.  The
+    process is made to look like a TPU one (with the kernel in interpret
+    mode), so that 'auto' really chooses."""
+    import jax
+    import jax.numpy as jnp
+    from tpu_mx.kernels import flash_attention as fa
+    from tpu_mx.parallel.ring_attention import (dispatch_counts,
+                                                local_flash_attention)
+    monkeypatch.delenv("TPUMX_ATTENTION", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_interpret", lambda: True)
+
+    def arm_of(t, max_kv):
+        # a head count no other test uses: the counter is once a signature
+        monkeypatch.setenv("TPUMX_DENSE_MAX_KV", str(max_kv))
+        q = jnp.ones((1, 5, t, 64), jnp.float32)
+        before = dict(dispatch_counts)
+        assert local_flash_attention(q, q, q).shape == q.shape
+        return [k for k in dispatch_counts
+                if dispatch_counts[k] != before[k]]
+
+    assert arm_of(128, max_kv=0) == ["xla_dense"]
+    assert arm_of(1024, max_kv=4096) == ["pallas_flash"]
+
+
 # ---------------------------------------------------------------------------
 # paged-attention decode kernel (ISSUE 9) — interpret mode on CPU
 # ---------------------------------------------------------------------------

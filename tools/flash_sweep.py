@@ -1,9 +1,13 @@
 """Short-T flash-kernel block sweep vs XLA dense (VERDICT r4 ask#4).
 
-The r4 A/B measured the Pallas flash kernel losing to XLA dense by
-34%/25%/5% at T=128/256/512 (fwd+bwd, causal, bf16) and the auto
-dispatch was pinned to dense at kv_len <= TPUMX_DENSE_MAX_KV=512.  This
-tool answers "is that overhead tunable or structural?" on chip:
+`auto` sends attention to the Pallas flash kernel from kv_len 256 where
+attention dropout is active and from 512 where it is not, and to XLA
+dense below (`parallel/ring_attention.py` `_auto_prefers_flash`; no
+option).  That crossover was read in BERT-base's whole train step, both
+arms pinned (`PERF.md` section 6, PR 26, has the table); this tool has
+never run on the chip.  It times attention alone (fwd+bwd,
+causal, bf16, no dropout) and answers "is the kernel's overhead below
+the crossover tunable or structural?":
 
   - for each T it measures XLA dense and the flash kernel at every valid
     (block_q, block_k) combination (the kernel's only tuning surface);
@@ -15,8 +19,7 @@ tool answers "is that overhead tunable or structural?" on chip:
 Note the structural expectation: at T <= 512 `_pick_block` already
 collapses to a single (T, T) block per b*h grid cell, so there is
 nothing smaller to pipeline — if no combo closes the gap, the honest
-outcome is "dense below the crossover is final" and the dispatch default
-stands with this artifact as the evidence.
+outcome is "dense below the crossover is final" and the rule stands.
 
     python tools/flash_sweep.py [--lens 128,256,512,1024]
         [--tokens 65536] [--heads 12] [--dim 64] [--iters 10]

@@ -1,4 +1,4 @@
-"""Paged-decode KV block-size sweep + dense/flash crossover disposition.
+"""Paged-decode KV block-size sweep.
 
 ISSUE 9's tuning satellite, on the bench harness's decode_attention
 micro-arm (bench.measure_decode_micro — the same fixed-seed A/B the
@@ -11,15 +11,6 @@ serve leg persists):
   program) and the dense-gather arm per decode step.  The default lives
   at ``tpu_mx/kernels/paged_attention.py DEFAULT_BLOCK_SIZE``; update it
   only with receipts from this tool.
-- **TPUMX_DENSE_MAX_KV crossover**: the dense/flash dispatch constant
-  (ring_attention, default 512, pinned by BENCH_INTERIM_r04 on chip and
-  flagged "expected to move" after the r5 native-dtype dot change) is a
-  TPU-kernel-vs-XLA-dense crossover: it CANNOT be measured off-TPU
-  (interpret-mode Pallas timing is meaningless).  On a TPU backend this
-  tool defers to tools/flash_sweep.py — the existing per-(block_q,
-  block_k) sweep — and records that pointer; on CPU it records an
-  explicit ``skipped`` disposition so a TPU-less round leaves an honest
-  artifact instead of silence.
 
 ISSUE 16 widens the sweep with a **Tq axis**: the speculative verify
 call batches ``Tq`` query positions per sequence into ONE attention
@@ -144,27 +135,10 @@ def main():
                 record["rows"][key] = row
                 write_atomic(args.out, record)  # row-at-a-time durability
 
-    # honest disposition for the dense/flash crossover constant
-    if platform == "tpu":
-        record["dense_max_kv_crossover"] = {
-            "status": "measure_with_flash_sweep",
-            "note": "run tools/flash_sweep.py on this chip; "
-                    "TPUMX_DENSE_MAX_KV moves only on its receipts "
-                    "(BENCH_INTERIM_r04 pinned 512)",
-        }
-    else:
-        record["dense_max_kv_crossover"] = {
-            "status": "skipped",
-            "note": f"backend={platform}: the dense/flash crossover is a "
-                    "TPU Mosaic-vs-XLA property; interpret-mode timing "
-                    "is meaningless.  Constant stands at 512 "
-                    "(BENCH_INTERIM_r04 receipts) until a chip round "
-                    "reruns tools/flash_sweep.py post-r5-native-dtype.",
-        }
     write_atomic(args.out, record)
     if not record["rows"]:
         log(f"done: 0 rows (every block size skipped for the given "
-            f"contexts) -> {args.out} holds the disposition only")
+            f"contexts) -> {args.out} holds no row")
         return 0
     best = min(record["rows"].values(),
                key=lambda r: r["paged_us_per_seq"])

@@ -121,6 +121,66 @@ def check_flash_fwd_bwd_vs_dense():
     return results
 
 
+def check_attention_auto_dispatch():
+    """What `auto` chooses on the chip (PERF.md section 6, PR 26 has the
+    two-arm table): BERT-base's SelfAttention, forward and backward with
+    attention dropout 0.1, takes the Pallas kernel at T 512 and XLA dense
+    at T 128; and with dropout off the two pinned arms agree in output and
+    q/k/v gradients within flash_fwd_bwd_vs_dense's bf16 tolerance."""
+    from unittest import mock
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from tpu_mx import autograd, nd
+    from tpu_mx.models.bert import SelfAttention
+    from tpu_mx.parallel.ring_attention import (dispatch_counts,
+                                                local_flash_attention)
+    b, heads, dim = 2, 12, 64
+    units = heads * dim
+    results = {}
+    with mock.patch.dict(os.environ):
+        os.environ.pop("TPUMX_ATTENTION", None)
+        for t, want, other in ((512, "pallas_flash", "xla_dense"),
+                               (128, "xla_dense", "pallas_flash")):
+            attn = SelfAttention(units=units, num_heads=heads, dropout=0.1)
+            attn.initialize()
+            attn.cast("bfloat16")
+            x = nd.array(np.random.RandomState(t).randn(b, t, units)
+                         .astype(np.float32)).astype("bfloat16")
+            x.attach_grad()
+            before = dict(dispatch_counts)
+            with autograd.record():
+                loss = attn(x).astype("float32").sum()
+            loss.backward()
+            took = {k: dispatch_counts[k] - before[k]
+                    for k in (want, other)}
+            finite = bool(np.isfinite(x.grad.asnumpy().astype(np.float32))
+                          .all())
+            results[f"T{t}"] = {"dispatch": took, "grad_finite": finite}
+            if took[want] < 1 or took[other] or not finite:
+                raise AssertionError(f"auto at T={t}: wanted {want}, counted "
+                                     f"{took}, finite grads {finite}")
+
+    tol = 4e-2                      # flash_fwd_bwd_vs_dense's bf16 leg
+    q, k, v = (jax.random.normal(key, (b, heads, 512, dim), jnp.bfloat16)
+               for key in jax.random.split(jax.random.PRNGKey(26), 3))
+    f = lambda q, k, v: local_flash_attention(q, k, v).astype(
+        jnp.float32).sum()
+    arms = {}
+    for arm in ("dense", "flash"):
+        with mock.patch.dict(os.environ, TPUMX_ATTENTION=arm):
+            arms[arm] = (local_flash_attention(q, k, v),
+                         jax.grad(f, argnums=(0, 1, 2))(q, k, v))
+    e_out = _max_err(arms["dense"][0], arms["flash"][0])
+    e_grad = max(_max_err(a, c)
+                 for a, c in zip(arms["dense"][1], arms["flash"][1]))
+    results["arms_agree"] = {"out_err": e_out, "grad_err": e_grad}
+    if e_out > tol or e_grad > tol * 20:
+        raise AssertionError(f"pinned arms disagree: out_err={e_out} "
+                             f"grad_err={e_grad} tol={tol}")
+    return results
+
+
 @_highest_precision
 def check_flash_bias_layouts():
     """All broadcast layouts of the additive attention bias (r3 commit
@@ -601,6 +661,7 @@ def check_cpu_tpu_consistency():
 
 CHECKS = [
     ("flash_fwd_bwd_vs_dense", check_flash_fwd_bwd_vs_dense),
+    ("attention_auto_dispatch", check_attention_auto_dispatch),
     ("flash_bias_layouts", check_flash_bias_layouts),
     ("flash_dropout_inkernel", check_flash_dropout),
     ("flash_kv_valid", check_flash_kv_valid),

@@ -240,8 +240,15 @@ def _extra_specs_and_args(kv_valid, seed):
     return specs, args
 
 
+# The kernel calls are jitted with `interpret` among the static arguments: a
+# model's layers then share one traced and lowered copy of each kernel
+# (twelve BERT layers lowered 36 pallas_calls one by one: a second a step
+# program and 300 KB of module text, paid in every process, compile-cache
+# hit or not), and a trace made in interpret mode is never taken for a
+# Mosaic one.
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
 def _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate, block_q,
-         block_k):
+         block_k, interpret):
     bh, t, d = q.shape
     tk = k.shape[1]
     block_q = min(block_q, t)
@@ -288,7 +295,7 @@ def _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate, block_q,
             pltpu.VMEM((block_q, 128), jnp.float32),   # running denom
             pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
         ],
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v, *extra_args)
     return out, lse
 
@@ -430,7 +437,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, rate, biased, block_q,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(scale, causal, rate, block_q, block_k, res, do):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
+def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do):
     q, k, v, kv_valid, seed, bias, out, lse = res
     bh, t, d = q.shape
     tk = k.shape[1]
@@ -472,7 +480,7 @@ def _bwd(scale, causal, rate, block_q, block_k, res, do):
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v, do, lse, delta, *bias_args, *extra_args)
     if biased:
         dq, db_full = dq_out
@@ -510,7 +518,7 @@ def _bwd(scale, causal, rate, block_q, block_k, res, do):
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v, do, lse, delta, *bias_args, *extra_args)
     return dq, dk, dv, None, None, db
 
@@ -522,15 +530,20 @@ def _bwd(scale, causal, rate, block_q, block_k, res, do):
 def _flash_core(q, k, v, kv_valid, seed, bias, scale, causal, rate,
                 block_q, block_k):
     out, _ = _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate,
-                  block_q, block_k)
+                  block_q, block_k, _interpret())
     return out
 
 
 def _flash_fwd_rule(q, k, v, kv_valid, seed, bias, scale, causal, rate,
                     block_q, block_k):
     out, lse = _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate,
-                    block_q, block_k)
+                    block_q, block_k, _interpret())
     return out, (q, k, v, kv_valid, seed, bias, out, lse)
+
+
+def _bwd(scale, causal, rate, block_q, block_k, res, do):
+    return _bwd_call(scale, causal, rate, block_q, block_k, _interpret(),
+                     res, do)
 
 
 _flash_core.defvjp(_flash_fwd_rule, _bwd)

@@ -46,18 +46,25 @@ dispatch_counts = {"ring": 0, "ulysses": 0, "pallas_flash": 0,
                    "xla_dense": 0}
 
 
-def _dense_max_kv():
-    """Largest kv_len at which 'auto' prefers XLA dense attention over the
-    Pallas flash kernel.  r4 on-chip A/B (fwd+bwd, causal, bf16, H=12,
-    D=64, constant token count): dense wins 34% at T=128, 25% at 256, 5%
-    at 512; flash wins 18% at 1024 and 31% at 2048 — the kernel's
-    grid/DMA overhead amortizes only once many 128-blocks are in flight.
-    The default stays at 512 rather than the ~768-1024 perf crossover
-    because dense materializes O(B·H·T²) probabilities in the backward,
-    and that memory cliff arrives before the perf one.  Read per call
-    (like TPUMX_ATTENTION) so probes can sweep the crossover at
-    runtime."""
-    return int(os.environ.get("TPUMX_DENSE_MAX_KV", "512"))
+def _auto_prefers_flash(q_len, kv_len, dropped, on_tpu):
+    """The arm 'auto' takes, from what the call shows: True for the Pallas
+    flash kernel, False for XLA dense.  The crossover is one recorded
+    measurement (PERF.md section 6, PR 26): both arms pinned in BERT-base's
+    bf16 train step on a v5e (H 12, D 64, 24,576 tokens a step, attention
+    dropout 0.1), samples/s dense -> flash: T 128 895.7 -> 774.2 (-13.6%),
+    T 256 370.5 -> 404.5 (+9.2%), T 512 135.9 -> 203.7 (+49.9%).  Dense
+    writes the (q_len, kv_len) scores of every head to HBM, keeps them for
+    the backward pass and draws their dropout mask from threefry (76 of
+    its 166 ms at T 512); the kernel holds one head's block in VMEM and
+    pays a fixed cost a grid step instead, which is what loses at T 128.
+    With that dropout off, ms a step dense against flash: T 256 219.1
+    against 235.3, T 512 274.8 against 230.2.
+    Only square shapes were measured, so the shorter of the two lengths is
+    held to the crossover; beyond kv 512 the kernel always was the choice,
+    for the memory."""
+    if not on_tpu:
+        return False
+    return kv_len > 512 or min(q_len, kv_len) >= (256 if dropped else 512)
 
 
 _seen_signatures = set()
@@ -320,20 +327,17 @@ def local_flash_attention(q, k, v, causal=False, valid_length=None,
     on_tpu = jax.default_backend() == "tpu"
     dropped = dropout_rate > 0.0 and dropout_key is not None
     rate = float(dropout_rate) if dropped else 0.0
-    # TPUMX_ATTENTION=dense|flash|auto (default auto): at short T the
-    # O(T²) score matrix is a single MXU tile and XLA's fused dense
-    # attention beats the Pallas kernel's grid/DMA overhead — measured on
-    # the r4 chip at T=128, BERT-base batch 512: dense 577 seq/s vs flash
-    # 454 (MFU_PROBE_r04.json).  'auto' therefore picks dense up to
-    # TPUMX_DENSE_MAX_KV (default 512 — see _dense_max_kv for the full
-    # crossover table) and flash beyond; 'flash'/'dense' pin the path
-    # ('flash' only where supported() holds; 'dense' always works).
+    # TPUMX_ATTENTION=dense|flash|auto (default auto): 'auto' follows
+    # _auto_prefers_flash's measured crossover; 'flash'/'dense' pin the arm,
+    # which is how both are read on the chip ('flash' only where
+    # supported() holds; 'dense' always works).
     mode = os.environ.get("TPUMX_ATTENTION", "auto")
     if mode not in ("auto", "dense", "flash"):
         raise ValueError(f"TPUMX_ATTENTION must be auto|dense|flash, "
                          f"got {mode!r}")
-    want_flash = on_tpu and mode != "dense" and \
-        not (mode == "auto" and k.shape[2] <= _dense_max_kv())
+    want_flash = (mode == "flash" and on_tpu) or (
+        mode == "auto" and _auto_prefers_flash(q.shape[2], k.shape[2],
+                                               dropped, on_tpu))
     if want_flash and fa.supported(q.shape, q.dtype, kv_len=k.shape[2],
                                    dropout_rate=rate):
         _count("pallas_flash", f"shape={q.shape}")
