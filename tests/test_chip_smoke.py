@@ -25,7 +25,7 @@ def test_tiny_flag_runs_all_phases_on_cpu(tmp_path):
     lines = out.stdout.strip().splitlines()
     assert lines[0].startswith("NOT A CHIP RUN")
     for phase in ("phase A", "phase mesh dp4", "phase mesh dp2xtp2",
-                  "phase B", "phase C"):
+                  "phase B", "phase C", "phase D"):
         assert any(ln.startswith(phase) for ln in lines), phase
     assert json.loads(lines[-1]) == {
         "ok": True, "tiny_cpu": True,

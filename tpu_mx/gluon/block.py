@@ -423,7 +423,8 @@ class HybridBlock(Block):
                 # rebuild the structure `closed` returned: backward() hands a
                 # bare array for single-output nodes, a tuple otherwise
                 cts = out_ct if isinstance(out_ct, tuple) else (out_ct,)
-                in_cts = vjp_fn(list(cts) if multi else cts[0])
+                # a forward that returned a tuple wants a tuple back
+                in_cts = vjp_fn(type(out)(cts) if multi else cts[0])
                 param_cts, arg_cts = in_cts[0], in_cts[1:]
                 return tuple(param_cts) + tuple(arg_cts)
 

@@ -10,7 +10,7 @@ from ... import layout as _layout_mod
 from ..block import Block, HybridBlock
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm", "GroupNorm", "ReflectionPad2D",
-           "LayerNorm", "InstanceNorm", "Embedding", "Flatten", "Activation",
+           "LayerNorm", "RMSNorm", "InstanceNorm", "Embedding", "Flatten", "Activation",
            "LeakyReLU", "PReLU", "ELU", "SELU", "GELU", "Swish", "Lambda",
            "HybridLambda"]
 
@@ -270,6 +270,27 @@ class LayerNorm(HybridBlock):
 
     def hybrid_forward(self, F, x, gamma, beta):
         return F.LayerNorm(x, gamma, beta, axis=self._axis, eps=self._eps)
+
+
+class RMSNorm(HybridBlock):
+    """Root-mean-square norm over `axis` with a learned scale and no
+    centring (Zhang & Sennrich, arXiv:1910.07467): the decoder families'
+    norm.  Statistics in f32 whatever the input's type (ops.RMSNorm)."""
+
+    def __init__(self, axis=-1, epsilon=1e-6, gamma_initializer="ones",
+                 in_channels=0, **kwargs):
+        super().__init__(**kwargs)
+        self._axis = axis
+        self._eps = epsilon
+        self.gamma = self.params.get(
+            "gamma", shape=(in_channels,) if in_channels else (0,),
+            init=gamma_initializer, allow_deferred_init=True)
+
+    def infer_shape(self, x, *args):
+        self.gamma.shape_hint((x.shape[self._axis],))
+
+    def hybrid_forward(self, F, x, gamma):
+        return F.RMSNorm(x, gamma, axis=self._axis, eps=self._eps)
 
 
 class InstanceNorm(HybridBlock):

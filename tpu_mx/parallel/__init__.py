@@ -2,7 +2,8 @@
 re-designed for ICI/DCN collectives; SURVEY §2.3, §5.7, §5.8)."""
 from .fleet import Fleet, MembershipChange, reshard_live
 from .mesh import Mesh, NamedSharding, P, hybrid_mesh, local_mesh, make_mesh
-from .moe import MoEFFN, moe_sharding_rules
+from .moe import (DroplessMoE, MoEFFN, dropless_route, load_census,
+                  moe_sharding_rules)
 from .pipeline import pipeline_apply, stack_stage_params
 from .ring_attention import attention, local_flash_attention, ring_attention
 from .ulysses import get_sp_strategy, set_sp_strategy, ulysses_attention

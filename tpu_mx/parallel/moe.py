@@ -26,17 +26,44 @@ the standard Switch trade-off that keeps shapes static.
 forward(x) -> (y, aux_loss): aux_loss is the Switch load-balance term
 (E · Σ_e fraction_tokens_e · mean_prob_e, ≥ 1 at perfect balance); add
 `aux_loss_weight * aux_loss` to the training objective.
+
+`DroplessMoE` is the second expert layer (ISSUE 27; merging the two is
+design debt, ROADMAP): it is TOLD which experts it holds
+(`held_experts=range(lo, hi)` of `num_experts`), routes over all of them,
+drops nothing, and runs grouped matrix products (`jax.lax.ragged_dot`) over
+its own: one chip's part of an expert-parallel layer, without the exchange.
+Shapes stay static: the (token, choice) rows are sorted by held expert into
+a buffer of S·k rows, the worst case, and the grouped products are handed
+the real group sizes, so their cost follows the rows that are real.  Each
+layer counts the rows every expert was chosen for, step by step, into a
+non-trainable buffer of the last LOAD_HISTORY training steps, written
+through the path BatchNorm's running statistics take, and
+`load_census(net)` reads it back when asked.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import autograd
+from .. import telemetry as _telemetry
 from ..gluon.block import HybridBlock
 from ..ndarray import ops
 
-__all__ = ["MoEFFN", "moe_sharding_rules"]
+__all__ = ["MoEFFN", "DroplessMoE", "moe_sharding_rules", "dropless_route",
+           "load_census", "MOE_SCOPES", "LOAD_HISTORY"]
+
+# training steps of expert load a DroplessMoE keeps: a router that learns
+# moves its load from step to step, and a trace is of some steps ago
+LOAD_HISTORY = 64
+
+# jax.named_scope names inside DroplessMoE (HLO metadata only), read from a
+# device trace by benchmark/decoder_scopes.py, which holds them as literals
+MOE_SCOPES = ("moe.route", "moe.experts", "moe.shared", "moe.combine")
+_ROUTE, _EXPERTS, _SHARED, _COMBINE = MOE_SCOPES
 
 
 def moe_sharding_rules():
@@ -48,6 +75,7 @@ def moe_sharding_rules():
         (r"expert_b1$", P("ep", None)),
         (r"expert_w2$", P("ep", None, None)),
         (r"expert_b2$", P("ep", None)),
+        (r"expert_w3$", P("ep", None, None)),
         (r"gate_weight$", P(None, None)),
     ]
 
@@ -172,3 +200,221 @@ class MoEFFN(HybridBlock):
         return (f"MoEFFN(units={self._units}, hidden={self._hidden}, "
                 f"experts={self._E}, top_k={self._k}, "
                 f"capacity_factor={self._cf})")
+
+
+# -- the dropless layer ---------------------------------------------------------
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_of(x, order, inv, here, k):
+    """x[order // k]: row r of the sorted buffer is the token of the r-th
+    (token, choice) pair in expert order.  `order` is a permutation of the
+    S·k pairs and `inv` its inverse, so the transpose is a gather too (JAX
+    would scatter-add); rows of pairs that are not `here` carry nothing
+    back."""
+    return x[order // k]
+
+
+def _rows_of_fwd(x, order, inv, here, k):
+    return x[order // k], (inv, here)
+
+
+def _rows_of_bwd(k, res, g):
+    inv, here = res
+    back = jnp.where(here[..., None], g[inv].reshape(here.shape + g.shape[1:]),
+                     0)
+    return jnp.sum(back, axis=1), None, None, None
+
+
+_rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
+
+
+@jax.custom_vjp
+def _unsort(ys, order, inv):
+    """ys[inv]: the sorted buffer back in (token, choice) order; transpose
+    by the inverse permutation, a gather again."""
+    return ys[inv]
+
+
+_unsort.defvjp(lambda ys, order, inv: (ys[inv], (order,)),
+               lambda res, g: (g[res[0]], None, None))
+
+
+def dropless_route(x, gate_weight, select_bias, top_k, scaling=1.0):
+    """The choice and its weights, over all E experts, for tokens (S, U):
+    (chosen (S, k) expert ids, weights (S, k) f32).  Scores are
+    sigmoid(x · gate) in f32 whatever the model's type (x and the gate hold
+    bf16 values at most, whose products an f32 accumulator takes exactly);
+    the k largest of score + bias are chosen, the bias for the choice only
+    (noaux_tc: no gradient, no weight); weights are the chosen scores
+    normalised over all chosen, times `scaling`."""
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "su,eu->se", x.astype(jnp.float32), gate_weight.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20) \
+        * scaling
+
+
+def _dropless_forward(x, gw, bias, w1, w3, w2, *, top_k, lo, scaling):
+    """Routing over all E experts and the held experts' part of the result,
+    on flattened tokens (S, U).  Returns (y (S, U), expert_load (E,) f32)."""
+    S, U = x.shape
+    E, H, k = gw.shape[0], w1.shape[0], top_k
+    with jax.named_scope(_ROUTE):
+        chosen, weights = dropless_route(x, gw, bias, k, scaling)
+        flat = chosen.reshape(-1)
+        load = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0)
+        here = (chosen >= lo) & (chosen < lo + H)             # (S, k)
+        key = jnp.where(here, chosen - lo, H).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        inv = jnp.argsort(order)
+        sizes = load[lo:lo + H].astype(jnp.int32)
+        xs = _rows_of(x, order, inv, here, k)                 # (S·k, U)
+    with jax.named_scope(_EXPERTS):
+        # the rows the buffer really holds come first
+        real = (jnp.arange(S * k) < jnp.sum(sizes))[:, None]
+
+        def grouped(a, w):
+            # XLA:TPU's kernel leaves the rows that no group owns UNWRITTEN,
+            # whatever the buffer held before (seen on the chip, PR 27: NaN
+            # by the third step of a toy decoder); they are cleared before
+            # anything reads them, forward and, by where()'s transpose,
+            # backward.  The select fuses into the SwiGLU that follows.
+            return jnp.where(real, jax.lax.ragged_dot(a, w, sizes), 0)
+        h = jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3)
+        ys = grouped(h, w2)                                   # (S·k, U)
+    with jax.named_scope(_COMBINE):
+        y_tok = _unsort(ys, order, inv).reshape(S, k, U)
+        # where(), not a zero weight: select is safe whatever a row holds
+        y = jnp.sum(jnp.where(here[..., None], weights[..., None]
+                              * y_tok.astype(jnp.float32), 0.0), axis=1)
+    return y.astype(x.dtype), load.astype(jnp.float32)
+
+
+class DroplessMoE(HybridBlock):
+    """Sparse SwiGLU experts without dropped tokens, for one holder of an
+    expert-parallel layer.
+
+    forward(x: (..., units)) -> y: (..., units), the sum over each token's
+    chosen experts THAT ARE HELD HERE of `w_i · E_i(x)`, plus `shared(x)`
+    if a shared block is given.  Scores are sigmoid(x · gate) in f32 over
+    all `num_experts`; the `top_k` largest of score + `select_bias` are
+    chosen (the bias is a buffer without gradient, for the choice only);
+    weights are the chosen scores normalised over ALL chosen (held or
+    not), times `scaling`.  What the absent experts would add is left
+    out; nothing stands in for them or their exchange.
+
+    Expert weights are stacked (held, in, out), the layout
+    `jax.lax.ragged_dot` takes; `moe_sharding_rules()` shards their leading
+    axis over `ep`.  The buffers `expert_load` (rows each of the num_experts
+    experts was chosen for in each of the last LOAD_HISTORY training steps,
+    a ring) and `steps_counted` are read by `load_census(net)`."""
+
+    def __init__(self, units, hidden_size, num_experts, top_k,
+                 held_experts=None, scaling=1.0, shared=None, **kwargs):
+        super().__init__(**kwargs)
+        held = range(num_experts) if held_experts is None else held_experts
+        if list(held) != list(range(held.start, held.stop)) or not \
+                0 <= held.start < held.stop <= num_experts:
+            raise ValueError(f"held_experts must be a contiguous range "
+                             f"within {num_experts} experts, got {held!r}")
+        if not 1 <= top_k <= num_experts:
+            raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        self._units, self._hidden, self._E, self._k = \
+            units, hidden_size, num_experts, top_k
+        self._held, self._scaling = held, float(scaling)
+        n = len(held)
+        self.gate_weight = self.params.get(
+            "gate_weight", shape=(num_experts, units))
+        self.select_bias = self.params.get(
+            "select_bias", shape=(num_experts,), init="zeros",
+            grad_req="null")
+        self.expert_w1 = self.params.get(
+            "expert_w1", shape=(n, units, hidden_size))
+        self.expert_w3 = self.params.get(
+            "expert_w3", shape=(n, units, hidden_size))
+        self.expert_w2 = self.params.get(
+            "expert_w2", shape=(n, hidden_size, units))
+        self.expert_load = self.params.get(
+            "expert_load", shape=(LOAD_HISTORY, num_experts), init="zeros",
+            grad_req="null")
+        self.steps_counted = self.params.get(
+            "steps_counted", shape=(1,), init="zeros", grad_req="null")
+        if shared is not None:
+            self.shared = shared
+
+    @property
+    def held_experts(self):
+        return self._held
+
+    def cast(self, dtype):
+        """The choice's bias and the counters stay f32: a count of 32,768
+        rows is not a bf16 number."""
+        super().cast(dtype)
+        for p in (self.select_bias, self.expert_load, self.steps_counted):
+            p.cast("float32")
+
+    def hybrid_forward(self, F, x, gate_weight, select_bias, expert_w1,
+                       expert_w3, expert_w2, expert_load, steps_counted):
+        shape = x.shape
+
+        def fn(xa, gw, bias, w1, w3, w2):
+            y, load = _dropless_forward(
+                xa.reshape((-1, shape[-1])), gw, bias, w1, w3, w2,
+                top_k=self._k, lo=self._held.start, scaling=self._scaling)
+            return y.reshape(shape), load
+
+        y, load = ops._apply(
+            fn, [x, gate_weight, select_bias, expert_w1, expert_w3,
+                 expert_w2], "DroplessMoE")
+        if autograd.is_training():
+            with autograd.pause():
+                history, count, load = (getattr(a, "_data", a) for a in
+                                        (expert_load, steps_counted, load))
+                slot = count[0].astype(jnp.int32) % LOAD_HISTORY
+                self.expert_load._register_mutation(
+                    history.at[slot].set(load))
+                self.steps_counted._register_mutation(count + 1)
+        if "shared" in self._children:
+            with jax.named_scope(_SHARED):
+                y = y + self.shared(x)
+        return y
+
+    def __repr__(self):
+        return (f"DroplessMoE(units={self._units}, hidden={self._hidden}, "
+                f"experts={self._E}, held={self._held}, top_k={self._k})")
+
+
+def load_census(net):
+    """What every DroplessMoE under `net` counted in its last training
+    steps, fetched from the device now (and only now: the steps themselves
+    never wait for it): [{"layer", "held": (lo, hi), "expert_load": [E
+    floats] of the step last run, "rows_routed_here", "max_expert_load",
+    "rows_routed_here_history": the last LOAD_HISTORY steps' at most, the
+    oldest first}] in the order the blocks were added.  A net that a
+    CompiledTrainStep trains holds the step's values after
+    `step.sync_to_net()`.  Also sets the gauges moe.rows_routed_here and
+    moe.max_expert_load, a layer each."""
+    layers = []
+    net.apply_fn(lambda b: isinstance(b, DroplessMoE) and layers.append(b))
+    out = []
+    for layer in layers:
+        ring = jax.device_get(layer.expert_load.data()._data)
+        count = int(jax.device_get(layer.steps_counted.data()._data)[0])
+        kept = min(count, LOAD_HISTORY)
+        history = ring[[(count - kept + i) % LOAD_HISTORY
+                        for i in range(kept)]]
+        held = layer.held_experts
+        load = (history[-1] if kept else ring[0]).tolist()
+        mine = load[held.start:held.stop]
+        entry = {"layer": layer.name, "held": (held.start, held.stop),
+                 "expert_load": load, "rows_routed_here": sum(mine),
+                 "max_expert_load": max(mine),
+                 "rows_routed_here_history":
+                     history[:, held.start:held.stop].sum(axis=1).tolist()}
+        _telemetry.gauge("moe.rows_routed_here", layer=layer.name).set(
+            entry["rows_routed_here"])
+        _telemetry.gauge("moe.max_expert_load", layer=layer.name).set(
+            entry["max_expert_load"])
+        out.append(entry)
+    return out

@@ -139,6 +139,11 @@ KNOWN_METRICS = frozenset({
     # compiled train step (tpu_mx/parallel/train_step.py)
     "train_step.seconds", "train_step.steps", "train_step.recompiles",
     "train_step.examples_per_sec",
+    # dropless expert layer (tpu_mx/parallel/moe.py DroplessMoE; gauges,
+    # a layer each, set by load_census(net) from the counter the last
+    # training step wrote on the device): rows routed to the experts held
+    # here, and the fullest held expert's rows
+    "moe.rows_routed_here", "moe.max_expert_load",
     # kvstore eager path (tpu_mx/kvstore.py).  checksums counts payload
     # digests recorded at push time, checksum_failures the pulls whose
     # aggregate no longer matched — silent corruption crossing the sync
