@@ -148,6 +148,27 @@ def test_determinism_fires_on_stray_rng():
     assert set(rules_of(found)) == {"determinism"}
 
 
+def test_determinism_flags_a_dropout_site_drawing_its_own_mask():
+    found, _ = run("""
+        import jax
+
+        def Dropout(x, key, p):
+            keep = jax.random.bernoulli(key, 1.0 - p, x.shape)
+            return x * keep
+
+        def attend(p, dropout_key, dropout_rate):
+            return jax.random.bernoulli(dropout_key, 1.0 - dropout_rate,
+                                        p.shape)
+
+        def random_bernoulli(key, prob, shape):      # the sampling API
+            return jax.random.bernoulli(key, prob, shape)
+
+        def variational_dropout(F, keep, like):      # through the API
+            return F.random.bernoulli(prob=keep, shape=like.shape)
+        """, "tpu_mx/foo.py", rules={"determinism"})
+    assert len(found) == 2 and all("dropout_keep" in f.message for f in found)
+
+
 def test_determinism_silent_on_blessed_patterns():
     # seeded private RandomState (iterator pattern), host_rng() routing,
     # and take_key() are all contract-compliant

@@ -185,7 +185,10 @@ class DeterminismPass(Pass):
     capsule-covered stream is explicit), fresh ``jax.random.PRNGKey``
     streams (escape the capsule entirely), entropy-seeded
     ``RandomState()``/``default_rng()`` (irreproducible by
-    construction), and time-seeded RNG anywhere.  A *seeded* private
+    construction), a dropout site that draws its own mask with
+    ``jax.random.bernoulli`` (every keep mask comes from
+    ``tpu_mx.random.dropout_keep``: one generator, one place that says
+    what a mask is a function of), and time-seeded RNG anywhere.  A *seeded* private
     ``RandomState(seed)`` is NOT flagged — that is the blessed pattern
     for iterators that snapshot their own stream via ``state_dict()``.
     """
@@ -214,6 +217,15 @@ class DeterminismPass(Pass):
         return any(not (isinstance(kw.value, ast.Constant)
                         and kw.value.value is None)
                    for kw in call.keywords if kw.arg is not None)
+
+    @staticmethod
+    def _dropout_site(ctx, call):
+        """A ``bernoulli`` call whose enclosing function or arguments name
+        dropout: the sampling API (``random_bernoulli``) does neither."""
+        where = (func_qual(ctx, call) or "") + " ".join(
+            expr_text(a) for a in
+            list(call.args) + [kw.value for kw in call.keywords])
+        return "drop" in where.lower()
 
     def _time_seeded(self, node):
         for sub in ast.walk(node):
@@ -270,6 +282,17 @@ class DeterminismPass(Pass):
                     f"fresh {parts[-1]} stream escapes the "
                     "process-global tpu_mx.random state — resume capsules "
                     "cannot replay it; use tpu_mx.random.take_key()")
+            # a dropout site drawing its own keep mask
+            elif (".".join([ctx.mod_alias.get(parts[0], parts[0])]
+                           + parts[1:]) == "jax.random.bernoulli"
+                    and self._dropout_site(ctx, node)):
+                yield ctx.finding(
+                    self.name, node,
+                    f"{fn} at a dropout site — draw the keep mask through "
+                    "tpu_mx.random.dropout_keep (or apply it with "
+                    "tpu_mx.random.dropout): its bits come from the "
+                    "chip's generator, and its module says what a mask "
+                    "is a function of")
             # entropy-seeded private streams (a seed passed positionally
             # OR as seed=/... keyword makes the stream reproducible)
             elif parts[-1] in self.SEEDED_CTORS and (

@@ -264,6 +264,40 @@ def check_flash_dropout():
             "dir_deriv_rel_err": rel, "fraction_changed": ratio}
 
 
+def check_dropout_mask_generator():
+    """Dropout masks on the chip (ISSUE 28: bits from rng_bit_generator,
+    drawn again in the backward pass): at BERT-base's two mask shapes,
+    under jit, the keep share, forward and backward on one mask (the
+    gradient is nonzero exactly where the output is), run-to-run equality
+    for one key and a different mask for its split's other half."""
+    import jax
+    import jax.numpy as jnp
+    from tpu_mx import random as R
+    rate, out = 0.1, {}
+    left, right = jax.random.split(jax.random.PRNGKey(28))
+    for name, shape, dtype in (("hidden", (192, 128, 768), jnp.bfloat16),
+                               ("attention", (192, 12, 128, 128),
+                                jnp.float32)):
+        x = jnp.ones(shape, dtype)
+        step = jax.jit(jax.value_and_grad(
+            lambda x, key: R.dropout(x, key, rate).astype(jnp.float32).sum()))
+        (_, g1), (_, g2) = step(x, left), step(x, left)
+        y = jax.jit(lambda x, key: R.dropout(x, key, rate))(x, left)
+        if not bool((g1 == g2).all()):
+            raise AssertionError(f"{name}: one key, two masks")
+        if not bool(((g1 != 0) == (y != 0)).all()):
+            raise AssertionError(f"{name}: forward and backward masks differ")
+        share = float((y != 0).mean())
+        if abs(share - (1 - rate)) > 0.002:
+            raise AssertionError(f"{name}: keep share {share}")
+        other = jax.jit(lambda x, key: R.dropout(x, key, rate))(x, right)
+        agree = float(((y != 0) == (other != 0)).mean())
+        if abs(agree - 0.82) > 0.01:
+            raise AssertionError(f"{name}: a split's halves agree on {agree}")
+        out[name] = {"keep_share": share, "halves_agree": agree}
+    return out
+
+
 @_highest_precision
 def check_flash_kv_valid():
     """Ragged key-padding masks (kv_valid) vs dense mask oracle."""
@@ -664,6 +698,7 @@ CHECKS = [
     ("attention_auto_dispatch", check_attention_auto_dispatch),
     ("flash_bias_layouts", check_flash_bias_layouts),
     ("flash_dropout_inkernel", check_flash_dropout),
+    ("dropout_mask_generator", check_dropout_mask_generator),
     ("flash_kv_valid", check_flash_kv_valid),
     ("flash_t2048", check_flash_t2048),
     ("ring_inner_chunking_t2048", check_ring_inner_chunking),

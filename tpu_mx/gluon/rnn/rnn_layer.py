@@ -18,6 +18,7 @@ from jax import lax
 from ..block import HybridBlock
 from ...ndarray import NDArray
 from ...ndarray.ops import _apply
+from ... import random as _random
 
 __all__ = ["RNN", "LSTM", "GRU"]
 
@@ -103,8 +104,7 @@ def rnn_fused_core(mode, num_layers, bidirectional, dropout, x, init_states,
         if dropout > 0 and training and layer < num_layers - 1 and \
                 rng_key is not None:
             rng_key, sub = jax.random.split(rng_key)
-            keep = jax.random.bernoulli(sub, 1 - dropout, outs.shape)
-            outs = jnp.where(keep, outs / (1 - dropout), 0.0).astype(outs.dtype)
+            outs = _random.dropout(outs, sub, dropout)
     h_out = jnp.stack(h_finals)
     if mode == "lstm":
         return outs, h_out, jnp.stack(c_finals)
@@ -179,7 +179,6 @@ class _RNNLayer(HybridBlock):
                 p.shape_hint((ng * self._hidden_size, sz))
 
     def forward(self, inputs, states=None):
-        from ... import autograd, random as _random
         for name, p in self._reg_params.items():
             if p._data is None and p._shape_incomplete():
                 self.infer_shape(inputs)
@@ -187,7 +186,7 @@ class _RNNLayer(HybridBlock):
         return super().forward(inputs, states)
 
     def hybrid_forward(self, F, inputs, states=None, **params):
-        from ... import autograd, random as _random
+        from ... import autograd
         skip_states = states is None
         if self._layout == "NTC":
             inputs = F.swapaxes(inputs, 0, 1)

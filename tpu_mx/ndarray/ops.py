@@ -1220,11 +1220,10 @@ def Dropout(data, p=0.5, mode="training", axes=None, **kw):
     key = _random.take_key()
 
     def f(x):
-        shape = x.shape
+        shape = None
         if axes:
             shape = tuple(1 if i in axes else s for i, s in enumerate(x.shape))
-        keep = jax.random.bernoulli(key, 1.0 - p, shape)
-        return jnp.where(keep, x / (1.0 - p), jnp.zeros((), x.dtype)).astype(x.dtype)
+        return _random.dropout(x, key, p, shape)
 
     return _apply(f, [data], "Dropout")
 

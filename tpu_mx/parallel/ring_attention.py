@@ -16,7 +16,8 @@ call `attention()` unconditionally and get ring behavior exactly when the
 mesh has an `sp` axis.
 
 Attention-prob dropout: on the ring and dense paths the keep-mask is drawn
-per (device, ring-step) from a folded key; on the local TPU path it runs
+per (device, ring-step) from a folded key (`random.dropped`: XLA's
+rng_bit_generator, drawn again in the backward pass); on the local TPU path it runs
 inside the Pallas kernel's PRNG (kernels.flash_attention).  The softmax
 normalizer always uses the un-dropped probabilities.
 """
@@ -31,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from .. import random as _random
 
 __all__ = ["ring_attention", "attention", "local_flash_attention",
            "dispatch_counts"]
@@ -54,8 +57,10 @@ def _auto_prefers_flash(q_len, kv_len, dropped, on_tpu):
     dropout 0.1), samples/s dense -> flash: T 128 895.7 -> 774.2 (-13.6%),
     T 256 370.5 -> 404.5 (+9.2%), T 512 135.9 -> 203.7 (+49.9%).  Dense
     writes the (q_len, kv_len) scores of every head to HBM, keeps them for
-    the backward pass and draws their dropout mask from threefry (76 of
-    its 166 ms at T 512); the kernel holds one head's block in VMEM and
+    the backward pass and draws their dropout mask twice (threefry then,
+    76 of its 166 ms at T 512; XLA's rng_bit_generator since PR 28, which
+    costs this site about the same: PERF.md section 6, PR 28); the
+    kernel holds one head's block in VMEM and
     pays a fixed cost a grid step instead, which is what loses at T 128.
     With that dropout off, ms a step dense against flash: T 256 219.1
     against 235.3, T 512 274.8 against 230.2.
@@ -110,17 +115,24 @@ def _block_attn(q, k, v, bias=None, mask=None, scale=1.0,
     m_safe = jnp.maximum(m, -1e30)
     p = jnp.exp(s - m_safe[..., None])                        # (B,H,Tq,Tk)
     l = jnp.sum(p, axis=-1)                                   # (B,H,Tq)
-    if dropout_rate > 0.0 and dropout_key is not None:
-        keep = jax.random.bernoulli(dropout_key, 1.0 - dropout_rate, p.shape)
-        p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
     # probs cast to v.dtype for the AV matmul (flash-kernel numerics: the
     # softmax stats m/l stay f32, only the normalized weights round).  On
     # the dense path p is a materialized (B,H,Tq,Tk) HBM tensor and the
     # default MXU precision truncates f32 dot operands to bf16 anyway —
     # keeping p f32 paid double the HBM bytes for no extra matmul
     # precision; f32 accumulation is preserved via preferred_element_type.
-    o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
-                   preferred_element_type=jnp.float32)        # (B,H,Tq,D)
+    def weighted(p, v):
+        return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)  # (B,H,Tq,D)
+    if dropout_rate > 0.0 and dropout_key is not None:
+        # mask and product as one site: alone, the masked probabilities
+        # would be held for the product's backward pass
+        o = _random.dropped(
+            lambda keep, p, v: weighted(
+                _random.scaled(keep, p, dropout_rate), v),
+            dropout_key, dropout_rate, p.shape, p, v)
+    else:
+        o = weighted(p, v)
     return m_safe, l, o
 
 

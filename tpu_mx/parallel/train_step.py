@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import random as _random
 from .. import telemetry as _telemetry
 from .. import tracing as _tracing
 from ..ndarray import NDArray
@@ -342,7 +343,10 @@ class CompiledTrainStep:
             def lfn(dv):
                 pm = dict(const_vals)
                 pm.update(dv)
-                out, updates = net._functional_call(pm, key, True, data_args)
+                with _random.partitioned_draws(mesh is not None
+                                               and mesh.size > 1):
+                    out, updates = net._functional_call(pm, key, True,
+                                                        data_args)
                 if isinstance(out, (tuple, list)):
                     # multi-output nets: the step trains on the FIRST
                     # output only.  That silently drops e.g. an MoE aux
@@ -718,7 +722,6 @@ class CompiledTrainStep:
             return self._step_phases(batch, lr, expect_gen)
 
     def _step_phases(self, batch, lr, expect_gen):
-        from .. import random as _random
         from ..contrib import chaos as _chaos
         with _tracing.phase("data_wait") as waited:
             # chaos straggler injection (ISSUE 18) lands INSIDE data_wait:

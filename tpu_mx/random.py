@@ -15,6 +15,21 @@ make a crash-recovered run replay the exact RNG stream of the run that died.
 :func:`seed` returns the prior token so tests (and capsule writers) can
 save/restore the stream around themselves.
 
+Dropout masks (:func:`dropout_keep`, and :func:`dropped` / :func:`dropout`
+that apply one) are the one draw that does not come from threefry.  A mask
+is a function of the sub-key its site took from this stream, of the mask's
+shape and the rate, **and of the compiled program and the backend** (under
+`shard_map`, of the device's mesh coordinates too, through the key): the
+same key, program and hardware give the same mask every time (what the resume capsule's
+bit-exact replay, `ShadowAuditor`'s re-execution and the cross-replica
+fingerprint rest on), forward and backward of one step draw the same one,
+and a split's two halves give independent ones.  It is NOT portable: a CPU
+and a TPU, or two jax versions, drop different (equally random) elements
+for one seed.  A step that GSPMD partitions over a mesh keeps threefry's
+masks, which do not depend on the mesh (:func:`partitioned_draws`).  The
+key stream itself (splits, `get_state`/`set_state`, the step's `key` input)
+is threefry as before and is portable.
+
 The global key is genuinely process-global (one lock-guarded stream): a step
 function running on a watchdog daemon thread (`supervisor.run_with_deadline`)
 draws from the SAME stream the main thread would — a thread-local key would
@@ -23,12 +38,20 @@ silently hand every watchdog thread its own fresh `PRNGKey(0)` replay.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 
 import jax
+import jax.numpy as jnp
 
 __all__ = ["seed", "get_state", "set_state", "take_key", "host_rng",
-           "KeyHolder", "key_scope"]
+           "KeyHolder", "key_scope", "dropout", "dropout_keep", "dropped",
+           "scaled", "partitioned_draws", "mask_draws"]
+
+# Dropout masks drawn through dropout_keep(), counted where the call is
+# traced (once a compilation under jit, once a call in eager mode), like
+# ring_attention.dispatch_counts.  The benchmark's `dropout_rbg_draws` reads it.
+mask_draws = {"rbg": 0}
 
 
 class _GlobalRNG:
@@ -71,6 +94,137 @@ def take_key():
     with _GLOBAL.lock:
         _GLOBAL.key, sub = jax.random.split(_GLOBAL.key)
     return sub
+
+
+def _raw(key):
+    """The words of a key, typed or already raw."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        return jax.random.key_data(key)
+    return key
+
+
+def dropout_keep(key, rate, shape):
+    """The keep mask of every dropout site: bool, True with probability
+    ``1 - rate``, independently an element, from 32 fresh bits each.
+
+    `key` is the sub-key the site took from the stream (`take_key()`, or
+    a fold of the ring's seed), typed or raw.  Its words seed an ``rbg``
+    key, so the bits lower to XLA's ``rng_bit_generator`` (on a TPU the
+    core's hardware generator) and not to twenty rounds of threefry2x32
+    an element on the VPU; the comparison is `jax.random.bernoulli`'s
+    own.  The module text says what the mask is a function of."""
+    words = _raw(key).astype(jnp.uint32).reshape(-1)
+    # impl="rbg" wants four words: the key's own, repeated, as jax seeds
+    # its rbg keys from a threefry half-key
+    rbg_key = jax.random.wrap_key_data(jnp.tile(words, 4 // words.size),
+                                       impl="rbg")
+    mask_draws["rbg"] += 1
+    return jax.random.bernoulli(rbg_key, 1.0 - rate, shape)
+
+
+def _after(key, x):
+    """`key`, unchanged, but not before `x` exists.  The comparison is
+    False for every number (True only for a NaN, which has poisoned the
+    step anyway) and XLA cannot fold it, so the draw that takes this key
+    is scheduled where its mask is used.  Without it every draw of a
+    step, a function of the step's key alone, is hoisted to the
+    program's start, and the generator's words (four bytes an element)
+    wait there for their consumers."""
+    x = jax.tree_util.tree_leaves(x)[0]
+    if not x.size:
+        return key
+    first = x.reshape(-1)[0]
+    return key ^ (first != first).astype(key.dtype)
+
+
+# Dropout sites in the order they were traced; `_site_bwd` asks whether
+# its site is the latest one.
+_sites = [0]
+
+
+@contextlib.contextmanager
+def partitioned_draws(on=True):
+    """Inside, a dropout mask is `jax.random.bernoulli` on the site's
+    threefry key, as before ISSUE 28: for a program that GSPMD partitions
+    over a mesh (`CompiledTrainStep(mesh=...)` enters this around the
+    net's forward).  XLA's partitioner does not split
+    ``rng_bit_generator``: every chip draws the mask of the global batch
+    (`u32[768,128,768]` on each of four chips in the compiled text of a
+    dp=4 step for a described v5e:2x2, PERF.md section 6, PR 28), and the
+    tie of `_after` becomes an all-reduce a site.  Threefry is elementwise
+    and partitionable: each chip draws its shard, and the mask does not
+    depend on the mesh.  A `shard_map` body sees local shapes and needs
+    none of this."""
+    prev = getattr(_HOLDER, "partitioned", False)
+    _HOLDER.partitioned = bool(on)
+    try:
+        yield
+    finally:
+        _HOLDER.partitioned = prev
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 3, 4))
+def _site(fn, n, key, rate, shape, *operands):
+    return _site_fwd(fn, n, key, rate, shape, *operands)[0]
+
+
+def _site_fwd(fn, n, key, rate, shape, *operands):
+    keep = dropout_keep(_after(key, operands), rate, shape)
+    return fn(keep, *operands), (key, keep, operands)
+
+
+def _site_bwd(fn, n, rate, shape, held, g):
+    key, keep, operands = held
+    if n != _sites[0]:
+        # the same key, so the same mask, drawn when the cotangent is
+        # there; the forward's `keep` is then dead code to XLA
+        keep = dropout_keep(_after(key, g), rate, shape)
+    _, pull = jax.vjp(functools.partial(fn, keep), *operands)
+    return (None, *pull(g))
+
+
+_site.defvjp(_site_fwd, _site_bwd)
+
+
+def dropped(fn, key, rate, shape, *operands):
+    """``fn(keep, *operands)`` at a dropout site, `keep` being
+    ``dropout_keep(key, rate, shape)``.
+
+    The backward pass does not hold the mask: it draws it again from the
+    same key (as XLA recomputes an elementwise threefry mask inside its
+    backward fusions) and runs `fn` again under `jax.vjp`.
+    `rng_bit_generator` is an operation of its own to XLA:TPU, so a mask
+    that autodiff holds costs its 32-bit words from forward to backward
+    (+2.5 GiB in BERT-base's step at 24,576 tokens), or a byte an element
+    behind a barrier (+0.8 GiB).  Give `fn` everything that would
+    otherwise hold a masked tensor: attention passes the
+    probabilities-times-V product with its mask.
+
+    One site keeps its bool mask: the latest one traced, which the
+    backward pass reaches first.  A generator call there opens the
+    backward pass, XLA's scheduler fills the wait with the loss head's
+    largest temporaries, and the step's peak rises by 0.25 GiB
+    (`bert-base.mlm512`; PERF.md section 6, PR 28).  Under jit the other
+    sites' forward masks are dead code; in eager mode each is held, a
+    byte an element, until its backward pass."""
+    if getattr(_HOLDER, "partitioned", False):
+        return fn(jax.random.bernoulli(key, 1.0 - rate, shape), *operands)
+    _sites[0] += 1
+    return _site(fn, _sites[0], _raw(key), rate, tuple(shape), *operands)
+
+
+def scaled(keep, x, rate):
+    """Inverted dropout's arithmetic on a mask that is there."""
+    return jnp.where(keep, x / (1.0 - rate),
+                     jnp.zeros((), x.dtype)).astype(x.dtype)
+
+
+def dropout(x, key, rate, mask_shape=None):
+    """Inverted dropout of `x`: kept elements scaled by ``1 / (1 - rate)``,
+    the rest zero, in `x`'s dtype.  `mask_shape` (default `x`'s) may hold
+    1s to share one draw along those axes."""
+    return dropped(lambda keep, x: scaled(keep, x, rate), key, rate,
+                   mask_shape or x.shape, x)
 
 
 def host_rng():
