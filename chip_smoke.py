@@ -89,7 +89,7 @@ def bert_config(tiny, seq_len):
 
 def mlm_batch(cfg, batch, seq_len):
     """One fixed synthetic MLM batch: the vocab head runs only on the 15%
-    masked positions, as in bench.py's BERT legs."""
+    masked positions, as in the benchmark's BERT cells."""
     import numpy as np
     rng = np.random.RandomState(SEED)
     tokens = rng.randint(4, cfg["vocab_size"], (batch, seq_len)).astype(
@@ -105,7 +105,7 @@ def mlm_batch(cfg, batch, seq_len):
 def train(tag, cfg, batch, seq_len, steps, compiles, remat=False,
           mesh_axes=None, presharded=False):
     """Build BERT + LAMB + CompiledTrainStep the way examples/bert/
-    pretrain.py and bench.py do and take `steps` steps on one fixed batch.
+    pretrain.py does and take `steps` steps on one fixed batch.
     Returns (losses, step)."""
     import jax
     import tpu_mx as mx

@@ -2,13 +2,18 @@
 
 Equivalence contract: a fused segment executes the same primitive
 sequence as eager dispatch, compiled as one XLA program.  Forward AND
-backward are asserted BIT-IDENTICAL for every covered chain here.  The
-one documented numerics divergence — XLA contracting a multiply that
-feeds an add into an FMA inside a fused loop (excess precision, the more
-accurate result) — gets its own test with the jit ground-truth oracle.
+backward are asserted BIT-IDENTICAL with eager for every covered chain
+here, but for the one documented numerics divergence: XLA contracts a
+multiply that feeds an add into an FMA inside a fused loop (excess
+precision, the more accurate result).  Where a chain or its derivative
+holds one (jax 0.9 writes tanh's as 1 - y*y), the fused result is held
+bit for bit to the same chain under ONE jax.jit, which is what a segment
+is, and to eager within a unit in the last place of the gradient's scale.
 """
 import os
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -54,6 +59,28 @@ def test_fused_forward_bit_identical(name):
     assert engine.bulk_stats()["segments_flushed"] >= 1
 
 
+# the chains whose DERIVATIVE holds a multiply->add, as jax.numpy
+# composites of (x, scalars): fusion passes scalars as weak-typed arguments
+ONE_JIT_TWINS = {
+    "unary": (lambda v, s: jnp.tanh(jnp.sin(jnp.exp(v * s))), (0.25,)),
+}
+
+
+def _assert_backward_of_one_program(fused, eager, composite, scalars):
+    """`fused` is the gradient a flushed segment gave: bit for bit what ONE
+    jitted pullback of the same composite gives (fusion.flush's `pullback`),
+    and per-op eager's within one unit in the last place of its scale."""
+    def pullback(xv, *s):
+        out, vjp = jax.vjp(lambda v: composite(v, *s), xv)
+        return vjp(jnp.ones_like(out))[0]
+
+    truth = jax.jit(pullback)(_x()._data, *map(jnp.asarray, scalars))
+    np.testing.assert_array_equal(fused, np.asarray(truth))
+    np.testing.assert_allclose(
+        eager, fused, rtol=0,
+        atol=np.finfo(np.float32).eps * max(1.0, np.abs(fused).max()))
+
+
 @pytest.mark.parametrize("name", sorted(CHAINS))
 def test_fused_backward_bit_identical(name):
     chain = CHAINS[name]
@@ -67,15 +94,17 @@ def test_fused_backward_bit_identical(name):
         with engine.bulk(64):
             lf = chain(xf).sum()
     lf.backward()
-    np.testing.assert_array_equal(xe.grad.asnumpy(), xf.grad.asnumpy())
+    if name in ONE_JIT_TWINS:
+        _assert_backward_of_one_program(
+            xf.grad.asnumpy(), xe.grad.asnumpy(), *ONE_JIT_TWINS[name])
+    else:
+        np.testing.assert_array_equal(xe.grad.asnumpy(), xf.grad.asnumpy())
 
 
 def test_fma_chain_matches_jit_ground_truth():
     """multiply->add chains: XLA contracts into FMA inside a fused loop.
     The fused result must equal jax.jit of the same composite exactly
     (one-program semantics, same as hybridize) and eager to ~1 ulp."""
-    import jax
-    import jax.numpy as jnp
     x = _x((16, 16))
     b = nd.array(np.linspace(0.1, 1.1, 16), dtype="float32")
 
@@ -196,7 +225,9 @@ def test_flush_barrier_backward():
     with autograd.record():
         ye = nd.tanh(xe) * 2.0
     ye.backward()
-    np.testing.assert_array_equal(x.grad.asnumpy(), xe.grad.asnumpy())
+    _assert_backward_of_one_program(
+        x.grad.asnumpy(), xe.grad.asnumpy(),
+        lambda v, s: jnp.tanh(v) * s, (2.0,))
 
 
 def test_lazy_metadata_does_not_flush():
@@ -492,9 +523,9 @@ def test_sgd_update_fuses_parameter_sweep():
 @pytest.mark.slow
 def test_fused_speedup_on_pointwise_chain():
     """Acceptance bar: >= 1.5x on a >= 32-op elementwise chain after
-    cache warm-up (dispatch-overhead regime).  bench.py's fusion leg is
-    the official measurement; this is the regression tripwire at a lower
-    threshold so host noise can't flake it."""
+    cache warm-up (dispatch-overhead regime), on whatever host runs the
+    tests: a tripwire at a lower threshold so host noise can't flake it,
+    not a measurement (nothing has measured fusion on the chip)."""
     import time
     x = nd.array(np.random.RandomState(0).rand(64, 64).astype(np.float32))
 

@@ -1062,103 +1062,3 @@ def test_ring_attention_long_seq_chunked():
     ref = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
-
-
-def test_fused_flat_update_matches_per_param(monkeypatch):
-    """The fused flat-concat update (mesh=None + elementwise optimizer)
-    must produce bit-identical training to the per-param path, including
-    bf16 params with f32 masters (multi_precision) and momentum state."""
-    from tpu_mx.parallel import CompiledTrainStep
-
-    def build():
-        mx.random.seed(11)
-        net = nn.HybridSequential()
-        net.add(nn.Dense(16, activation="relu"), nn.Dense(16,
-                activation="relu"), nn.Dense(4))
-        net.initialize()
-        net(nd.ones((1, 8)))
-        net.cast("bfloat16")
-        return net
-
-    x = nd.cast(nd.array(np.random.RandomState(0).rand(8, 8)
-                         .astype(np.float32)), "bfloat16")
-    y = nd.array(np.random.RandomState(1).randint(0, 4, (8,)),
-                 dtype="float32")
-    results = []
-    for fused in ("1", "0"):
-        monkeypatch.setenv("TPUMX_FUSED_UPDATE", fused)
-        net = build()
-        opt = mx.optimizer.create("sgd", learning_rate=0.1, momentum=0.9,
-                                  wd=1e-4, multi_precision=True)
-        step = CompiledTrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-                                 opt, mesh=None)
-        losses = [float(step.step(x, y).asscalar()) for _ in range(4)]
-        if fused == "1":
-            # the fused path must actually engage (>1 param per group)
-            assert step._fuse_groups and \
-                sum(len(g) for g in step._fuse_groups) >= 2, \
-                step._fuse_groups
-        step.sync_to_net()
-        w = {k: p.data().asnumpy().astype(np.float32)
-             for k, p in net.collect_params().items()}
-        m = {k: np.asarray(v) for k, v in step.masters.items()}
-        results.append((losses, w, m))
-    (l1, w1, m1), (l2, w2, m2) = results
-    np.testing.assert_array_equal(l1, l2)
-    # auto-generated name prefixes differ between builds: align by
-    # insertion order (same construction order => same param order)
-    for (ka, a), (kb, b) in zip(list(w1.items()), list(w2.items())):
-        np.testing.assert_array_equal(a, b, err_msg=f"{ka} vs {kb}")
-    for a, b in zip(list(m1.values()), list(m2.values())):
-        np.testing.assert_array_equal(a, b)
-
-
-def test_fused_update_groups_respect_mults():
-    """Params with distinct lr_mult/wd_mult must not be folded into one
-    flat group (their update programs differ)."""
-    from tpu_mx.parallel import CompiledTrainStep
-    net = nn.HybridSequential()
-    net.add(nn.Dense(8, activation="relu"), nn.Dense(4))
-    net.initialize()
-    net(nd.ones((1, 6)))
-    params = net.collect_params()
-    first = list(params.keys())[0]
-    params[first].lr_mult = 0.5
-    # build the per-param oracle net FIRST and copy weights before any
-    # step runs: donation deletes the source net's live buffers
-    net2 = nn.HybridSequential()
-    net2.add(nn.Dense(8, activation="relu"), nn.Dense(4))
-    net2.initialize()
-    net2(nd.ones((1, 6)))
-    p2 = net2.collect_params()
-    for (k1, v1), (k2, v2) in zip(list(params.items()), list(p2.items())):
-        v2.set_data(nd.array(v1.data().asnumpy()))
-        v2.lr_mult = v1.lr_mult
-    opt = mx.optimizer.create("sgd", learning_rate=0.1, momentum=0.9)
-    import os
-    os.environ["TPUMX_FUSED_UPDATE"] = "1"   # opt-in path under test
-    try:
-        step = CompiledTrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-                                 opt, mesh=None)
-        x = nd.array(np.random.RandomState(3).rand(4, 6)
-                     .astype(np.float32))
-        y = nd.array(np.zeros(4), dtype="float32")
-        l0 = float(step.step(x, y).asscalar())
-    finally:
-        os.environ.pop("TPUMX_FUSED_UPDATE", None)
-    try:
-        step2 = CompiledTrainStep(net2,
-                                  gluon.loss.SoftmaxCrossEntropyLoss(),
-                                  mx.optimizer.create(
-                                      "sgd", learning_rate=0.1,
-                                      momentum=0.9), mesh=None)
-        l1 = float(step2.step(x, y).asscalar())
-    finally:
-        os.environ.pop("TPUMX_FUSED_UPDATE", None)
-    np.testing.assert_allclose(l0, l1, rtol=1e-6)
-    step.sync_to_net()
-    step2.sync_to_net()
-    for (ka, a), (kb, b) in zip(list(params.items()), list(p2.items())):
-        np.testing.assert_array_equal(a.data().asnumpy(),
-                                      b.data().asnumpy(),
-                                      err_msg=f"{ka} vs {kb}")

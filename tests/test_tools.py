@@ -281,61 +281,6 @@ def test_bandwidth_tool():
     assert all(r["devices"] == 8 for r in recs)
 
 
-@pytest.mark.slow
-def test_bench_scaling_mode():
-    """BENCH_MODELS=scaling measures weak-scaling efficiency on the
-    virtual mesh (the BASELINE metric-3 harness)."""
-    import json as _json
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=600,
-        env={**os.environ, "BENCH_SMOKE": "1", "BENCH_MODELS": "scaling",
-             "XLA_FLAGS": "--xla_force_host_platform_device_count=8"})
-    assert out.returncode == 0, out.stderr[-500:]
-    rec = _json.loads([l for l in out.stdout.splitlines()
-                       if l.startswith("{")][-1])
-    assert rec["metric"].startswith("weak_scaling_efficiency")
-    assert 0 < rec["value"] <= 1.5
-
-
-@pytest.mark.slow
-def test_bench_lstm_ssd_smoke():
-    """BENCH_MODELS=lstm,ssd (BASELINE workloads 3 and 5) run end-to-end
-    in smoke mode and emit both records."""
-    import json as _json
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=600,
-        env={**_env_cpu(), "BENCH_SMOKE": "1",
-             "BENCH_MODELS": "lstm,ssd"})
-    assert out.returncode == 0, out.stderr[-500:]
-    rec = _json.loads([l for l in out.stdout.splitlines()
-                       if l.startswith("{")][-1])
-    assert rec["metric"] == "lstm_smoke_tokens_per_sec" and rec["value"] > 0
-    assert rec["ssd"]["metric"] == "ssd_smoke_images_per_sec"
-    assert rec["ssd"]["value"] > 0
-
-
-@pytest.mark.slow
-def test_bench_lstm_ssd_smoke_bf16():
-    """The on-chip default dtype path (bf16 cast + multi_precision
-    masters) must execute end-to-end, not only on TPU time: pin the
-    dtype knobs to bfloat16 in smoke (smoke defaults to f32)."""
-    import json as _json
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=600,
-        env={**_env_cpu(), "BENCH_SMOKE": "1",
-             "BENCH_MODELS": "lstm,ssd",
-             "BENCH_LSTM_DTYPE": "bfloat16",
-             "BENCH_SSD_DTYPE": "bfloat16"})
-    assert out.returncode == 0, out.stderr[-500:]
-    rec = _json.loads([l for l in out.stdout.splitlines()
-                       if l.startswith("{")][-1])
-    assert rec["dtype"] == "bfloat16" and rec["value"] > 0
-    assert rec["ssd"]["dtype"] == "bfloat16" and rec["ssd"]["value"] > 0
-
-
 def test_parse_log_table():
     """tools/parse_log.py (REF:tools/parse_log.py analog): Speedometer +
     fit log lines -> per-epoch table."""
@@ -459,56 +404,3 @@ def test_launch_two_process_compiled_train_step(tmp_path):
         loss = step.step(nd.array(x), nd.array(y))
     np.testing.assert_allclose(float(np.asarray(loss._data)),
                                losses["0"], rtol=1e-5)
-
-
-def test_artifact_protocol_merge_and_clobber_guard(tmp_path):
-    """The on-chip artifact write contract (tools/artifact_protocol.py):
-    partial reruns merge (own keys win, sibling rows survive), a TPU-less
-    process refuses to clobber a platform=tpu artifact, cross-platform
-    rows never merge, and writes are atomic + corruption-tolerant."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        from artifact_protocol import (load_prior, merge_prior_sections,
-                                       refuses_clobber, write_atomic)
-    finally:
-        sys.path.pop(0)
-
-    out = str(tmp_path / "artifact.json")
-    # absent / corrupt priors load as {}
-    assert load_prior(out) == {}
-    with open(out, "w") as f:
-        f.write("{not json")
-    assert load_prior(out) == {}
-    with open(out, "w") as f:
-        f.write('["a", "list"]')
-    assert load_prior(out) == {}
-
-    full = {"platform": "tpu",
-            "configs": {"a:1": {"v": 1}, "b:2": {"v": 2}}}
-    write_atomic(out, full)
-    prior = load_prior(out)
-    assert prior == full
-
-    # a TPU-less process must refuse; a TPU process must not
-    assert refuses_clobber(prior, "cpu")
-    assert not refuses_clobber(prior, "tpu")
-    assert not refuses_clobber({}, "cpu")  # nothing to protect
-
-    # partial rerun: own key wins, sibling survives
-    rerun = {"platform": "tpu", "configs": {"b:2": {"v": 99}}}
-    merge_prior_sections(rerun, prior, ("configs",),
-                         require_platform="tpu")
-    assert rerun["configs"] == {"a:1": {"v": 1}, "b:2": {"v": 99}}
-
-    # cross-platform rows never merge
-    cpu_run = {"platform": "cpu", "configs": {"c:3": {"v": 3}}}
-    merge_prior_sections(cpu_run, prior, ("configs",),
-                         require_platform="cpu")
-    assert cpu_run["configs"] == {"c:3": {"v": 3}}
-
-    # without a platform gate the merge is unconditional (longctx mode)
-    ungated = {"flash": {"T=2": {"v": 2}}}
-    merge_prior_sections(ungated, {"flash": {"T=1": {"v": 1}}}, ("flash",))
-    assert ungated["flash"] == {"T=1": {"v": 1}, "T=2": {"v": 2}}
-
-

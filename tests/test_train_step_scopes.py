@@ -5,7 +5,6 @@ the benchmark's copy of both (benchmark/scopes.py)."""
 import glob
 import os
 import re
-import subprocess
 import sys
 import time
 
@@ -63,29 +62,27 @@ def _batch(dtype=None):
     return (nd.cast(x, dtype) if dtype else x), y
 
 
-def _plain(monkeypatch):
+def _plain():
     return CompiledTrainStep(
         _net(), gluon.loss.SoftmaxCrossEntropyLoss(),
         mx.optimizer.create("sgd", learning_rate=0.1)), _batch()
 
 
-def _mp_fused(monkeypatch):
-    monkeypatch.setenv("TPUMX_FUSED_UPDATE", "1")
-    step = CompiledTrainStep(
+def _mp():
+    return CompiledTrainStep(
         _net(dtype="bfloat16"), gluon.loss.SoftmaxCrossEntropyLoss(),
         mx.optimizer.create("sgd", learning_rate=0.1, momentum=0.9,
-                            multi_precision=True))
-    return step, _batch(dtype="bfloat16")
+                            multi_precision=True)), _batch(dtype="bfloat16")
 
 
-def _accum(monkeypatch):
+def _accum():
     return CompiledTrainStep(
         _net(), gluon.loss.SoftmaxCrossEntropyLoss(),
         mx.optimizer.create("sgd", learning_rate=0.1),
         accum_steps=2), _batch()
 
 
-def _compressed(monkeypatch):
+def _compressed():
     mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
     return CompiledTrainStep(
         _net(), gluon.loss.SoftmaxCrossEntropyLoss(),
@@ -107,17 +104,15 @@ def _scopes_in(names):
 # -- (a) inside the program ------
 @pytest.mark.parametrize("build, applies", [
     (_plain, {GRAD, OPTIMIZER, FINGERPRINT}),
-    (_mp_fused, {GRAD, OPTIMIZER, FINGERPRINT}),
+    (_mp, {GRAD, OPTIMIZER, FINGERPRINT}),
     (_accum, {GRAD, GRAD_ACCUM, OPTIMIZER, FINGERPRINT}),
     (_compressed, {GRAD, GRAD_SYNC, OPTIMIZER, FINGERPRINT}),
-], ids=["plain", "mp_fused", "accum2", "compressed_dp2"])
-def test_the_lowered_step_names_its_scopes(build, applies, monkeypatch):
-    step, batch = build(monkeypatch)
+], ids=["plain", "mp", "accum2", "compressed_dp2"])
+def test_the_lowered_step_names_its_scopes(build, applies):
+    step, batch = build()
     module, names = _op_names(step.aot_compiled(*batch))
     assert module == "jit_tpumx_train_step"
     assert _scopes_in(names) == applies
-    if build is _mp_fused:
-        assert step._fuse_groups, "the fused update did not engage"
     # forward and backward part by what JAX transposes
     grad = [n for n in names if f"/{GRAD}/" in n]
     assert [n for n in grad if "transpose(" in n]
@@ -176,7 +171,7 @@ def test_phase_events_are_in_order_and_tile_the_step():
 def test_the_phases_are_annotations_on_the_profilers_timeline(
         tmp_path, bench_scopes):
     import xplane
-    step, batch = _plain(None)
+    step, batch = _plain()
     step.step(*batch).asscalar()
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
@@ -211,7 +206,7 @@ def test_the_phases_are_annotations_on_the_profilers_timeline(
 # -- (c) TPUMX_TRACING=0 ------
 def test_with_tracing_off_no_event_is_recorded_and_the_step_runs():
     tracing.configure(enabled=False)
-    step, batch = _plain(None)
+    step, batch = _plain()
     losses = [float(step.step(*batch).asscalar()) for _ in range(3)]
     assert losses[-1] < losses[0]
     assert tracing.snapshot() == []
@@ -232,13 +227,3 @@ def test_the_benchmarks_literals_equal_the_programs(bench_scopes):
     assert ATTRIBUTION_PHASES == tracing.TRAIN_STEP_PHASES
     assert bench_scopes.STEP_SPAN == "tpu_mx/train_step"
 
-
-# -- (e) the yardstick's own tests ------
-def test_the_benchmarks_own_tests_pass():
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    done = subprocess.run(
-        [sys.executable, "-m", "pytest", "benchmark/tests", "-q",
-         "-p", "no:cacheprovider", "-p", "no:xdist", "-p", "no:randomly"],
-        cwd=ROOT, env=dict(env, JAX_PLATFORMS="cpu"), capture_output=True,
-        text=True, timeout=900)
-    assert done.returncode == 0, done.stdout[-4000:] + done.stderr[-2000:]

@@ -2256,7 +2256,7 @@ def obs_tier():
         env = dict(os.environ, TPUMX_TELEMETRY=jsonl, JAX_PLATFORMS="cpu")
         env.pop("TPUMX_CHAOS", None)  # a chaos-armed env would tear the run
         # TPUMX_FUSION=0 would force the bulk() blocks eager and zero the
-        # required fusion.flushes (same scrub bench.py's fusion leg does)
+        # required fusion.flushes
         env.pop("TPUMX_FUSION", None)
         try:
             run = subprocess.run([sys.executable, "-c", OBS_SCRIPT],

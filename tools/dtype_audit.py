@@ -86,7 +86,6 @@ def main():
     import jax
     jax.config.update("jax_platforms", "cpu")
     import hlo_inspect
-    import mfu_probe
 
     log(f"building {args.model} batch={args.batch} (CPU, trace-only)...")
     builders = {"resnet": hlo_inspect.build_resnet_step,

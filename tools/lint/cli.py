@@ -244,8 +244,7 @@ def main(argv=None):
         prog="tpumx_lint",
         description="framework-aware static analysis for tpu-mx contracts")
     ap.add_argument("targets", nargs="*", default=list(DEFAULT_TARGETS),
-                    help="files/dirs to lint (default: tpu_mx tools "
-                         "bench.py)")
+                    help="files/dirs to lint (default: tpu_mx tools)")
     ap.add_argument("--format", choices=("human", "json"), default="human")
     ap.add_argument("--rules", default=None,
                     help="comma-separated subset of rules to run")

@@ -19,8 +19,8 @@ LINT_FORMAT = "tpumx-lint-baseline-v1"
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-# the default scan set (ISSUE 6): the library, the tools, the bench driver
-DEFAULT_TARGETS = ("tpu_mx", "tools", "bench.py")
+# the default scan set (ISSUE 6): the library and the tools
+DEFAULT_TARGETS = ("tpu_mx", "tools")
 
 _SUPPRESS_RE = re.compile(
     r"#\s*tpumx-lint:\s*disable="

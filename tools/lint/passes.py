@@ -51,7 +51,7 @@ class DurabilityPass(Pass):
 
     Flags, in library code (``tpu_mx/``): any ``open(path, "w"/"wb")``,
     any ``pickle.dump(obj, file)``, and ``np.save/np.savez`` to anything
-    not provably an in-memory buffer.  In ``tools/``/``bench.py`` only
+    not provably an in-memory buffer.  In ``tools/`` only
     *state-shaped* paths are flagged (ones whose expression mentions
     checkpoints/params/states/manifests) — report files there are not
     recovery state.  ``atomic_write``'s own internal ``open`` is the one
@@ -69,7 +69,7 @@ class DurabilityPass(Pass):
     name = "durability"
 
     STATE_HINTS = ("params", "states", "checkpoint", "ckpt", "manifest",
-                   "capsule", "lastgood")
+                   "capsule")
 
     def _is_library(self, ctx):
         return ctx.path.startswith("tpu_mx/")

@@ -3,8 +3,8 @@
 Interpret-mode green is not chip green: real Mosaic enforces limits the
 CPU interpreter does not (PRNG seed arity, SMEM layouts), and the MXU's
 default precision is not the CPU's.  This runner executes each kernel
-path on `jax.devices()[0]` of a real TPU backend and records a per-check
-pass/fail artifact (TPU_VALIDATION_<round>.json).
+path on `jax.devices()[0]` of a real TPU backend and prints a per-check
+pass/fail record of what this run executed (to --out, else to stdout).
 
 One process, holding the chip:
     python tools/tpu_validate.py [--out PATH] [--skip-bert]
@@ -24,7 +24,6 @@ import traceback
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
 def log(msg):
@@ -711,10 +710,17 @@ CHECKS = [
 ]
 
 
+def _write(out, record):
+    from tpu_mx.checkpoint import atomic_write
+    with atomic_write(out, "w") as f:
+        json.dump(record, f, indent=1)
+
+
 def main():
     ap = argparse.ArgumentParser()
-    from artifact_protocol import artifact
-    ap.add_argument("--out", default=artifact("TPU_VALIDATION"))
+    ap.add_argument("--out", default=None,
+                    help="write the record here, after every check; "
+                         "without it the record goes to stdout")
     ap.add_argument("--skip-bert", action="store_true")
     ap.add_argument("--only", default=None,
                     help="comma-separated check names")
@@ -735,54 +741,28 @@ def main():
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
     else:
-        # share the bench's persistent compile cache: a rerun on the
-        # same machine skips straight to execution
+        # a rerun on the same machine skips straight to execution
         from tpu_mx.runtime import enable_shared_compilation_cache
         enable_shared_compilation_cache()
-    from artifact_protocol import load_prior, refuses_clobber, write_atomic
     devs = jax.devices()
     platform = devs[0].platform
+    # the record holds what THIS run executed and nothing else: a row
+    # from an earlier run in this run's place would be a green nobody
+    # measured
     record = {"ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
               "platform": platform, "n_devices": len(devs), "checks": {}}
-    prior = load_prior(args.out)
-    if refuses_clobber(prior, platform):
-        log(f"platform is {platform}, not tpu; refusing to overwrite the "
-            f"hardware artifact {args.out} (pass --out elsewhere)")
-        return 1
-    ran = set()
     if platform != "tpu":
         record["skipped"] = True
         record["reason"] = f"platform is {platform}, not tpu"
         log(f"not a TPU backend ({platform}); writing skip record")
     else:
         record["skipped"] = False
-        # seed with the prior artifact's passing rows for checks still in
-        # the suite: a mid-sweep wedge (the recurring failure mode) must
-        # not cost previously-recorded green results — each seeded row is
-        # REPLACED the moment its check re-executes below, so a full
-        # sweep still re-proves everything it reaches
-        if prior.get("platform") == "tpu":
-            current = {name for name, _ in CHECKS}
-            for name, row in (prior.get("checks") or {}).items():
-                if name in current and isinstance(row, dict) and \
-                        row.get("ok") is True:
-                    seeded = dict(row)
-                    # setdefault: across two consecutive wedged runs the
-                    # chain must keep pointing at the run that actually
-                    # MEASURED the check, not the intermediate carrier
-                    seeded.setdefault("carried_from", prior.get("ts"))
-                    record["checks"][name] = seeded
         for name, fn in CHECKS:
             if only and name not in only:
                 continue
             if args.skip_bert and name == "bert_remat_batch512":
-                # don't clobber a carried green row with {ok: None} — that
-                # would drop the measured pass (and its carried_from chain)
-                # from every later wedge-seeded run
-                record["checks"].setdefault(
-                    name, {"ok": None, "skipped": True})
+                record["checks"][name] = {"ok": None, "skipped": True}
                 continue
-            ran.add(name)
             log(f"running {name}...")
             t0 = time.perf_counter()
             try:
@@ -797,29 +777,20 @@ def main():
                     "error": f"{type(e).__name__}: {e}"[:500],
                     "traceback": traceback.format_exc()[-1500:]}
                 log(f"  {name}: FAIL {type(e).__name__}: {e}")
-            # persist after every check — a later hang must not lose
-            # earlier results (the bench lastgood lesson)
-            write_atomic(args.out, record)
-    if not record.get("skipped"):
-        record["ran_this_run"] = sorted(ran)
-    write_atomic(args.out, record)
-    # rc contract: 0 iff (a) every check EXECUTED this run passed and
-    # (b) the merged artifact covers the full current suite all-green —
-    # so a wedge-shortened or --only run can't report a green sweep while
-    # most checks were neither run nor carried (advisor r4 finding #4)
-    current = {name for name, _ in CHECKS}
-    ok_run = not record.get("skipped", True) and all(
-        record["checks"][n].get("ok") is True
-        for n in ran if n in record["checks"])
-    # a --skip-bert {ok: None} row is NOT complete: it was neither run
-    # nor carried, and rc 0 would report a green sweep over an
-    # unmeasured check
-    complete = all(
-        n in record["checks"] and
-        record["checks"][n].get("ok") is True for n in current)
-    log(f"done: {args.out} (ran={len(ran) if not record.get('skipped') else 0}"
-        f" ok_run={ok_run} merged_complete={complete})")
-    return 0 if (ok_run and complete) else 1
+            # after every check: a later hang must not lose earlier rows
+            if args.out:
+                _write(args.out, record)
+    if args.out:
+        _write(args.out, record)
+    else:
+        print(json.dumps(record, indent=1))
+    # 0 iff the suite ran on a TPU and every check it executed passed;
+    # what --only and --skip-bert left out is left out by request and
+    # is absent from (or marked skipped in) the record
+    ran = [r for r in record["checks"].values() if not r.get("skipped")]
+    ok = not record["skipped"] and all(r["ok"] is True for r in ran)
+    log(f"done: {args.out or 'stdout'} (ran={len(ran)} ok={ok})")
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

@@ -101,8 +101,7 @@ def cache_stats():
     """Public jit-cache accessor: compiled-program counts plus hit/miss
     totals, backed by the telemetry registry counters
     (``fusion.cache_hits`` / ``fusion.cache_misses`` /
-    ``fusion.flushes``).  bench.py's fusion leg persists this dict into
-    its JSON record, so cache behavior rides every benchmark receipt."""
+    ``fusion.flushes``)."""
     def val(name):
         m = _telemetry.get(name)
         return int(m.value) if m is not None else 0

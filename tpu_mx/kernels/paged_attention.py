@@ -51,9 +51,10 @@ Two arms share the math:
 - :func:`paged_attention_reference` — the same block-table algorithm as
   ONE jitted XLA program (gather-by-table + masked softmax fused by the
   compiler).  Off-TPU this is the production paged arm: it keeps the
-  pool device-resident and beats the per-step host dense-gather at long
-  context (bench `decode_attention` micro-arm, ROUND8_NOTES.md), while
-  the interpret-mode kernel stays a correctness-only tool.
+  pool device-resident and reads each pool byte once where the per-step
+  host dense-gather copies the padded context out and reads it again (on
+  a CPU host; not measured on the chip), while the interpret-mode kernel
+  stays a correctness-only tool.
 
 No backward pass: decode is inference — there is nothing to
 differentiate, and keeping the kernel forward-only is what lets the
@@ -76,12 +77,11 @@ __all__ = ["paged_attention", "paged_attention_reference", "window_walk",
 
 NEG_INF = -1e30
 
-# Serving KV block size (tokens per pool block).  Swept on the bench
-# harness (tools/paged_sweep.py -> PAGED_SWEEP_r08.json, receipts in
-# ROUND8_NOTES.md): 8 loses ~20-25% on the paged arm (double the block
-# walk's iteration count for the same bytes); 16/32/64 land within ~10%
-# of each other, with 16 best at short context and carrying the least
-# padded-tail waste and free-list fragmentation — so 16 stands.
+# Serving KV block size (tokens per pool block).  Swept once on a CPU
+# host, not on the chip: 8 doubles the block walk's iteration count for
+# the same bytes; 16/32/64 landed close to each other, and 16 carries the
+# least padded-tail waste and free-list fragmentation, so 16 stands until
+# a serving cell measures it (ROADMAP W4).
 DEFAULT_BLOCK_SIZE = 16
 
 

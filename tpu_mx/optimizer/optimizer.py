@@ -97,16 +97,6 @@ class Optimizer:
         return wd
 
     # -- state ---------------------------------------------------------------
-    # True on subclasses whose update_core is purely elementwise in
-    # (weight, grad, state) with scalar hyperparameters — no per-tensor
-    # reductions (norms, trust ratios) and no shape dependence.  Such
-    # updates may be applied to a flat concatenation of many params in
-    # ONE call (CompiledTrainStep's fused single-chip update path; the
-    # r4 chip profile measured ~160 per-param update op-clusters of pure
-    # per-op overhead).  LAMB/LBSGD compute per-tensor statistics and
-    # must stay per-param; flags are set below the class definitions.
-    elementwise_update = False
-
     def create_state(self, index, weight):
         """Return opaque per-weight state (raw jax arrays / tuples / None)."""
         return None
@@ -590,14 +580,3 @@ class FTML(Optimizer):
         z = self.beta1 * z_prev + (1 - self.beta1) * g - sigma * weight
         new_w = (-z / d).astype(weight.dtype)
         return new_w, (d, v, z)
-
-
-# update_core verified elementwise (no per-tensor reductions / shape
-# dependence) — eligible for the fused flat-update path.  SGLD is NOT
-# listed: its update draws normal(key, weight.shape), and a draw over the
-# flat concatenation yields different noise than per-param draws, so the
-# fused path could not be bit-identical.
-for _cls in (SGD, NAG, Adam, AdamW, RMSProp, AdaGrad, AdaDelta, Signum,
-             Adamax, Nadam, FTML):
-    _cls.elementwise_update = True
-del _cls

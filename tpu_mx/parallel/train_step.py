@@ -305,40 +305,6 @@ class CompiledTrainStep:
         compression = self._compression
         mesh = self.mesh
 
-        # Fused flat update (single-chip, TPUMX_FUSED_UPDATE=1 opt-in):
-        # params with identical elementwise update programs — same (mp,
-        # dtype, lr_mult, wd_mult, state structure) — are concatenated
-        # into ONE flat buffer, updated in one optimizer call, and sliced
-        # back.  Measured on the r4 chip for ResNet-50/SGD-mom: the
-        # concat+slice round trip costs MORE than the ~160 per-param
-        # op-clusters it replaces (2341.8 vs 2379.2 img/s) because the
-        # step is HBM-bandwidth-bound (PROFILE_STEP_r04.json) and the
-        # flat buffers add a full extra pass over masters+grads+state.
-        # Default OFF; kept because op-overhead-bound models (many tiny
-        # params) are the case it does help, and the equivalence is
-        # regression-tested (bit-identical to the per-param path).
-        # Sharded/multi-chip params always keep the per-param path
-        # (flattening would destroy their shardings); LAMB-style
-        # optimizers are excluded by the elementwise_update flag.
-        fuse_groups = []
-        if mesh is None and getattr(opt, "elementwise_update", False) and \
-                os.environ.get("TPUMX_FUSED_UPDATE", "0") == "1":
-            by_sig = {}
-            for k in diff_keys:
-                w = self.masters[k] if k in mp_keys else self.values[k]
-                leaves, treedef = jax.tree_util.tree_flatten(
-                    self.opt_states[k])
-                if not all(getattr(l, "shape", None) == w.shape
-                           for l in leaves):
-                    continue
-                sig = (k in mp_keys, str(self.values[k].dtype),
-                       str(w.dtype), lr_mults[k], wd_mults[k],
-                       str(treedef), tuple(str(l.dtype) for l in leaves))
-                by_sig.setdefault(sig, []).append(k)
-            fuse_groups = [ks for ks in by_sig.values() if len(ks) > 1]
-        fused_keys = {k for ks in fuse_groups for k in ks}
-        self._fuse_groups = fuse_groups  # introspection (tests/debug)
-
         def make_lfn(const_vals, key, data_args, loss_args):
             def lfn(dv):
                 pm = dict(const_vals)
@@ -486,38 +452,7 @@ class CompiledTrainStep:
             new_masters = {}
             new_states = {}
             with jax.named_scope(_OPTIMIZER):
-                for ks in fuse_groups:
-                    is_mp = ks[0] in mp_keys
-                    srcs = [masters[k] if is_mp else values[k] for k in ks]
-                    flat_w = jnp.concatenate([s.ravel() for s in srcs])
-                    flat_g = jnp.concatenate(
-                        [grads[k].astype(flat_w.dtype).ravel() for k in ks])
-                    leaves0, st_def = jax.tree_util.tree_flatten(
-                        opt_states[ks[0]])
-                    flat_state = jax.tree_util.tree_unflatten(st_def, [
-                        jnp.concatenate(
-                            [jax.tree_util.tree_flatten(opt_states[k])[0][i]
-                             .ravel() for k in ks])
-                        for i in range(len(leaves0))])
-                    w, s = opt.update_core(
-                        flat_w, flat_g, flat_state, lr * lr_mults[ks[0]],
-                        base_wd * wd_mults[ks[0]], t)
-                    s_leaves, s_def = jax.tree_util.tree_flatten(s)
-                    off = 0
-                    for k, src in zip(ks, srcs):
-                        n = src.size
-                        piece = w[off:off + n].reshape(src.shape)
-                        if is_mp:
-                            new_masters[k] = piece
-                        new_vals[k] = piece.astype(values[k].dtype)
-                        new_states[k] = jax.tree_util.tree_unflatten(
-                            s_def,
-                            [sl[off:off + n].reshape(src.shape)
-                             for sl in s_leaves])
-                        off += n
                 for k in diff_keys:
-                    if k in fused_keys:
-                        continue
                     if k in mp_keys:
                         # update in f32 master space; forward weight is a cast
                         w, s = opt.update_core(
@@ -893,9 +828,8 @@ class CompiledTrainStep:
         """Lower + compile the step WITHOUT executing it and return the
         jax Compiled object (for cost_analysis / memory_analysis / HLO
         text).  Shares the jit/persistent compile cache with step(), so
-        after a step() has run this is cache-hit cheap.  Used by bench.py
-        (XLA-cost MFU is the number-of-record, VERDICT r4 ask#9) and
-        tools/mfu_probe.py."""
+        after a step() has run this is cache-hit cheap.  benchmark/run.py
+        reads the step's memory_analysis() through it."""
         raw = tuple(b._data if isinstance(b, NDArray)
                     else (None if b is None else jnp.asarray(b))
                     for b in batch)

@@ -21,8 +21,8 @@ def fetch_sync(x):
     order, so fetching the LAST result proves all prior work completed.
     Pass a small slice/scalar (e.g. ``loss`` or ``out[:1]``), not a big
     tensor — the copy is inside the timed region.  Used by
-    tools/longctx_bench.py and tools/bandwidth.py; bench.py and
-    tools/tpu_validate.py fetch their loss scalars inline."""
+    tools/bandwidth.py; benchmark/run.py and tools/tpu_validate.py fetch
+    their loss scalars inline."""
     import numpy as np
     return np.asarray(x)
 
@@ -135,7 +135,7 @@ def _set_cache_thresholds(min_compile_time_secs=1.0):
 
 def enable_shared_compilation_cache():
     """The one place the program chooses a compile-cache directory;
-    chip_smoke.py, bench.py and every on-chip tool call it.
+    chip_smoke.py, benchmark/run.py and tools/tpu_validate.py call it.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set the directory is the
     environment's: jax reads the variable itself, and this function sets

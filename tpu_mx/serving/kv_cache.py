@@ -1155,8 +1155,8 @@ class PagedKVCache:
         consumers see a bounded set of shapes instead of recompiling at
         every block-boundary crossing.  The bucket is deliberately fine:
         pow2 buckets made the padded gather tail up to 2x the true
-        context, which alone pushed the long-generation per-token
-        receipt past the <=1.15x flatness bar (ROUND8_NOTES.md); at
+        context (a count on a CPU host, not a speed: the per-token cost
+        over a long generation then grew with the padding); at
         mult-4 the tail is <=3 blocks and a 4096-block pool still
         compiles at most ~1k shapes over its whole lifetime."""
         with self._lock:

@@ -39,11 +39,10 @@ __all__ = ["DEFAULT_WINDOW", "resolve_spec_window", "SiblingProposer",
 
 _SPEC_ENV = "TPUMX_SPECULATIVE"
 
-# Swept on the Tq axis of tools/paged_sweep.py (ROUND11_NOTES.md): the
-# widened kernel's per-window cost grows sublinearly in Tq (the block
-# walk is shared), so the window wants to be as wide as the accept rate
-# sustains; 4 is where the toy proposer's acceptance still pays for the
-# extra verify rows.
+# The widened kernel shares the block walk over the Tq rows of a window,
+# so the window wants to be as wide as the accept rate sustains; 4 is
+# where the toy proposer's acceptance still paid for the extra verify
+# rows (a count on a CPU host, not a speed; no cell measures it yet).
 DEFAULT_WINDOW = 4
 
 
