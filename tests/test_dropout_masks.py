@@ -1,9 +1,11 @@
 """Every dropout mask comes from one helper (`tpu_mx.random.dropout_keep`)
 whose bits are XLA's `rng_bit_generator`, drawn again in the backward pass
-from the same key (ISSUE 28; `tpu_mx.random.dropped` says why).  What a mask
+from the same key (ISSUE 28; `tpu_mx.random.dropped` says why) unless the
+site holds it (ISSUE 30: the dense attention site does).  What a mask
 must be: kept with probability 1 - rate, the same for the same key, independent for a split's two halves,
 the same in forward and backward, and replayed bit for bit from a restored
 RNG state."""
+import functools
 import re
 
 import jax
@@ -87,31 +89,62 @@ def test_dropout_gradient_is_the_mask(hybridized):
     np.testing.assert_array_equal(g[g != 0], np.float32(1 / 0.9))
 
 
-@pytest.mark.parametrize("latest", [True, False], ids=["held", "drawn_again"])
-def test_a_sites_backward_matches_plain_autodiff(latest):
-    # the custom backward pass against jax's own, on the product attention
-    # wraps: the latest site holds its mask, every other draws it again
-    key = R.take_key()
-    p = jnp.asarray(np.random.RandomState(1).rand(2, 3, 16, 16), jnp.float32)
-    v = jnp.asarray(np.random.RandomState(2).rand(2, 3, 16, 8), jnp.float32)
+def _product(keep, p, v):
+    return jnp.einsum("bhqk,bhkd->bhqd", R.scaled(keep, p, 0.1), v)
 
-    def product(keep, p, v):
-        return jnp.einsum("bhqk,bhkd->bhqd", R.scaled(keep, p, 0.1), v)
+
+def _probabilities_and_values():
+    return (jnp.asarray(np.random.RandomState(1).rand(2, 3, 16, 16), jnp.float32),
+            jnp.asarray(np.random.RandomState(2).rand(2, 3, 16, 8), jnp.float32))
+
+
+def _site_gradients(how, key, p, v):
+    """Gradients of the product attention wraps through one site: "latest"
+    is the latest site traced, "drawn_again" has another traced after it,
+    "hold" has one too and says that it holds its mask.  With them, how
+    many masks the trace drew and how many sites said they hold."""
+    def site(p, v):
+        out = (R.dropped(_product, key, 0.1, p.shape, p, v,
+                         hold=how == "hold") ** 2).sum()
+        if how != "latest":
+            out = out + 0 * R.dropout(p, jax.random.PRNGKey(5), 0.5).sum()
+        return out
+    before = dict(R.mask_draws)
+    got = jax.jit(jax.grad(site, argnums=(0, 1)))(p, v)
+    return got, {k: n - before[k] for k, n in R.mask_draws.items()}
+
+
+@pytest.mark.parametrize("how", ["latest", "drawn_again", "hold"])
+def test_a_sites_backward_matches_plain_autodiff(how):
+    # the custom backward pass against jax's own, on the product attention
+    # wraps: the latest site holds its mask, every other draws it again,
+    # unless it says that it holds it
+    key = R.take_key()
+    p, v = _probabilities_and_values()
 
     def plain(p, v):
-        return (product(R.dropout_keep(key, 0.1, p.shape), p, v) ** 2).sum()
+        return (_product(R.dropout_keep(key, 0.1, p.shape), p, v) ** 2).sum()
 
-    def site(p, v):
-        out = (R.dropped(product, key, 0.1, p.shape, p, v) ** 2).sum()
-        if not latest:
-            out = out + 0 * R.dropout(p, R.take_key(), 0.5).sum()
-        return out
     want = jax.grad(plain, argnums=(0, 1))(p, v)
-    before = R.mask_draws["rbg"]
-    got = jax.jit(jax.grad(site, argnums=(0, 1)))(p, v)
-    assert R.mask_draws["rbg"] - before == (1 if latest else 3)
+    got, counted = _site_gradients(how, key, p, v)
+    # the other site is the latest and holds: one draw; this one's second
+    # draw is the third
+    assert counted == {"latest": {"rbg": 1, "held": 0},
+                       "drawn_again": {"rbg": 3, "held": 0},
+                       "hold": {"rbg": 2, "held": 1}}[how]
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def test_a_site_that_holds_gives_the_gradients_of_one_that_draws_again():
+    # same key, same 32-bit draws, same comparison: the same mask, held or
+    # drawn a second time, and the same arithmetic on it
+    key = R.take_key()
+    p, v = _probabilities_and_values()
+    again, _ = _site_gradients("drawn_again", key, p, v)
+    held, _ = _site_gradients("hold", key, p, v)
+    for a, b in zip(held, again):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_rnn_inter_layer_dropout_draws_through_the_helper():
@@ -141,11 +174,13 @@ def _transformer_layer_text():
 
 
 def test_transformer_layer_lowers_to_rng_bit_generator():
-    before = R.mask_draws["rbg"]
+    before = dict(R.mask_draws)
     text = _transformer_layer_text()
-    # three sites (attention probabilities, two hidden), forward and
-    # backward, but for the latest site's mask, which is held
-    assert R.mask_draws["rbg"] - before == 2 * 3 - 1
+    # three sites (attention probabilities, two hidden) in the forward
+    # pass; in the backward pass one: the attention site holds its mask
+    # and so does the latest site
+    assert R.mask_draws["rbg"] - before["rbg"] == 3 + 1
+    assert R.mask_draws["held"] - before["held"] == 1
     drawn = re.findall(r"stablehlo.rng_bit_generator.*-> \(tensor<2xui64>, "
                        r"tensor<([0-9x]+)xui32>\)", text)
     assert sorted(set(drawn)) == ["8x32x64", "8x4x32x32"]
@@ -155,12 +190,66 @@ def test_transformer_layer_lowers_to_rng_bit_generator():
     assert fry and not [l for l in fry if re.search(r"tensor<8x(32x64|4x32x32)x", l)]
 
 
-@pytest.mark.parametrize("arm, sites", [("dense", 25 + 12), ("flash", 25)])
-def test_one_trace_of_berts_step_counts_its_sites(monkeypatch, arm, sites):
+def test_the_dense_attention_site_draws_once_and_a_hidden_site_twice():
+    # forward and backward in one lowered text: the scores' shape is drawn
+    # in the forward pass alone; of the two hidden sites the earlier draws
+    # again in the backward pass (the later is the latest site)
+    # (a mask draw is a call of a private function that holds the
+    # generator; draws of one shape may share the function)
+    drawn = re.findall(r"call @_bernoulli.*-> tensor<([0-9x]+)xi1>",
+                       _transformer_layer_text())
+    assert drawn.count("8x4x32x32") == 1
+    assert drawn.count("8x32x64") == 2 + 1
+
+
+def test_a_site_that_does_not_hold_lowers_as_before_issue_30():
+    # the cells whose sites do not hold (bert-base.mlm512's 25 hidden
+    # sites, the RNN layers) run the program they ran: the site below is
+    # ISSUE 28's, copied, and lowers to the same text as today's
+    def fwd(fn, n, key, rate, shape, *operands):
+        keep = R.dropout_keep(R._after(key, operands), rate, shape)
+        return fn(keep, *operands), (key, keep, operands)
+
+    def bwd(fn, n, rate, shape, held, g):
+        key, keep, operands = held
+        if n != R._sites[0]:
+            keep = R.dropout_keep(R._after(key, g), rate, shape)
+        _, pull = jax.vjp(functools.partial(fn, keep), *operands)
+        return (None, *pull(g))
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 3, 4))
+    def site(fn, n, key, rate, shape, *operands):
+        return fwd(fn, n, key, rate, shape, *operands)[0]
+    site.defvjp(fwd, bwd)
+
+    def before_issue_30(fn, key, rate, shape, *operands):
+        R._sites[0] += 1
+        return site(fn, R._sites[0], R._raw(key), rate, tuple(shape), *operands)
+
+    def text(dropped):
+        def loss(x, w, key):
+            k1, k2 = jax.random.split(key)
+            h = dropped(lambda keep, x: R.scaled(keep, x, 0.1), k1, 0.1,
+                        x.shape, x) @ w
+            return dropped(lambda keep, h: R.scaled(keep, h, 0.1), k2, 0.1,
+                           h.shape, h).sum()
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jnp.ones((8, 32)), jnp.ones((32, 32)), jax.random.PRNGKey(0)
+        ).as_text()
+    now = text(R.dropped)
+    assert len(re.findall(r"call @_bernoulli", now)) == 3
+    assert now == text(before_issue_30)
+
+
+@pytest.mark.parametrize("arm, sites, held", [("dense", 25 + 12, 12),
+                                              ("flash", 25, 0)])
+def test_one_trace_of_berts_step_counts_its_sites(monkeypatch, arm, sites,
+                                                  held):
     # BERT-base's depth at a toy width: embedding + two a layer, and on the
     # dense arm the twelve attention sites too (the flash kernel draws
     # inside itself).  The backward pass draws every mask again but the
-    # latest site's.  A trace alone: the kernel's dropout needs a TPU to run
+    # latest site's and those of the attention sites, which hold theirs:
+    # 61 and 49.  A trace alone: the kernel's dropout needs a TPU to run
     monkeypatch.setenv("TPUMX_ATTENTION", arm)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     net = BERTModel(dict(num_layers=12, units=128, hidden_size=256,
@@ -181,9 +270,10 @@ def test_one_trace_of_berts_step_counts_its_sites(monkeypatch, arm, sites):
             params, key, True, (NDArray(tokens), NDArray(np.zeros_like(tokens)),
                                 None, NDArray(positions)))
         return (out.astype(jnp.float32) ** 2).sum()
-    before = R.mask_draws["rbg"]
+    before = dict(R.mask_draws)
     jax.make_jaxpr(jax.grad(loss))(params, jax.random.PRNGKey(0))
-    assert R.mask_draws["rbg"] - before == 2 * sites - 1
+    assert R.mask_draws["rbg"] - before["rbg"] == 2 * sites - 1 - held
+    assert R.mask_draws["held"] - before["held"] == held
 
 
 # -- determinism: the same key, program and backend give the same mask -------------------

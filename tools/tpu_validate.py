@@ -265,7 +265,9 @@ def check_flash_dropout():
 
 def check_dropout_mask_generator():
     """Dropout masks on the chip (ISSUE 28: bits from rng_bit_generator,
-    drawn again in the backward pass): at BERT-base's two mask shapes,
+    drawn again in the backward pass, or held where the site says so or
+    is the latest, as the one site of each program here is): at
+    BERT-base's two mask shapes,
     under jit, the keep share, forward and backward on one mask (the
     gradient is nonzero exactly where the output is), run-to-run equality
     for one key and a different mask for its split's other half."""

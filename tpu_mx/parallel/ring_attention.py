@@ -17,9 +17,11 @@ mesh has an `sp` axis.
 
 Attention-prob dropout: on the ring and dense paths the keep-mask is drawn
 per (device, ring-step) from a folded key (`random.dropped`: XLA's
-rng_bit_generator, drawn again in the backward pass); on the local TPU path it runs
-inside the Pallas kernel's PRNG (kernels.flash_attention).  The softmax
-normalizer always uses the un-dropped probabilities.
+rng_bit_generator, drawn once and held for the backward pass, in the room
+of the row maximum's tie mask, which `_block_attn` no longer holds); on the
+local TPU path it runs inside the Pallas kernel's PRNG
+(kernels.flash_attention).  The softmax normalizer always uses the
+un-dropped probabilities.
 """
 from __future__ import annotations
 
@@ -57,9 +59,10 @@ def _auto_prefers_flash(q_len, kv_len, dropped, on_tpu):
     dropout 0.1), samples/s dense -> flash: T 128 895.7 -> 774.2 (-13.6%),
     T 256 370.5 -> 404.5 (+9.2%), T 512 135.9 -> 203.7 (+49.9%).  Dense
     writes the (q_len, kv_len) scores of every head to HBM, keeps them for
-    the backward pass and draws their dropout mask twice (threefry then,
+    the backward pass and drew their dropout mask twice (threefry then,
     76 of its 166 ms at T 512; XLA's rng_bit_generator since PR 28, which
-    costs this site about the same: PERF.md section 6, PR 28); the
+    cost this site about the same: PERF.md section 6, PR 28; one draw
+    since PR 30, and the crossover was not read again: section 7); the
     kernel holds one head's block in VMEM and
     pays a fixed cost a grid step instead, which is what loses at T 128.
     With that dropout off, ms a step dense against flash: T 256 219.1
@@ -111,8 +114,13 @@ def _block_attn(q, k, v, bias=None, mask=None, scale=1.0,
     if mask is not None:
         s = jnp.where(mask, s, -jnp.inf)
     m = jnp.max(s, axis=-1)                                   # (B,H,Tq)
-    # guard fully-masked rows: exp(-inf - -inf) -> use max(m, finite floor)
-    m_safe = jnp.maximum(m, -1e30)
+    # guard fully-masked rows: exp(-inf - -inf) -> use max(m, finite floor).
+    # The maximum is a shift that cancels in o / l (here and through
+    # _merge), so its gradient is zero in exact arithmetic; autodiff would
+    # pay for it with jnp.max's tie indicator, a (B,H,Tq,Tk) tensor held
+    # from forward to backward.  The dropout site below holds its keep
+    # mask in that room.
+    m_safe = lax.stop_gradient(jnp.maximum(m, -1e30))
     p = jnp.exp(s - m_safe[..., None])                        # (B,H,Tq,Tk)
     l = jnp.sum(p, axis=-1)                                   # (B,H,Tq)
     # probs cast to v.dtype for the AV matmul (flash-kernel numerics: the
@@ -126,11 +134,12 @@ def _block_attn(q, k, v, bias=None, mask=None, scale=1.0,
                           preferred_element_type=jnp.float32)  # (B,H,Tq,D)
     if dropout_rate > 0.0 and dropout_key is not None:
         # mask and product as one site: alone, the masked probabilities
-        # would be held for the product's backward pass
+        # would be held for the product's backward pass.  One draw: the
+        # site holds its mask, in the room the row maximum gave up above
         o = _random.dropped(
             lambda keep, p, v: weighted(
                 _random.scaled(keep, p, dropout_rate), v),
-            dropout_key, dropout_rate, p.shape, p, v)
+            dropout_key, dropout_rate, p.shape, p, v, hold=True)
     else:
         o = weighted(p, v)
     return m_safe, l, o

@@ -22,8 +22,14 @@ shape and the rate, **and of the compiled program and the backend** (under
 `shard_map`, of the device's mesh coordinates too, through the key): the
 same key, program and hardware give the same mask every time (what the resume capsule's
 bit-exact replay, `ShadowAuditor`'s re-execution and the cross-replica
-fingerprint rest on), forward and backward of one step draw the same one,
-and a split's two halves give independent ones.  It is NOT portable: a CPU
+fingerprint rest on), forward and backward of one step use the same one,
+and a split's two halves give independent ones.  A site either draws its
+mask a second time in the backward pass or holds it, a byte an element
+(:func:`dropped`): the dense attention site holds, because the row
+maximum's tie mask, as large, is no longer held there (ISSUE 30), and so
+does the latest site traced; every other site (the hidden ones of
+`nd.Dropout`, `gluon.rnn`'s between layers) draws again, for want of the
+memory.  It is NOT portable: a CPU
 and a TPU, or two jax versions, drop different (equally random) elements
 for one seed.  A step that GSPMD partitions over a mesh keeps threefry's
 masks, which do not depend on the mesh (:func:`partitioned_draws`).  The
@@ -48,10 +54,12 @@ __all__ = ["seed", "get_state", "set_state", "take_key", "host_rng",
            "KeyHolder", "key_scope", "dropout", "dropout_keep", "dropped",
            "scaled", "partitioned_draws", "mask_draws"]
 
-# Dropout masks drawn through dropout_keep(), counted where the call is
-# traced (once a compilation under jit, once a call in eager mode), like
-# ring_attention.dispatch_counts.  The benchmark's `dropout_rbg_draws` reads it.
-mask_draws = {"rbg": 0}
+# "rbg": dropout masks drawn through dropout_keep(); "held": sites that hold
+# their mask for the backward pass (dropped(hold=True)).  Both counted where
+# the call is traced (once a compilation under jit, once a call in eager
+# mode), like ring_attention.dispatch_counts.  The benchmark's
+# `dropout_rbg_draws` and `dropout_masks_held` read them.
+mask_draws = {"rbg": 0, "held": 0}
 
 
 class _GlobalRNG:
@@ -163,19 +171,19 @@ def partitioned_draws(on=True):
         _HOLDER.partitioned = prev
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 3, 4))
-def _site(fn, n, key, rate, shape, *operands):
-    return _site_fwd(fn, n, key, rate, shape, *operands)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 4, 5))
+def _site(fn, n, hold, key, rate, shape, *operands):
+    return _site_fwd(fn, n, hold, key, rate, shape, *operands)[0]
 
 
-def _site_fwd(fn, n, key, rate, shape, *operands):
+def _site_fwd(fn, n, hold, key, rate, shape, *operands):
     keep = dropout_keep(_after(key, operands), rate, shape)
     return fn(keep, *operands), (key, keep, operands)
 
 
-def _site_bwd(fn, n, rate, shape, held, g):
+def _site_bwd(fn, n, hold, rate, shape, held, g):
     key, keep, operands = held
-    if n != _sites[0]:
+    if not hold and n != _sites[0]:
         # the same key, so the same mask, drawn when the cotangent is
         # there; the forward's `keep` is then dead code to XLA
         keep = dropout_keep(_after(key, g), rate, shape)
@@ -186,31 +194,44 @@ def _site_bwd(fn, n, rate, shape, held, g):
 _site.defvjp(_site_fwd, _site_bwd)
 
 
-def dropped(fn, key, rate, shape, *operands):
+def dropped(fn, key, rate, shape, *operands, hold=False):
     """``fn(keep, *operands)`` at a dropout site, `keep` being
     ``dropout_keep(key, rate, shape)``.
 
-    The backward pass does not hold the mask: it draws it again from the
-    same key (as XLA recomputes an elementwise threefry mask inside its
-    backward fusions) and runs `fn` again under `jax.vjp`.
+    Without `hold` the backward pass does not hold the mask: it draws it
+    again from the same key (as XLA recomputes an elementwise threefry
+    mask inside its backward fusions) and runs `fn` again under `jax.vjp`.
     `rng_bit_generator` is an operation of its own to XLA:TPU, so a mask
     that autodiff holds costs its 32-bit words from forward to backward
     (+2.5 GiB in BERT-base's step at 24,576 tokens), or a byte an element
-    behind a barrier (+0.8 GiB).  Give `fn` everything that would
-    otherwise hold a masked tensor: attention passes the
-    probabilities-times-V product with its mask.
+    as a bool (+0.8 GiB).  Give `fn` everything that would otherwise hold
+    a masked tensor: attention passes the probabilities-times-V product
+    with its mask.
 
-    One site keeps its bool mask: the latest one traced, which the
+    With `hold` the site keeps the bool `keep` of its forward pass and the
+    backward pass uses it: no second generator call, no second copy of the
+    words into the operand's layout, no second comparison.  That is the
+    faster form wherever the byte an element is there to spend, and the
+    site's code says so, not a user.  One site does: dense attention
+    (`parallel.ring_attention._block_attn`), whose row maximum no longer
+    holds a tie mask of the same shape, dtype and lifetime (432 MiB for
+    432 MiB in BERT-base's step at sequence 128; PERF.md section 6, PR
+    30).  The same 32-bit draws, comparison and key as the other form:
+    the forward mask is the one it would draw.
+
+    One more site keeps its bool mask: the latest one traced, which the
     backward pass reaches first.  A generator call there opens the
     backward pass, XLA's scheduler fills the wait with the loss head's
     largest temporaries, and the step's peak rises by 0.25 GiB
-    (`bert-base.mlm512`; PERF.md section 6, PR 28).  Under jit the other
-    sites' forward masks are dead code; in eager mode each is held, a
-    byte an element, until its backward pass."""
+    (`bert-base.mlm512`; PERF.md section 6, PR 28).  Under jit the
+    forward masks of the sites that draw again are dead code; in eager
+    mode each is held, a byte an element, until its backward pass."""
     if getattr(_HOLDER, "partitioned", False):
         return fn(jax.random.bernoulli(key, 1.0 - rate, shape), *operands)
     _sites[0] += 1
-    return _site(fn, _sites[0], _raw(key), rate, tuple(shape), *operands)
+    mask_draws["held"] += hold
+    return _site(fn, _sites[0], hold, _raw(key), rate, tuple(shape),
+                 *operands)
 
 
 def scaled(keep, x, rate):
