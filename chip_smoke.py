@@ -13,10 +13,13 @@ serving's device arm a short leg:
         layers, 512 wide — the only serving model there is), its tokens
         compared with the XLA twin of the paged kernel on the same device.
   D     a toy causal decoder (models/decoder.py CausalLM: latent attention
-        with heads of 64, dropless experts, 2 of 8 held, multi-token head;
-        seq 512), TPUMX_ATTENTION=flash, 10 AdamW steps: the flash
-        kernel's causal path and XLA:TPU's grouped product outside the
-        benchmark.  Every loss finite, the loss falling, no row dropped.
+        with heads of 64, its last layer grouped-query attention, 4 query
+        heads over 2 key/value heads, with a window of 128; dropless
+        experts, 2 of 8 held, multi-token head; seq 512),
+        TPUMX_ATTENTION=flash, 10 AdamW steps: the flash kernel's causal
+        path, its window arm and its dk/dv group sum, and XLA:TPU's grouped
+        product outside the benchmark.  Every loss finite, the loss
+        falling, no row dropped, the window's blocks counted.
 
     python chip_smoke.py              # needs a TPU; exits non-zero without
     python chip_smoke.py --tiny-cpu   # same control flow, toy sizes, CPU
@@ -331,16 +334,21 @@ def phase_d(tiny, platform, compiles):
     from tpu_mx import gluon, nd
     from tpu_mx.models.decoder import CausalLM
     from tpu_mx.parallel import CompiledTrainStep, load_census
-    from tpu_mx.parallel.ring_attention import dispatch_counts
+    from tpu_mx.parallel.ring_attention import (
+        _seen_signatures, dispatch_counts, window_blocks)
     seq_len, steps = (64 if tiny else 512), 10
     want = "pallas_flash" if platform == "tpu" else "xla_dense"
     print(f"phase D: toy causal decoder seq {seq_len} batch 2, remat, "
           f"TPUMX_ATTENTION=flash, expecting {want}")
+    latent = dict(num_heads=2, q_rank=64, kv_rank=64, nope_dim=48,
+                  rope_dim=16, v_dim=64, rope_theta=1e6)
+    # the third layer's queries see a quarter of the sequence
+    windowed = dict(kind="grouped_query", num_heads=4, num_kv_heads=2,
+                    head_dim=64, rope_theta=1e6, window=seq_len // 4)
     cfg = dict(
         vocab_size=1024, units=256, num_layers=3, num_dense_layers=1,
         dense_hidden=512, epsilon=1e-5,
-        attention=dict(num_heads=2, q_rank=64, kv_rank=64, nope_dim=48,
-                       rope_dim=16, v_dim=64, rope_theta=1e6),
+        attention=[latent, latent, windowed, latent],  # the last: the module's
         moe=dict(hidden_size=128, num_experts=8, top_k=2,
                  held_experts=(0, 2), scaling=1.8, shared_hidden=128),
         mtp_depth=1, mtp_weight=0.3)
@@ -351,7 +359,7 @@ def phase_d(tiny, platform, compiles):
         0, cfg["vocab_size"], (2, seq_len)), dtype="int32")
     opt = mx.optimizer.create("adamw", learning_rate=3e-4, beta2=0.95,
                               wd=0.1, multi_precision=True)
-    before = dict(dispatch_counts)
+    before, blocks = dict(dispatch_counts), dict(window_blocks)
     with env(TPUMX_ATTENTION="flash"):
         step = CompiledTrainStep(net, gluon.loss.PassThrough(), opt)
         losses = [float(step.step(tokens, tokens).asscalar())
@@ -365,6 +373,16 @@ def phase_d(tiny, platform, compiles):
           f"D: loss fell, {losses[0]:.3f} -> {losses[-1]:.3f}")
     check(dispatch_counts[want] > before[want],
           f"D: causal attention dispatched to {want}")
+    detail = f"kv_heads=2 window={seq_len // 4}"
+    check(any(path == want and detail in said
+              for path, said in _seen_signatures),
+          f"D: the grouped window layer ({detail}) dispatched to {want}")
+    grid, run = (window_blocks[k] - blocks[k] for k in ("grid", "run"))
+    # at this length the default blocks make a grid of one: counted, and
+    # nothing to skip (the benchmark's 16k cell is where blocks are skipped)
+    check(platform != "tpu" or 0 < run <= grid,
+          f"D: the windowed flash call counted its blocks, {run} of "
+          f"{grid} run")
     step.sync_to_net()
     census = load_census(net)
     check(len(census) == 3 and all(

@@ -41,6 +41,8 @@ _benchmark = _load("test_benchmark")
 TestBenchmark = type("TestBenchmark", (), _cases(_benchmark))
 TestScopes = type("TestScopes", (), _cases(_load("test_scopes")))
 TestDecoderCell = type("TestDecoderCell", (), _cases(_load("test_decoder_cell")))
+TestWindowedCell = type("TestWindowedCell", (),
+                        _cases(_load("test_windowed_cell")))
 grown = _benchmark.grown        # test_benchmark.py's one fixture
 
 

@@ -27,6 +27,9 @@ def test_tiny_flag_runs_all_phases_on_cpu(tmp_path):
     for phase in ("phase A", "phase mesh dp4", "phase mesh dp2xtp2",
                   "phase B", "phase C", "phase D"):
         assert any(ln.startswith(phase) for ln in lines), phase
+    # phase D's toy decoder holds a grouped-query layer with a window
+    assert any("D: the grouped window layer (kv_heads=2 window=16) "
+               "dispatched to xla_dense" in ln for ln in lines)
     assert json.loads(lines[-1]) == {
         "ok": True, "tiny_cpu": True,
         "device": {"platform": "cpu", "kind": "cpu", "count": 4}}

@@ -88,6 +88,28 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e, mosaic, masked_dropout):
     assert _mosaic_calls(compiled) >= 3  # forward, dq, dk/dv
 
 
+@pytest.mark.parametrize("window", [None, 4096])
+def test_windowed_grouped_flash_compiles_for_v5e(v5e, mosaic, window):
+    """smallthinker-21ba3b.extend16k's attention (28 query heads over 4
+    key/value heads of 128, T = 16,384, bf16, causal), forward and backward:
+    the global layers' kernels and, with the window, those whose index maps
+    clamp a skipped step to the nearest block that runs."""
+    q, kv = ((1, 28, 16384, 128), jnp.bfloat16), \
+        ((1, 4, 16384, 128), jnp.bfloat16)
+    assert fa.supported(q[0], q[1], kv_heads=4, window=window)
+
+    def loss(q, k, v):
+        return fa.mha_flash_attention(q, k, v, causal=True,
+                                      window=window).astype(jnp.float32).sum()
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e, q, kv, kv)
+    assert _mosaic_calls(compiled) >= 3  # forward, dq, dk/dv
+    assert [tuple(a.shape) for a in jax.eval_shape(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        *(jax.ShapeDtypeStruct(*x) for x in (q, kv, kv)))] \
+        == [q[0], kv[0], kv[0]]     # dk, dv per key/value head
+
+
 @pytest.mark.parametrize("tq", [1, 4])
 def test_paged_decode_compiles_for_v5e(v5e, mosaic, tq):
     """The paged decode kernel at a decoder-sized geometry (16 heads of

@@ -27,6 +27,17 @@ probabilities (standard inverted dropout on the probs).  The TPU PRNG has
 no CPU/interpret lowering, so dropout>0 requires a real TPU; callers gate
 via `supported()`.
 
+Window and grouped heads (ISSUE 31).  With `causal` and `window=W`, query i
+sees keys i - W < j <= i: blocks that lie wholly before every query's window
+are skipped like those above the diagonal, edge blocks are masked, and the
+index maps clamp a skipped step to the nearest block that runs, so that its
+K/V (or, in the dk/dv kernel, q/do) tiles are not copied in again.  With k
+and v of BH / G rows (`G` query heads share a key/value head, row b reads
+row b // G), K and V are never repeated in HBM: the block maps send the
+query row to its key/value row, and the dk/dv kernel walks the G query
+heads of its row on its innermost grid axis and sums them in its scratch.
+Without a window and with G = 1 the three kernels are the ones they were.
+
 Falls back to interpret mode off-TPU so tests run anywhere.
 """
 from __future__ import annotations
@@ -39,7 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "mha_flash_attention", "supported"]
+__all__ = ["flash_attention", "mha_flash_attention", "supported",
+           "blocks_run"]
 
 NEG_INF = -1e30
 # Largest (Bq × Bk) f32 score block we let the kernel materialize in VMEM:
@@ -76,25 +88,35 @@ def _keep_mask(seed_ref, b, qi, ki, rate, block_q, block_k):
     return bits >= thresh
 
 
-def _score_mask(s, valid, causal, qi, ki, block_q, block_k):
-    """Apply causal and/or key-padding masks to a score block."""
+def _score_mask(s, valid, causal, qi, ki, block_q, block_k, window=None):
+    """Apply causal (with its window, if any) and/or key-padding masks to a
+    score block.  A row may lose every key of an edge block: NEG_INF is
+    finite, so what such a row accumulates there is wiped by the factor
+    exp(NEG_INF - m) = 0 once a block with a key it sees (its diagonal
+    block at the latest) sets its maximum."""
     kpos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
     if causal:
         qpos = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         s = jnp.where(qpos >= kpos, s, NEG_INF)
+        if window is not None:
+            s = jnp.where(qpos - kpos < window, s, NEG_INF)
     if valid is not None:
         s = jnp.where(kpos < valid, s, NEG_INF)
     return s
 
 
-def _run_cond(causal, valid, qi, ki, block_q, block_k):
+def _run_cond(causal, valid, qi, ki, block_q, block_k, window=None):
     """Whether block (qi, ki) can contribute at all: on/below the causal
-    diagonal AND not entirely beyond the valid key length."""
+    diagonal, holding a key inside some query's window, AND not entirely
+    beyond the valid key length."""
     cond = None
     if causal:
         cond = qi * block_q + block_q - 1 >= ki * block_k
+        if window is not None:
+            # `&`: blocks_run() asks with numpy indices, inside a trace too
+            cond = cond & (ki * block_k + block_k - 1 > qi * block_q - window)
     if valid is not None:
         c = ki * block_k < valid
         cond = c if cond is None else jnp.logical_and(cond, c)
@@ -105,7 +127,7 @@ def _run_cond(causal, valid, qi, ki, block_q, block_k):
 # forward
 # ----------------------------------------------------------------------------
 def _fwd_kernel(*refs, scale, causal, masked, rate, biased, block_q,
-                block_k):
+                block_k, window=None):
     (q_ref, k_ref, v_ref), bias_ref, valid_ref, seed_ref, tail = \
         _split_refs(refs, 3, masked, rate, biased)
     o_ref, lse_ref, m_scr, l_scr, acc_scr = tail
@@ -134,7 +156,7 @@ def _fwd_kernel(*refs, scale, causal, masked, rate, biased, block_q,
                                 preferred_element_type=jnp.float32) * scale
         if biased:
             s = s + bias_ref[0].astype(jnp.float32)           # (Bq, Bk)
-        s = _score_mask(s, valid, causal, qi, ki, block_q, block_k)
+        s = _score_mask(s, valid, causal, qi, ki, block_q, block_k, window)
         m_prev = m_scr[:, 0]                                  # (Bq,)
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         alpha = jnp.exp(m_prev - m_cur)
@@ -153,7 +175,7 @@ def _fwd_kernel(*refs, scale, causal, masked, rate, biased, block_q,
         m_scr[:] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
 
-    run = _run_cond(causal, valid, qi, ki, block_q, block_k)
+    run = _run_cond(causal, valid, qi, ki, block_q, block_k, window)
     if run is True:
         _compute()
     else:
@@ -240,15 +262,71 @@ def _extra_specs_and_args(kv_valid, seed):
     return specs, args
 
 
+def _kv_index(group, window, bq, bk, nk):
+    """Index map (b, qblk, kblk) -> K/V block, for the forward and dq grids:
+    query row b reads key/value row b // group; with a window a step that
+    is skipped stays on the nearest block that runs, and a block that does
+    not change is not copied in again."""
+    if group == 1 and window is None:
+        return lambda b, i, j: (b, j, 0)
+
+    def index(b, i, j):
+        if window is not None:
+            lo = jnp.maximum(i * bq - window + 1, 0) // bk
+            hi = jnp.minimum((i * bq + bq - 1) // bk, nk - 1)
+            j = jnp.clip(j, lo, hi)
+        return (b // group, j, 0)
+    return index
+
+
+def _q_index(group, window, bq, bk, nq):
+    """Index map (b, kblk, step) -> block of q, do, lse or delta, for the
+    dk/dv grid: key/value row b is read by the query rows b·group …
+    b·group + group - 1, whose q blocks the innermost axis walks in turn;
+    the window's clamp as in _kv_index."""
+    if group == 1 and window is None:
+        return lambda b, j, i: (b, i, 0)
+
+    def index(b, j, step):
+        i = step
+        if group > 1:
+            b, i = b * group + step // nq, jax.lax.rem(step, nq)
+        if window is not None:
+            lo = (j * bk) // bq
+            hi = jnp.minimum((j * bk + bk + window - 2) // bq, nq - 1)
+            i = jnp.clip(i, lo, hi)
+        return (b, i, 0)
+    return index
+
+
+def _blocks(t, tk, block_q=None, block_k=None):
+    """The (q, k) block sizes a call runs in: the caller's, or the tuned
+    defaults, never beyond the sequence."""
+    return (min(block_q or _pick_block(t, 512), t),
+            min(block_k or _pick_block(tk, 1024), tk))
+
+
+def blocks_run(t, tk, causal=True, window=None, block_q=None, block_k=None):
+    """(blocks in the (q block, k block) grid of one head, blocks of it that
+    run), by the kernels' own _run_cond on the whole grid at once; at the
+    block sizes flash_attention() would take."""
+    import numpy as np
+    bq, bk = _blocks(t, tk, block_q, block_k)
+    qi, ki = np.meshgrid(np.arange(_cdiv(t, bq)), np.arange(_cdiv(tk, bk)),
+                         indexing="ij")
+    run = _run_cond(causal, None, qi, ki, bq, bk, window)
+    return qi.size, qi.size if run is True else int(np.sum(run))
+
+
 # The kernel calls are jitted with `interpret` among the static arguments: a
 # model's layers then share one traced and lowered copy of each kernel
 # (twelve BERT layers lowered 36 pallas_calls one by one: a second a step
 # program and 300 KB of module text, paid in every process, compile-cache
 # hit or not), and a trace made in interpret mode is never taken for a
 # Mosaic one.
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate, block_q,
-         block_k, interpret):
+         block_k, interpret, window=None):
     bh, t, d = q.shape
     tk = k.shape[1]
     block_q = min(block_q, t)
@@ -258,7 +336,9 @@ def _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate, block_q,
     biased = bias is not None
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                masked=masked, rate=rate, biased=biased,
-                               block_q=block_q, block_k=block_k)
+                               block_q=block_q, block_k=block_k,
+                               window=window)
+    kv_index = _kv_index(bh // k.shape[0], window, block_q, block_k, grid[2])
     bias_specs, bias_args = ([], [])
     if biased:
         bias_specs = [_bias_spec(bias, bh, block_q, block_k)]
@@ -273,10 +353,8 @@ def _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate, block_q,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, d), kv_index, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, d), kv_index, memory_space=pltpu.VMEM),
         ] + extra_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
@@ -304,7 +382,7 @@ def _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate, block_q,
 # backward: dq kernel (grid k-innermost, accumulate dq over k blocks)
 # ----------------------------------------------------------------------------
 def _bwd_dq_kernel(*refs, scale, causal, masked, rate, biased, block_q,
-                   block_k):
+                   block_k, window=None):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, \
         valid_ref, seed_ref, tail = _split_refs(refs, 6, masked, rate,
                                                 biased)
@@ -341,7 +419,7 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, rate, biased, block_q,
                                 preferred_element_type=jnp.float32) * scale
         if biased:
             s = s + bias_ref[0].astype(jnp.float32)
-        s = _score_mask(s, valid, causal, qi, ki, block_q, block_k)
+        s = _score_mask(s, valid, causal, qi, ki, block_q, block_k, window)
         p = jnp.exp(s - lse[:, None])                          # (Bq, Bk)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -359,7 +437,7 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, rate, biased, block_q,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    run = _run_cond(causal, valid, qi, ki, block_q, block_k)
+    run = _run_cond(causal, valid, qi, ki, block_q, block_k, window)
     if run is True:
         _compute()
     else:
@@ -374,7 +452,7 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, rate, biased, block_q,
 # backward: dk/dv kernel (grid q-innermost, accumulate dk,dv over q blocks)
 # ----------------------------------------------------------------------------
 def _bwd_dkv_kernel(*refs, scale, causal, masked, rate, biased, block_q,
-                    block_k):
+                    block_k, window=None, group=1):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, \
         valid_ref, seed_ref, tail = _split_refs(refs, 6, masked, rate,
                                                 biased)
@@ -382,11 +460,14 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, rate, biased, block_q,
 
     b = pl.program_id(0)
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    # the innermost axis walks the q blocks of each of the `group` query
+    # heads that read this key/value row, one head after the other
+    step = pl.program_id(2)
+    nsteps = pl.num_programs(2)
+    qi = step if group == 1 else jax.lax.rem(step, nsteps // group)
     valid = valid_ref[jax.lax.rem(b, _VALID_BLOCK)] if masked else None
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -403,7 +484,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, rate, biased, block_q,
                                 preferred_element_type=jnp.float32) * scale
         if biased:
             s = s + bias_ref[0].astype(jnp.float32)
-        s = _score_mask(s, valid, causal, qi, ki, block_q, block_k)
+        s = _score_mask(s, valid, causal, qi, ki, block_q, block_k, window)
         p = jnp.exp(s - lse[:, None])                          # (Bq, Bk)
         if rate > 0.0:
             # same (seed, b, qi, ki) triple as fwd/dq → identical bits
@@ -425,25 +506,28 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, rate, biased, block_q,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    run = _run_cond(causal, valid, qi, ki, block_q, block_k)
+    run = _run_cond(causal, valid, qi, ki, block_q, block_k, window)
     if run is True:
         _compute()
     else:
         pl.when(run)(_compute)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nsteps - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5))
-def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 8))
+def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do,
+              window=None):
     q, k, v, kv_valid, seed, bias, out, lse = res
     bh, t, d = q.shape
-    tk = k.shape[1]
+    bhk, tk = k.shape[:2]
+    group = bh // bhk
     bq = min(block_q, t)
     bk = min(block_k, tk)
+    nq, nk = _cdiv(t, bq), _cdiv(tk, bk)
     masked = kv_valid is not None
     biased = bias is not None
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
@@ -455,7 +539,7 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do):
 
     qspec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0),
+    kspec = pl.BlockSpec((1, bk, d), _kv_index(group, window, bq, bk, nk),
                          memory_space=pltpu.VMEM)
     rowq = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
                         memory_space=pltpu.VMEM)
@@ -473,8 +557,8 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do):
     dq_out = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           masked=masked, rate=rate, biased=biased,
-                          block_q=bq, block_k=bk),
-        grid=(bh, _cdiv(t, bq), _cdiv(tk, bk)),
+                          block_q=bq, block_k=bk, window=window),
+        grid=(bh, nq, nk),
         in_specs=[qspec, kspec, kspec, qspec, rowq, rowq] + bias_specs
         + extra_specs,
         out_specs=out_specs,
@@ -496,19 +580,20 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do):
         dq = dq_out
         db = None
 
-    # dk/dv: swap grid so q is innermost; index maps take (b, kblk, qblk)
-    qspec2 = pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0),
-                          memory_space=pltpu.VMEM)
+    # dk/dv: swap grid so q is innermost; index maps take (b, kblk, qblk),
+    # b the key/value row, whose `group` query heads the innermost axis
+    # walks one after the other: dk and dv come out summed over them
+    q_index = _q_index(group, window, bq, bk, nq)
+    qspec2 = pl.BlockSpec((1, bq, d), q_index, memory_space=pltpu.VMEM)
     kspec2 = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
                           memory_space=pltpu.VMEM)
-    rowq2 = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0),
-                         memory_space=pltpu.VMEM)
+    rowq2 = pl.BlockSpec((1, bq, 1), q_index, memory_space=pltpu.VMEM)
     bias_specs2 = [_bias_spec(bias, bh, bq, bk, swap=True)] if biased else []
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           masked=masked, rate=rate, biased=biased,
-                          block_q=bq, block_k=bk),
-        grid=(bh, _cdiv(tk, bk), _cdiv(t, bq)),
+                          block_q=bq, block_k=bk, window=window, group=group),
+        grid=(bhk, nk, group * nq),
         # the SMEM scalar index maps only use the leading batch axis, so the
         # same specs serve both backward grids
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2]
@@ -526,24 +611,24 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do):
 # ----------------------------------------------------------------------------
 # public entry
 # ----------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
 def _flash_core(q, k, v, kv_valid, seed, bias, scale, causal, rate,
-                block_q, block_k):
+                block_q, block_k, window):
     out, _ = _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate,
-                  block_q, block_k, _interpret())
+                  block_q, block_k, _interpret(), window)
     return out
 
 
 def _flash_fwd_rule(q, k, v, kv_valid, seed, bias, scale, causal, rate,
-                    block_q, block_k):
+                    block_q, block_k, window):
     out, lse = _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate,
-                    block_q, block_k, _interpret())
+                    block_q, block_k, _interpret(), window)
     return out, (q, k, v, kv_valid, seed, bias, out, lse)
 
 
-def _bwd(scale, causal, rate, block_q, block_k, res, do):
+def _bwd(scale, causal, rate, block_q, block_k, window, res, do):
     return _bwd_call(scale, causal, rate, block_q, block_k, _interpret(),
-                     res, do)
+                     res, do, window)
 
 
 _flash_core.defvjp(_flash_fwd_rule, _bwd)
@@ -551,11 +636,18 @@ _flash_core.defvjp(_flash_fwd_rule, _bwd)
 
 def flash_attention(q, k, v, scale=None, causal=False, kv_valid=None,
                     dropout_rate=0.0, dropout_seed=None, bias=None,
-                    bias_groups=None, block_q=None, block_k=None):
+                    bias_groups=None, block_q=None, block_k=None,
+                    window=None):
     """softmax(q·kᵀ·scale [+causal/padding mask])·v, blockwise.
     q/k/v: (BH, T, D).  scale defaults to 1/sqrt(D); blocks default to the
     tuned sizes.  T (for both q and k/v) must tile exactly by the chosen
     blocks — partial K blocks would feed padded garbage into the softmax.
+
+    window: with `causal`, query i sees only the keys i - window < j <= i
+    (itself among them); blocks wholly before every window are skipped.
+    Grouped heads: k/v may have BH / G rows, query row b then reads
+    key/value row b // G, and dk, dv come out summed over the G query rows
+    (no padding mask, dropout or bias with G > 1).
 
     kv_valid: optional (BH,) int32, number of valid keys per row (≥1); key
     columns beyond it are masked out and whole K blocks beyond it skipped.
@@ -574,9 +666,21 @@ def flash_attention(q, k, v, scale=None, causal=False, kv_valid=None,
     O(T²) bias."""
     t, tk = q.shape[1], k.shape[1]
     scale = 1.0 / math.sqrt(q.shape[-1]) if scale is None else scale
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"flash_attention: window {window!r} needs "
+                         "causal=True and at least one key")
+    if k.shape != v.shape or q.shape[0] % k.shape[0]:
+        raise ValueError(
+            f"flash_attention: k {k.shape} and v {v.shape} must agree, and "
+            f"their rows divide q's {q.shape[0]}")
+    if q.shape[0] != k.shape[0] and (
+            kv_valid is not None or bias is not None or dropout_rate > 0.0):
+        raise ValueError(
+            "flash_attention: grouped heads take no padding mask, dropout "
+            "or bias; gate callers with supported(..., kv_heads=...)")
     block_q = block_q or _pick_block(t, 512)
     block_k = block_k or _pick_block(tk, 1024)
-    bq, bk = min(block_q, t), min(block_k, tk)
+    bq, bk = _blocks(t, tk, block_q, block_k)
     if t % bq or tk % bk:
         raise ValueError(
             f"flash_attention: seq lens (q={t}, kv={tk}) must be divisible "
@@ -615,7 +719,8 @@ def flash_attention(q, k, v, scale=None, causal=False, kv_valid=None,
                 f"bias_groups and dividing BH={bh} — a bare divisor is "
                 "ambiguous between per-head and per-batch")
     return _flash_core(q, k, v, kv_valid, dropout_seed, bias, scale,
-                       causal, float(dropout_rate), block_q, block_k)
+                       causal, float(dropout_rate), block_q, block_k,
+                       None if window is None else int(window))
 
 
 
@@ -635,13 +740,15 @@ def _pick_block(t, prefer):
 
 def mha_flash_attention(q, k, v, causal=False, valid_length=None,
                         dropout_rate=0.0, dropout_seed=None, bias=None,
-                        block_q=None, block_k=None):
-    """Multi-head wrapper: q/k/v are (B, H, T, D); collapses batch*heads,
-    runs the Pallas kernel, restores the layout.  valid_length is per-batch
-    (B,) and is broadcast across heads.  Default blocks tuned on v5e-class
-    hardware: large K blocks amortize the scratch carry."""
+                        block_q=None, block_k=None, window=None):
+    """Multi-head wrapper: q is (B, H, T, D), k/v (B, H_kv, T, D) with H a
+    multiple of H_kv (query head h reads key/value head h // (H / H_kv));
+    collapses batch*heads, runs the Pallas kernel, restores the layout.
+    valid_length is per-batch (B,) and is broadcast across heads.  Default
+    blocks tuned on v5e-class hardware: large K blocks amortize the scratch
+    carry."""
     b, h, t, d = q.shape
-    fold = lambda x: x.reshape(b * h, x.shape[2], d)
+    fold = lambda x: x.reshape(b * x.shape[1], x.shape[2], d)
     kv_valid = None
     if valid_length is not None:
         kv_valid = jnp.repeat(jnp.asarray(valid_length, jnp.int32), h)
@@ -667,20 +774,28 @@ def mha_flash_attention(q, k, v, causal=False, valid_length=None,
                 b * h, t, tk)
     out = flash_attention(fold(q), fold(k), fold(v), None, causal,
                           kv_valid, dropout_rate, dropout_seed, kbias,
-                          bias_groups, block_q, block_k)
+                          bias_groups, block_q, block_k, window)
     return out.reshape(b, h, t, d)
 
 
-def supported(q_shape, dtype, kv_len=None, dropout_rate=0.0):
+def supported(q_shape, dtype, kv_len=None, dropout_rate=0.0, kv_heads=None,
+              window=None, causal=True, plain=True):
     """Whether the Pallas path handles this problem: head dim a multiple of
     the VPU lane half-count (dense MXU tiles), BOTH sequence lengths
     multiples of the smallest block so K blocks tile exactly, and — when
     attention dropout is active — a real TPU backend (the kernel PRNG has
-    no interpret lowering)."""
+    no interpret lowering).  A window needs `causal`; `kv_heads` other than
+    q's own (q_shape (B, H, T, D)) have to divide them and need a `plain`
+    call: no padding mask, no dropout, no bias."""
     d = q_shape[-1]
     t = q_shape[-2]
     kv_len = t if kv_len is None else kv_len
     if dropout_rate > 0.0 and _interpret():
+        return False
+    if window is not None and (not causal or window < 1):
+        return False
+    if kv_heads is not None and kv_heads != q_shape[-3] and (
+            q_shape[-3] % kv_heads or dropout_rate > 0.0 or not plain):
         return False
     return d % 64 == 0 and t % 128 == 0 and kv_len % 128 == 0 and \
         jnp.dtype(dtype).name in ("float32", "bfloat16")

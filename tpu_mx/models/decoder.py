@@ -1,12 +1,16 @@
 """Causal decoder blocks, named by mechanism and not by model (ROADMAP M1):
-rotary positions, a gated MLP, latent attention, a pre-norm layer, and a
-causal language model with its next-token loss inside the forward and an
-optional multi-token-prediction module.  The expert layer is
-`parallel.DroplessMoE`.
+rotary positions, a gated MLP, latent attention, grouped-query attention
+with an optional window and optional positions, a pre-norm layer, and a
+causal language model with its next-token loss inside the forward (whole,
+or in chunks of positions) and an optional multi-token-prediction module.
+The expert layer is `parallel.DroplessMoE`.
 
 The equations are those of the DeepSeek-V3 family (arXiv:2412.19437,
 sections 2.1 and 2.2); tests/references/latent_moe_decoder.py is their plain
 float32 form, and tests/test_latent_moe_decoder.py holds the two together.
+The grouped-query layers, the router fed from before the attention and the
+chunked head (ISSUE 31) follow tests/references/windowed_gqa_decoder.py,
+held together by tests/test_windowed_gqa_decoder.py.
 Training form only: nothing is absorbed, there is no cache (serving is
 ROADMAP M4-M8).  No bias and no dropout anywhere.
 
@@ -24,8 +28,8 @@ from ..ndarray import ops
 from ..parallel import DroplessMoE, attention as _attention
 from ..parallel.moe import MOE_SCOPES
 
-__all__ = ["rotary", "GatedMLP", "LatentAttention", "DecoderLayer",
-           "CausalLM", "DECODER_SCOPES"]
+__all__ = ["rotary", "GatedMLP", "LatentAttention", "GroupedQueryAttention",
+           "DecoderLayer", "CausalLM", "DECODER_SCOPES", "ATTENTION_SCOPES"]
 
 # jax.named_scope names inside these blocks (HLO metadata only), beside
 # train_step.STEP_SCOPES; benchmark/decoder_scopes.py holds them as literals
@@ -33,17 +37,29 @@ DECODER_SCOPES = ("mla.project", "mla.attend") + MOE_SCOPES \
     + ("mtp", "lm_head")
 _PROJECT, _ATTEND = DECODER_SCOPES[:2]
 _MTP, _LM_HEAD = DECODER_SCOPES[-2:]
+# GroupedQueryAttention's own (benchmark/attention_scopes.py holds them as
+# literals): its projections, and its attention by the layer's kind
+ATTENTION_SCOPES = ("attn.project", "attn.window", "attn.full")
+_GQ_PROJECT, _GQ_WINDOW, _GQ_FULL = ATTENTION_SCOPES
 
 
-def rotary(x, theta):
+def rotary(x, theta, pairs="interleaved"):
     """Rotary positions (Su et al., arXiv:2104.09864) over the whole last
-    axis of x (..., T, d), pairs interleaved: (x[2i], x[2i+1]) turns by
-    position · theta^(-2i/d).  Angles and the turn in f32."""
+    axis of x (..., T, d): pair i turns by position · theta^(-2i/d).
+    `pairs` "interleaved": pair i is (x[2i], x[2i+1]); "halves": (x[i],
+    x[i + d/2]), the `rotate_half` convention.  Angles and the turn in
+    f32."""
     t, d = x.shape[-2], x.shape[-1]
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
+    if pairs == "halves":
+        a, b = xf[..., :d // 2], xf[..., d // 2:]
+        return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                               axis=-1).astype(x.dtype)
+    if pairs != "interleaved":
+        raise ValueError(f"rotary pairs {pairs!r}: interleaved or halves")
     a, b = xf[..., 0::2], xf[..., 1::2]
     return jnp.stack([a * cos - b * sin, a * sin + b * cos],
                      axis=-1).reshape(x.shape).astype(x.dtype)
@@ -147,20 +163,85 @@ class LatentAttention(HybridBlock):
             return _linear(F, out, o_weight)
 
 
+class GroupedQueryAttention(HybridBlock):
+    """Causal attention with `num_heads` query heads over `num_kv_heads`
+    key/value heads of `head_dim` (query head h reads key/value head
+    h // (num_heads / num_kv_heads)), no bias.  `rope_theta` None: no
+    positions at all; else q and k turn by rotary positions over the whole
+    head (`rotary_pairs` as `rotary` takes them).  `window` None: query i
+    sees every key j <= i; else the last `window` of them, i - window < j
+    <= i.  Through `parallel.attention`, which hands k and v to the flash
+    kernel with the heads they have."""
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 rope_theta=None, window=None, rotary_pairs="halves",
+                 mesh=None, **kwargs):
+        super().__init__(**kwargs)
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads over {num_kv_heads} "
+                             "key/value heads")
+        self._h, self._hkv, self._d = num_heads, num_kv_heads, head_dim
+        self._theta = None if rope_theta is None else float(rope_theta)
+        self._window, self._pairs, self._mesh = window, rotary_pairs, mesh
+        self.q_weight = self.params.get(
+            "q_weight", shape=(num_heads * head_dim, units))
+        self.k_weight = self.params.get(
+            "k_weight", shape=(num_kv_heads * head_dim, units))
+        self.v_weight = self.params.get(
+            "v_weight", shape=(num_kv_heads * head_dim, units))
+        self.o_weight = self.params.get(
+            "o_weight", shape=(units, num_heads * head_dim))
+
+    def _heads(self, x, n, turn):
+        b, t = x.shape[:2]
+        x = x.reshape(b, t, n, self._d).transpose(0, 2, 1, 3)
+        return rotary(x, self._theta, self._pairs) if turn else x
+
+    def hybrid_forward(self, F, x, q_weight, k_weight, v_weight, o_weight):
+        b, t = x.shape[:2]
+        turn = self._theta is not None
+        with jax.named_scope(_GQ_PROJECT):
+            q, k, v = (_linear(F, x, w)
+                       for w in (q_weight, k_weight, v_weight))
+        with jax.named_scope(_GQ_FULL if self._window is None
+                             else _GQ_WINDOW):
+            q = ops._apply(lambda a: self._heads(a, self._h, turn), [q],
+                           "query_heads")
+            k = ops._apply(lambda a: self._heads(a, self._hkv, turn), [k],
+                           "key_heads")
+            v = ops._apply(lambda a: self._heads(a, self._hkv, False), [v],
+                           "value_heads")
+            out = ops._apply(
+                lambda qq, kk, vv: _attention(
+                    qq, kk, vv, mesh=self._mesh, causal=True,
+                    window=self._window), [q, k, v], "RingAttention")
+            out = ops._apply(
+                lambda o: o.transpose(0, 2, 1, 3).reshape(
+                    b, t, self._h * self._d), [out], "merge_heads")
+        with jax.named_scope(_GQ_PROJECT):
+            return _linear(F, out, o_weight)
+
+
 class DecoderLayer(HybridBlock):
     """Pre-norm residual layer: x + attention(RMS(x)), then x + ffn(RMS(x));
-    `attention` and `ffn` are the blocks given."""
+    `attention` and `ffn` are the blocks given.  With
+    `router_before_attention` the ffn (an expert layer) is also handed the
+    layer's raw input, from which it takes its router's scores."""
 
-    def __init__(self, units, attention, ffn, epsilon=1e-6, **kwargs):
+    def __init__(self, units, attention, ffn, epsilon=1e-6,
+                 router_before_attention=False, **kwargs):
         super().__init__(**kwargs)
         self.ln1 = nn.RMSNorm(epsilon=epsilon, in_channels=units)
         self.attention = attention
         self.ln2 = nn.RMSNorm(epsilon=epsilon, in_channels=units)
         self.ffn = ffn
+        self._router_first = router_before_attention
 
     def hybrid_forward(self, F, x):
-        x = x + self.attention(self.ln1(x))
-        return x + self.ffn(self.ln2(x))
+        y = x + self.attention(self.ln1(x))
+        if self._router_first:
+            return y + self.ffn(self.ln2(y), x)
+        return y + self.ffn(self.ln2(y))
 
 
 class _MultiTokenModule(HybridBlock):
@@ -183,18 +264,51 @@ class _MultiTokenModule(HybridBlock):
         return self.final_norm(self.layer(_linear(F, joined, eh_proj_weight)))
 
 
+def _logits(hidden, head):
+    """f32: the MXU's accumulator, as for BERTModel's head."""
+    return jnp.einsum("btu,vu->btv", hidden, head,
+                      preferred_element_type=jnp.float32)
+
+
+def _nll(logits, labels):
+    return -jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
+                                labels[..., None], axis=-1)[..., 0]
+
+
 def _next_token_loss(hidden, head, tokens, shift):
     """(logits (B, T, V) f32, mean cross-entropy of position i against
-    token i + shift over the T - shift positions that have one).  The f32
-    is the MXU's accumulator, as for BERTModel's head."""
-    logits = jnp.einsum("btu,vu->btv", hidden, head,
-                        preferred_element_type=jnp.float32)
+    token i + shift over the T - shift positions that have one)."""
+    logits = _logits(hidden, head)
     labels = jnp.roll(tokens.astype(jnp.int32), -shift, axis=1)
-    nll = -jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
-                               labels[..., None], axis=-1)[..., 0]
+    nll = _nll(logits, labels)
     n = tokens.shape[1] - shift
     valid = jnp.arange(tokens.shape[1]) < n
     return logits, jnp.sum(jnp.where(valid, nll, 0.0)) / (tokens.shape[0] * n)
+
+
+def _chunked_next_token_loss(hidden, head, tokens, shift, chunk, stride):
+    """_next_token_loss without the (B, T, V) logits: the loss is summed
+    over chunks of `chunk` positions (it divides T) by a `lax.map` whose
+    body is under `jax.checkpoint`, so that forward and backward each hold
+    one chunk's logits at a time; the logits returned are those of every
+    `stride`-th position only, from a product of their own (a step that
+    does not read them does not compute them)."""
+    b, t = tokens.shape
+    if t % chunk:
+        raise ValueError(f"loss_chunk {chunk} does not divide T {t}")
+    labels = jnp.roll(tokens.astype(jnp.int32), -shift, axis=1)
+    valid = jnp.arange(t) < t - shift
+
+    @jax.checkpoint
+    def one(h, l, ok, w):
+        return jnp.sum(jnp.where(ok, _nll(_logits(h, w), l), 0.0))
+    split = lambda a: jnp.moveaxis(
+        a.reshape(b, t // chunk, chunk, *a.shape[2:]), 1, 0)
+    sums = jax.lax.map(lambda a: one(*a, head),
+                       (split(hidden), split(labels),
+                        valid.reshape(t // chunk, chunk)))
+    return _logits(hidden[:, ::stride], head), \
+        jnp.sum(sums) / (b * (t - shift))
 
 
 class CausalLM(HybridBlock):
@@ -208,9 +322,15 @@ class CausalLM(HybridBlock):
     module's (position i against token i + 2).
 
     config: vocab_size, units, num_layers, num_dense_layers, dense_hidden,
-    epsilon, attention (LatentAttention's arguments after units), moe
-    (hidden_size, num_experts, top_k, held_experts (lo, hi), scaling,
-    shared_hidden), mtp_depth (0 or 1), mtp_weight."""
+    epsilon, attention (the attention block's arguments after units: one
+    dict for every layer alike, or a list with one dict a layer, the
+    multi-token module's last; `kind` "latent", the default, for
+    LatentAttention, "grouped_query" for GroupedQueryAttention), moe
+    (hidden_size, num_experts, top_k,
+    held_experts (lo, hi), scaling, shared_hidden, scoring, activation,
+    router_before_attention), mtp_depth (0 or 1), mtp_weight, loss_chunk
+    (the head's loss in chunks of so many positions; the logits returned
+    are then those of every `logits_stride`-th position)."""
 
     def __init__(self, config, mesh=None, dtype="float32", remat=False,
                  **kwargs):
@@ -225,25 +345,41 @@ class CausalLM(HybridBlock):
             "head_weight", shape=(cfg["vocab_size"], units))
         self.final_norm = nn.RMSNorm(epsilon=eps, in_channels=units)
 
-        def layer(sparse):
+        def attention(i):
+            att = cfg["attention"]
+            att = dict(att[i] if isinstance(att, (list, tuple)) else att)
+            kind = att.pop("kind", "latent")
+            if kind == "latent":
+                return LatentAttention(units, epsilon=eps, mesh=mesh, **att)
+            if kind != "grouped_query":
+                raise ValueError(f"attention kind {kind!r}: latent or "
+                                 "grouped_query")
+            return GroupedQueryAttention(units, mesh=mesh, **att)
+
+        def layer(sparse, i):
+            moe = cfg["moe"] if sparse else {}
             if sparse:
-                moe = cfg["moe"]
                 ffn = DroplessMoE(
                     units, moe["hidden_size"], moe["num_experts"],
                     moe["top_k"], held_experts=range(*moe["held_experts"]),
                     scaling=moe.get("scaling", 1.0),
                     shared=GatedMLP(units, moe["shared_hidden"])
-                    if moe.get("shared_hidden") else None)
+                    if moe.get("shared_hidden") else None,
+                    scoring=moe.get("scoring", "sigmoid"),
+                    activation=moe.get("activation", "silu"))
             else:
                 ffn = GatedMLP(units, cfg["dense_hidden"])
             return DecoderLayer(
-                units, LatentAttention(units, epsilon=eps, mesh=mesh,
-                                       **cfg["attention"]), ffn, epsilon=eps)
+                units, attention(i), ffn, epsilon=eps,
+                router_before_attention=moe.get("router_before_attention",
+                                                False))
         self.layers = nn.HybridSequential()
         for i in range(cfg["num_layers"]):
-            self.layers.add(layer(i >= cfg.get("num_dense_layers", 0)))
+            self.layers.add(layer(i >= cfg.get("num_dense_layers", 0), i))
         if cfg.get("mtp_depth", 0):
-            self.mtp = _MultiTokenModule(units, layer(True), eps)
+            # the module's layer is one more (a list names it last)
+            self.mtp = _MultiTokenModule(
+                units, layer(True, cfg["num_layers"]), eps)
         if remat:
             # one checkpoint a layer, as BERTModel's: layer inputs stay,
             # the inside is recomputed in the backward pass
@@ -261,9 +397,14 @@ class CausalLM(HybridBlock):
         x = F.Embedding(tokens, embed_weight)
         for block in self.layers._children.values():
             x = block(x)
+        chunk, stride = (self._cfg.get(k) for k in ("loss_chunk",
+                                                    "logits_stride"))
         with jax.named_scope(_LM_HEAD):
             logits, loss = ops._apply(
-                lambda h, w, t: _next_token_loss(h, w, t, 1),
+                (lambda h, w, t: _next_token_loss(h, w, t, 1))
+                if chunk is None else
+                (lambda h, w, t: _chunked_next_token_loss(
+                    h, w, t, 1, chunk, stride or 1)),
                 [self.final_norm(x), head_weight, tokens], "next_token_loss")
         if "mtp" not in self._children:
             return loss, logits

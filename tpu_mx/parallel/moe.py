@@ -238,30 +238,50 @@ _unsort.defvjp(lambda ys, order, inv: (ys[inv], (order,)),
                lambda res, g: (g[res[0]], None, None))
 
 
-def dropless_route(x, gate_weight, select_bias, top_k, scaling=1.0):
+def dropless_route(x, gate_weight, select_bias, top_k, scaling=1.0,
+                   scoring="sigmoid"):
     """The choice and its weights, over all E experts, for tokens (S, U):
-    (chosen (S, k) expert ids, weights (S, k) f32).  Scores are
-    sigmoid(x · gate) in f32 whatever the model's type (x and the gate hold
-    bf16 values at most, whose products an f32 accumulator takes exactly);
-    the k largest of score + bias are chosen, the bias for the choice only
-    (noaux_tc: no gradient, no weight); weights are the chosen scores
-    normalised over all chosen, times `scaling`."""
-    scores = jax.nn.sigmoid(jnp.einsum(
+    (chosen (S, k) expert ids, weights (S, k) f32).  Scores come from
+    x · gate in f32 whatever the model's type (x and the gate hold bf16
+    values at most, whose products an f32 accumulator takes exactly).
+    `scoring` "sigmoid": scores are sigmoid(x · gate); the k largest of
+    score + bias are chosen, the bias for the choice only (noaux_tc: no
+    gradient, no weight); weights are the chosen scores normalised over all
+    chosen, times `scaling`.  "softmax": the k largest of x · gate + bias
+    are chosen, and the weights are the softmax over the chosen k of x ·
+    gate (the softmax over all E, normalised over the chosen, is the same
+    number), times `scaling`."""
+    logits = jnp.einsum(
         "su,eu->se", x.astype(jnp.float32), gate_weight.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        _, chosen = jax.lax.top_k(logits + select_bias.astype(jnp.float32),
+                                  top_k)
+        return chosen, jax.nn.softmax(
+            jnp.take_along_axis(logits, chosen, axis=-1), axis=-1) * scaling
+    if scoring != "sigmoid":
+        raise ValueError(f"scoring {scoring!r}: sigmoid or softmax")
+    scores = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     return chosen, picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20) \
         * scaling
 
 
-def _dropless_forward(x, gw, bias, w1, w3, w2, *, top_k, lo, scaling):
-    """Routing over all E experts and the held experts' part of the result,
-    on flattened tokens (S, U).  Returns (y (S, U), expert_load (E,) f32)."""
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _dropless_forward(x, router_x, gw, bias, w1, w3, w2, *, top_k, lo,
+                      scaling, scoring="sigmoid", activation="silu"):
+    """Routing over all E experts (on `router_x`, the rows the router reads:
+    x itself unless the model feeds its router from elsewhere) and the held
+    experts' part of the result, on flattened tokens (S, U).  Returns
+    (y (S, U), expert_load (E,) f32)."""
     S, U = x.shape
     E, H, k = gw.shape[0], w1.shape[0], top_k
     with jax.named_scope(_ROUTE):
-        chosen, weights = dropless_route(x, gw, bias, k, scaling)
+        chosen, weights = dropless_route(router_x, gw, bias, k, scaling,
+                                         scoring)
         flat = chosen.reshape(-1)
         load = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0)
         here = (chosen >= lo) & (chosen < lo + H)             # (S, k)
@@ -281,7 +301,7 @@ def _dropless_forward(x, gw, bias, w1, w3, w2, *, top_k, lo, scaling):
             # anything reads them, forward and, by where()'s transpose,
             # backward.  The select fuses into the SwiGLU that follows.
             return jnp.where(real, jax.lax.ragged_dot(a, w, sizes), 0)
-        h = jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3)
+        h = _GATES[activation](grouped(xs, w1)) * grouped(xs, w3)
         ys = grouped(h, w2)                                   # (S·k, U)
     with jax.named_scope(_COMBINE):
         y_tok = _unsort(ys, order, inv).reshape(S, k, U)
@@ -292,17 +312,20 @@ def _dropless_forward(x, gw, bias, w1, w3, w2, *, top_k, lo, scaling):
 
 
 class DroplessMoE(HybridBlock):
-    """Sparse SwiGLU experts without dropped tokens, for one holder of an
-    expert-parallel layer.
+    """Sparse gated experts (SwiGLU, or ReGLU with `activation` "relu")
+    without dropped tokens, for one holder of an expert-parallel layer.
 
-    forward(x: (..., units)) -> y: (..., units), the sum over each token's
-    chosen experts THAT ARE HELD HERE of `w_i · E_i(x)`, plus `shared(x)`
-    if a shared block is given.  Scores are sigmoid(x · gate) in f32 over
-    all `num_experts`; the `top_k` largest of score + `select_bias` are
-    chosen (the bias is a buffer without gradient, for the choice only);
-    weights are the chosen scores normalised over ALL chosen (held or
-    not), times `scaling`.  What the absent experts would add is left
-    out; nothing stands in for them or their exchange.
+    forward(x: (..., units)[, router_x]) -> y: (..., units), the sum over
+    each token's chosen experts THAT ARE HELD HERE of `w_i · E_i(x)`, plus
+    `shared(x)` if a shared block is given.  Scores are sigmoid(x · gate)
+    in f32 over all `num_experts`; the `top_k` largest of score +
+    `select_bias` are chosen (the bias is a buffer without gradient, for
+    the choice only); weights are the chosen scores normalised over ALL
+    chosen (held or not), times `scaling` (`scoring` "softmax":
+    dropless_route says how).  `router_x`, where given, is what the router
+    reads in place of x (a router placed before the attention reads the
+    layer's own input).  What the absent experts would add is left out;
+    nothing stands in for them or their exchange.
 
     Expert weights are stacked (held, in, out), the layout
     `jax.lax.ragged_dot` takes; `moe_sharding_rules()` shards their leading
@@ -311,8 +334,13 @@ class DroplessMoE(HybridBlock):
     a ring) and `steps_counted` are read by `load_census(net)`."""
 
     def __init__(self, units, hidden_size, num_experts, top_k,
-                 held_experts=None, scaling=1.0, shared=None, **kwargs):
+                 held_experts=None, scaling=1.0, shared=None,
+                 scoring="sigmoid", activation="silu", **kwargs):
         super().__init__(**kwargs)
+        if scoring not in ("sigmoid", "softmax") or activation not in _GATES:
+            raise ValueError(f"scoring {scoring!r} (sigmoid or softmax), "
+                             f"activation {activation!r} ({list(_GATES)})")
+        self._scoring, self._activation = scoring, activation
         held = range(num_experts) if held_experts is None else held_experts
         if list(held) != list(range(held.start, held.stop)) or not \
                 0 <= held.start < held.stop <= num_experts:
@@ -354,19 +382,25 @@ class DroplessMoE(HybridBlock):
         for p in (self.select_bias, self.expert_load, self.steps_counted):
             p.cast("float32")
 
-    def hybrid_forward(self, F, x, gate_weight, select_bias, expert_w1,
-                       expert_w3, expert_w2, expert_load, steps_counted):
+    def hybrid_forward(self, F, x, router_x=None, *, gate_weight,
+                       select_bias, expert_w1, expert_w3, expert_w2,
+                       expert_load, steps_counted):
         shape = x.shape
+        routed = router_x is not None
 
-        def fn(xa, gw, bias, w1, w3, w2):
+        def fn(xa, *rest):      # rest: [router_x,] then the five parameters
+            rows = xa.reshape((-1, shape[-1]))
             y, load = _dropless_forward(
-                xa.reshape((-1, shape[-1])), gw, bias, w1, w3, w2,
-                top_k=self._k, lo=self._held.start, scaling=self._scaling)
+                rows, rest[0].reshape(rows.shape) if routed else rows,
+                *rest[routed:], top_k=self._k, lo=self._held.start,
+                scaling=self._scaling, scoring=self._scoring,
+                activation=self._activation)
             return y.reshape(shape), load
 
         y, load = ops._apply(
-            fn, [x, gate_weight, select_bias, expert_w1, expert_w3,
-                 expert_w2], "DroplessMoE")
+            fn, [x] + [router_x] * routed
+            + [gate_weight, select_bias, expert_w1, expert_w3, expert_w2],
+            "DroplessMoE")
         if autograd.is_training():
             with autograd.pause():
                 history, count, load = (getattr(a, "_data", a) for a in

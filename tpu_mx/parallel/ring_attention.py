@@ -36,9 +36,10 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import random as _random
+from .. import telemetry as _telemetry
 
 __all__ = ["ring_attention", "attention", "local_flash_attention",
-           "dispatch_counts"]
+           "dispatch_counts", "window_blocks"]
 
 _logger = logging.getLogger(__name__)
 
@@ -49,6 +50,11 @@ _logger = logging.getLogger(__name__)
 # weak#6).  Mirrored into profiler counters.
 dispatch_counts = {"ring": 0, "ulysses": 0, "pallas_flash": 0,
                    "xla_dense": 0}
+# For every flash call with a window, as it is traced: the (q block, k block)
+# pairs of one head's grid and those of them that run (the rest lie above
+# the diagonal or wholly before every query's window and are skipped).
+# Mirrored into the telemetry counter attention.window_blocks{kind=grid|run}.
+window_blocks = {"grid": 0, "run": 0}
 
 
 def _auto_prefers_flash(q_len, kv_len, dropped, on_tpu):
@@ -67,9 +73,16 @@ def _auto_prefers_flash(q_len, kv_len, dropped, on_tpu):
     pays a fixed cost a grid step instead, which is what loses at T 128.
     With that dropout off, ms a step dense against flash: T 256 219.1
     against 235.3, T 512 274.8 against 230.2.
-    Only square shapes were measured, so the shorter of the two lengths is
-    held to the crossover; beyond kv 512 the kernel always was the choice,
-    for the memory."""
+    Only square shapes at D 64 were measured for the crossover, so the
+    shorter of the two lengths is held to it; beyond kv 512 the kernel
+    always was the choice, for the memory.  Far beyond it, D 128 and T
+    16,384 with 28 query heads over 4 key/value heads (PERF.md section 6,
+    PR 31, smallthinker-21ba3b.extend16k's traced step, ms a layer): the
+    causal kernel forward 22.8 and its backward pair 54.0 (43% and 36% of
+    the MXU's peak for the pairs under the mask), with a window of 4,096
+    forward 11.9 and backward 27.7, 0.52 of the whole past's for 0.515 of
+    its blocks; dense has no arm there (its scores alone would be 28 GiB
+    a layer in f32).  The rule itself did not change."""
     if not on_tpu:
         return False
     return kv_len > 512 or min(q_len, kv_len) >= (256 if dropped else 512)
@@ -256,7 +269,7 @@ def _ring_body(q, k, v, valid, seed, bias, *, axis_name, causal, scale,
 def ring_attention(q, k, v, mesh, axis_name="sp", causal=False,
                    q_spec=None, valid_length=None, dropout_rate=0.0,
                    dropout_key=None, bias=None, batch_axes=("dp", "tp"),
-                   step_chunk=None):
+                   step_chunk=None, window=None):
     """Sequence-parallel attention.  q/k/v: GLOBAL (B, H, T, D) arrays whose
     T axis is sharded over `axis_name`.  Returns attention output with the
     same sharding.  `q_spec` overrides the default
@@ -267,7 +280,9 @@ def ring_attention(q, k, v, mesh, axis_name="sp", causal=False,
     dropout_rate/dropout_key: attention-prob dropout, drawn per ring step.
     bias: (B|1, H|1, T, T) additive attention bias (ALiBi, relative
     position, …) — rows shard with q over `axis_name`, columns stay whole
-    and are sliced per ring step to match the rotating K block."""
+    and are sliced per ring step to match the rotating K block.
+    window: refused by name (ValueError), never dropped."""
+    _refuse_window(window, "ring_attention")
 
     def present(ax):
         return ax in mesh.axis_names
@@ -324,11 +339,25 @@ def _sp_valid_seed(q, masked, dropped, valid_length, dropout_key, spec):
     return valid, seed, vspec
 
 
-def _dense_mask(t, tk, causal, valid_length):
-    """Combined causal + key-padding mask, or None.  True = attend."""
+def _refuse_window(window, arm):
+    if window is not None:
+        raise ValueError(
+            f"{arm}: window={window} is not implemented on the sequence-"
+            "parallel arms (a window reaches over at most two ring steps); "
+            "run windowed layers without an `sp` axis")
+
+
+def _dense_mask(t, tk, causal, valid_length, window=None):
+    """Combined causal (with its window: query i sees keys i - window < j
+    <= i) + key-padding mask, or None.  True = attend."""
     mask = None
+    if window is not None and not causal:
+        raise ValueError(f"window={window} needs causal=True")
     if causal:
         mask = (jnp.arange(t)[:, None] >= jnp.arange(tk)[None, :])[None, None]
+        if window is not None:
+            mask &= (jnp.arange(t)[:, None] - jnp.arange(tk)[None, :]
+                     < window)[None, None]
     if valid_length is not None:
         km = (jnp.arange(tk)[None, None, None, :] <
               jnp.asarray(valid_length, jnp.int32)[:, None, None, None])
@@ -337,13 +366,17 @@ def _dense_mask(t, tk, causal, valid_length):
 
 
 def local_flash_attention(q, k, v, causal=False, valid_length=None,
-                          dropout_rate=0.0, dropout_key=None, bias=None):
+                          dropout_rate=0.0, dropout_key=None, bias=None,
+                          window=None):
     """Single-device attention with the same numerics as the ring kernel.
     On TPU with tile-friendly shapes this runs the Pallas flash kernel
     (tpu_mx.kernels.flash_attention: blockwise online softmax, O(T) memory,
     in-kernel padding mask, prob dropout, and additive bias — ALiBi/
     relative-position tensors stream block-by-block with a differentiable
-    d_bias); otherwise the XLA dense path."""
+    d_bias); otherwise the XLA dense path.  `window` (with causal): query i
+    sees keys i - window < j <= i.  k and v may have fewer heads than q
+    (grouped queries: head h reads key/value head h // (H / H_kv)); the
+    kernel reads them where they lie, the dense path repeats them."""
     from ..kernels import flash_attention as fa
     on_tpu = jax.default_backend() == "tpu"
     dropped = dropout_rate > 0.0 and dropout_key is not None
@@ -359,23 +392,41 @@ def local_flash_attention(q, k, v, causal=False, valid_length=None,
     want_flash = (mode == "flash" and on_tpu) or (
         mode == "auto" and _auto_prefers_flash(q.shape[2], k.shape[2],
                                                dropped, on_tpu))
-    if want_flash and fa.supported(q.shape, q.dtype, kv_len=k.shape[2],
-                                   dropout_rate=rate):
-        _count("pallas_flash", f"shape={q.shape}")
+    grouped = k.shape[1] != q.shape[1]
+    # what only the new calls show, so that the old ones count as they did
+    new = (f" kv_heads={k.shape[1]}" if grouped else "") + \
+        (f" window={window}" if window is not None else "")
+    if want_flash and fa.supported(
+            q.shape, q.dtype, kv_len=k.shape[2], dropout_rate=rate,
+            kv_heads=k.shape[1], window=window, causal=causal,
+            plain=valid_length is None and bias is None):
+        _count("pallas_flash", f"shape={q.shape}{new}")
+        if window is not None:
+            grid, run = fa.blocks_run(q.shape[2], k.shape[2], causal, window)
+            for kind, n in (("grid", grid), ("run", run)):
+                window_blocks[kind] += n
+                _telemetry.counter("attention.window_blocks",
+                                   kind=kind).inc(n)
         seed = (jax.random.randint(dropout_key, (1,), 0, 2 ** 31 - 1,
                                    jnp.int32) if dropped else None)
         return fa.mha_flash_attention(q, k, v, causal=causal,
                                       valid_length=valid_length,
                                       dropout_rate=rate, dropout_seed=seed,
-                                      bias=bias)
+                                      bias=bias, window=window)
     # CPU dense is expected, and a DELIBERATE dense choice (the A/B pin,
     # or auto's measured short-T preference) must not fire the
     # perf-regression warning — it exists for wanted-but-unsupported flash
     _count("xla_dense",
-           f"shape={q.shape} dtype={q.dtype} kv_len={k.shape[2]}",
+           f"shape={q.shape} dtype={q.dtype} kv_len={k.shape[2]}{new}",
            warn=want_flash)
     scale = 1.0 / math.sqrt(q.shape[-1])
-    mask = _dense_mask(q.shape[2], k.shape[2], causal, valid_length)
+    mask = _dense_mask(q.shape[2], k.shape[2], causal, valid_length, window)
+    if grouped:
+        if q.shape[1] % k.shape[1]:
+            raise ValueError(f"{q.shape[1]} query heads over {k.shape[1]} "
+                             "key/value heads")
+        k, v = (jnp.repeat(a, q.shape[1] // k.shape[1], axis=1)
+                for a in (k, v))
     m, l, o = _block_attn(q, k, v, bias=bias, mask=mask, scale=scale,
                           dropout_rate=rate, dropout_key=dropout_key)
     return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
@@ -383,14 +434,16 @@ def local_flash_attention(q, k, v, causal=False, valid_length=None,
 
 def attention(q, k, v, mesh=None, causal=False, valid_length=None,
               dropout_rate=0.0, dropout_key=None, bias=None,
-              sp_strategy=None):
+              sp_strategy=None, window=None):
     """Dispatch: sequence-parallel attention when a mesh with an `sp` axis
     is active (strategy 'ring' or 'ulysses' — per-call `sp_strategy`, else
     the module default set via `parallel.set_sp_strategy`; ulysses needs
     H % sp == 0 and quietly falls back to ring otherwise), local flash
     when not.  valid_length (B,) masks padded keys; dropout is
     attention-prob dropout (pass a key only in training mode); bias is an
-    additive (B|1, H|1, Tq, Tk) attention bias (ALiBi, relative pos)."""
+    additive (B|1, H|1, Tq, Tk) attention bias (ALiBi, relative pos);
+    window and grouped key/value heads as local_flash_attention says (the
+    sequence-parallel arms refuse a window by name)."""
     if sp_strategy is not None and sp_strategy not in ("ring", "ulysses"):
         # validate on EVERY call, not just sp>1 meshes — a typo must not
         # silently select the local path
@@ -408,12 +461,15 @@ def attention(q, k, v, mesh=None, causal=False, valid_length=None,
             return ulysses_attention(q, k, v, mesh, causal=causal,
                                      valid_length=valid_length,
                                      dropout_rate=dropout_rate,
-                                     dropout_key=dropout_key, bias=bias)
+                                     dropout_key=dropout_key, bias=bias,
+                                     window=window)
         return ring_attention(q, k, v, mesh, causal=causal,
                               valid_length=valid_length,
                               dropout_rate=dropout_rate,
-                              dropout_key=dropout_key, bias=bias)
+                              dropout_key=dropout_key, bias=bias,
+                              window=window)
     return local_flash_attention(q, k, v, causal=causal,
                                  valid_length=valid_length,
                                  dropout_rate=dropout_rate,
-                                 dropout_key=dropout_key, bias=bias)
+                                 dropout_key=dropout_key, bias=bias,
+                                 window=window)

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .ring_attention import _count, _sp_valid_seed, local_flash_attention
+from .ring_attention import (_count, _refuse_window, _sp_valid_seed,
+                             local_flash_attention)
 
 __all__ = ["ulysses_attention", "set_sp_strategy", "get_sp_strategy"]
 
@@ -99,12 +100,15 @@ def _ulysses_body(q, k, v, valid, seed, bias, *, axis_name, causal,
 
 def ulysses_attention(q, k, v, mesh, axis_name="sp", causal=False,
                       q_spec=None, valid_length=None, dropout_rate=0.0,
-                      dropout_key=None, bias=None, batch_axes=("dp", "tp")):
+                      dropout_key=None, bias=None, batch_axes=("dp", "tp"),
+                      window=None):
     """All-to-all sequence-parallel attention.  Same contract as
     `ring_attention`: q/k/v are GLOBAL (B, H, T, D) arrays with T sharded
     over `axis_name`; returns output with the same sharding.  Requires
     H % mesh.shape[axis_name] == 0 (raises otherwise — `attention()`
-    falls back to ring for such models)."""
+    falls back to ring for such models).  A window is refused by name, as
+    on the ring."""
+    _refuse_window(window, "ulysses_attention")
 
     def present(ax):
         # size-1 axes shard nothing — treat as absent so e.g. tp=1 meshes

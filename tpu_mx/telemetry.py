@@ -144,6 +144,10 @@ KNOWN_METRICS = frozenset({
     # training step wrote on the device): rows routed to the experts held
     # here, and the fullest held expert's rows
     "moe.rows_routed_here", "moe.max_expert_load",
+    # attention dispatch (tpu_mx/parallel/ring_attention.py; label `kind`):
+    # for every flash call with a window, as it is traced, the (q block, k
+    # block) pairs of one head's grid (`grid`) and those that run (`run`)
+    "attention.window_blocks",
     # kvstore eager path (tpu_mx/kvstore.py).  checksums counts payload
     # digests recorded at push time, checksum_failures the pulls whose
     # aggregate no longer matched — silent corruption crossing the sync
