@@ -391,6 +391,68 @@ def phase_d(tiny, platform, compiles):
         "D: every expert layer counted a choice for every token, rows "
         "routed here " + ", ".join(str(int(c["rows_routed_here"]))
                                    for c in census))
+    forced_past_the_first_slab(net, cfg)
+
+
+def forced_past_the_first_slab(net, cfg):
+    """One more step of one expert layer with the selection bias sending
+    every token to the two held experts: 2,048 rows against slabs of 1,024,
+    so the loop over slabs runs twice, forward and backward, here on the
+    chip, where XLA:TPU's grouped product leaves the rows no group owns
+    unwritten.  Its reference is the unforced layer that holds the same
+    two experts as ALL its experts: the same choice and weights without a
+    bias, one slab and no loop."""
+    import numpy as np
+    from tpu_mx import autograd, nd
+    from tpu_mx.parallel import DroplessMoE, load_census
+    found = []
+    net.apply_fn(lambda b: isinstance(b, DroplessMoE) and found.append(b))
+    trained = found[0]
+    units, moe = cfg["units"], cfg["moe"]
+
+    def layer(experts):
+        block = DroplessMoE(units, moe["hidden_size"], experts, 2,
+                            held_experts=range(2), scaling=moe["scaling"])
+        block.initialize()
+        block.cast("bfloat16")
+        block.gate_weight.set_data(trained.gate_weight.data()[:experts])
+        for name in ("expert_w1", "expert_w3", "expert_w2"):
+            getattr(block, name).set_data(getattr(trained, name).data())
+        # the bias on the held experts alone: it decides only where there
+        # are others to choose
+        block.select_bias.set_data(np.where(
+            np.arange(experts) < 2, 100.0, 0.0).astype(np.float32))
+        return block
+    rng = np.random.RandomState(SEED)
+    x, head = (nd.array(rng.randn(1024, units), dtype="bfloat16")
+               for _ in range(2))
+    sides = []
+    for block in (layer(moe["num_experts"]), layer(2)):
+        x.attach_grad()
+        with autograd.record():
+            y = block(x)
+        y.backward(head)
+        census, = load_census(block)
+        sides.append((census, [a.asnumpy().astype(np.float32) for a in (
+            y, x.grad, block.gate_weight.grad[:2], block.expert_w1.grad,
+            block.expert_w3.grad, block.expert_w2.grad)]))
+    (forced, got), (whole, want) = sides
+    check(forced["rows_routed_here"] == 2048 == 2 * forced["head_rows"]
+          and whole["head_rows"] == 2048,
+          f"D: the forced layer ran two slabs ({forced['rows_routed_here']:.0f}"
+          f" rows in slabs of {forced['head_rows']}) and the reference is "
+          "one slab")
+    check(all(np.isfinite(a).all() for a in got),
+          "D: the slabs left no unwritten row to read (all finite)")
+    errors = [float(np.sqrt(np.mean((a - b) ** 2))
+                    / (np.sqrt(np.mean(b ** 2)) + 1e-30))
+              for a, b in zip(got, want)]
+    # two bf16 programs: they stand one rounding apart (3e-3 to 4e-3 on the
+    # chip, PR 32), where an unwritten or a dropped row would read 1e-1 or NaN
+    check(max(errors) < 1e-2,
+          "D: two slabs under the loop equal the layer that is one slab, "
+          "relative error of y, dx, dgate, dw1, dw3, dw2: "
+          + " ".join(f"{e:.2e}" for e in errors))
 
 
 def main():

@@ -43,6 +43,7 @@ TestScopes = type("TestScopes", (), _cases(_load("test_scopes")))
 TestDecoderCell = type("TestDecoderCell", (), _cases(_load("test_decoder_cell")))
 TestWindowedCell = type("TestWindowedCell", (),
                         _cases(_load("test_windowed_cell")))
+TestExpertHead = type("TestExpertHead", (), _cases(_load("test_expert_head")))
 grown = _benchmark.grown        # test_benchmark.py's one fixture
 
 

@@ -267,6 +267,179 @@ def test_no_token_dropped_when_every_token_chooses_the_same_expert():
     assert census["rows_routed_here"] == 40
 
 
+# -- slabs of the sorted order under a loop (ISSUEs 32 and 33) ---------------
+def _plain_layer(x, router_x, gw, bias, w1, w3, w2, *, top_k, lo, scaling,
+                 scoring, activation):
+    """The held experts' part, every held expert applied to every token
+    under a dense mask, as reference.expert_layer does it; with the file's
+    reference's route where that has the scoring."""
+    logits = router_x @ gw.T
+    if scoring == "softmax":
+        _, chosen = jax.lax.top_k(logits + bias, top_k)
+        weights = jax.nn.softmax(
+            jnp.take_along_axis(logits, chosen, -1), -1) * scaling
+    else:
+        chosen, weights = reference.route(
+            router_x, {"router": gw, "bias": bias},
+            dict(top_k=top_k, scaling=scaling), (lo, lo + w1.shape[0]))
+    hit = chosen[:, :, None] == lo + jnp.arange(w1.shape[0])[None, None, :]
+    w = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), 1)  # (S, held)
+    gate = {"silu": jax.nn.silu, "relu": jax.nn.relu}[activation]
+    act = gate(jnp.einsum("su,eui->sei", x, w1)) \
+        * jnp.einsum("su,eui->sei", x, w3)
+    return jnp.einsum("sei,eiu,se->su", act, w2, w)
+
+
+# S 384, k 2 over 8 experts: 768 (token, choice) pairs; 2 held experts draw
+# 192 of them on average, so a slab is one tile of 512 rows and the sorted
+# order has two (the second padded from 256 pairs).  S 1024 over 16 experts:
+# 2,048 pairs, 2 held draw 256, four slabs of 512
+HEAD_CASES = {
+    # name: ((S, E), held, bias on the held experts, scoring, activation,
+    #        router_x, the slabs that the rows routed here reach)
+    "rows_under_the_head": ((384, 8), (2, 4), (0.0, 0.0), "sigmoid", "silu",
+                            False, 1),
+    "the_cut_inside_a_group": ((384, 8), (2, 4), (100.0, 0.3), "sigmoid",
+                               "silu", False, 2),
+    "every_token_here": ((384, 8), (2, 4), (100.0, 100.0), "sigmoid", "silu",
+                         False, 2),
+    "every_expert_held": ((384, 8), (0, 8), (0.0,) * 8, "sigmoid", "silu",
+                          False, 1),
+    "softmax_relu_forced": ((384, 8), (2, 4), (100.0, 100.0), "softmax",
+                            "relu", False, 2),
+    "softmax_silu": ((384, 8), (0, 2), (0.0, 0.0), "softmax", "silu", False,
+                     1),
+    "sigmoid_relu_over_the_head": ((384, 8), (2, 4), (100.0, 0.3), "sigmoid",
+                                   "relu", False, 2),
+    "router_fed_from_elsewhere": ((384, 8), (2, 4), (100.0, 100.0), "softmax",
+                                  "relu", True, 2),
+    "four_slabs_rows_under_the_head": ((1024, 16), (2, 4), (0.0, 0.0),
+                                       "sigmoid", "silu", False, 1),
+    "four_slabs_the_last_not_reached": ((1024, 16), (2, 4), (100.0, 0.15),
+                                        "sigmoid", "silu", False, 3),
+    "four_slabs_every_token_here": ((1024, 16), (2, 4), (100.0, 100.0),
+                                    "softmax", "relu", True, 4),
+}
+
+
+@pytest.mark.parametrize("case", list(HEAD_CASES))
+def test_the_slabs_are_the_plain_layer_and_drop_no_row(case):
+    """Output, the five gradients (and the router input's, where it has one)
+    and the load, for rows within the first slab, past it with a cut inside
+    an expert's group, with slabs that run and slabs that do not, and with
+    every token routed here."""
+    from tpu_mx.parallel import moe
+    (S, E), (lo, hi), held_bias, scoring, activation, routed, slabs_run = \
+        HEAD_CASES[case]
+    U, F, k = 32, 16, 2
+    keys = jax.random.split(jax.random.key(32), 7)
+    x, router_x, r = (jax.random.normal(q, (S, U)) for q in keys[:3])
+    gw = 0.3 * jax.random.normal(keys[3], (E, U))
+    w1, w3 = (0.3 * jax.random.normal(q, (hi - lo, U, F)) for q in keys[4:6])
+    w2 = 0.3 * jax.random.normal(keys[6], (hi - lo, F, U))
+    bias = jnp.zeros(E).at[lo:hi].set(jnp.asarray(held_bias))
+    kw = dict(top_k=k, lo=lo, scaling=1.8, scoring=scoring,
+              activation=activation)
+
+    def system(x, router_x, gw, w1, w3, w2):
+        y, load = moe._dropless_forward(
+            x, router_x if routed else x, gw, bias, w1, w3, w2, **kw)
+        return jnp.sum(y * r), (y, load)
+
+    def plain(x, router_x, gw, w1, w3, w2):
+        y = _plain_layer(x, router_x if routed else x, gw, bias, w1, w3, w2,
+                         **kw)
+        return jnp.sum(y * r), y
+    args = (x, router_x, gw, w1, w3, w2)
+    wrt = (0, 1, 2, 3, 4, 5) if routed else (0, 2, 3, 4, 5)
+    with jax.default_matmul_precision("highest"):
+        (_, (y, load)), grads = jax.jit(jax.value_and_grad(
+            system, wrt, has_aux=True))(*args)
+        (_, want), want_grads = jax.jit(jax.value_and_grad(
+            plain, wrt, has_aux=True))(*args)
+    assert rel(np.asarray(y), np.asarray(want)) < 1e-4
+    for i, g, h in zip(wrt, grads, want_grads):
+        assert rel(np.asarray(g), np.asarray(h)) < 1e-4, i
+    # no row dropped: every (token, choice) pair is counted, and the rows
+    # here are what the case says
+    load = np.asarray(load)
+    assert load.sum() == S * k
+    head = moe.head_rows(S * k, hi - lo, E)
+    rows, ends = load[lo:hi].sum(), np.cumsum(load[lo:hi])
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda *a: system(*a)[0]))(*args))
+    # a layer that holds every expert has one slab and no loop, forward or
+    # backward; no form stands behind a condition
+    assert ("while" in jaxpr) == (head < S * k) == (hi - lo < E)
+    assert "cond[" not in jaxpr
+    assert rows > 0 and -(-rows // head) == slabs_run, rows
+    if max(held_bias) == 100.0:
+        # a forced expert draws every token: a cut lies inside its group,
+        # and with both forced every pair is here
+        assert any(c not in ends for c in range(head, int(rows), head))
+        assert (rows == S * k) == (min(held_bias) == 100.0)
+
+
+def _grouped_products(jaxpr):
+    """`ragged_dot` equations in a jaxpr and in every jaxpr inside it (a
+    loop's body, a rule's, a checkpoint's)."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += eqn.primitive.name.startswith("ragged_dot")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            count += _grouped_products(sub)
+    return count
+
+
+def test_the_program_does_not_grow_with_the_slabs():
+    """The gradient of a layer under a checkpoint holds the same grouped
+    products whether the sorted order has 2 slabs or 4, and at most 15:
+    3 forward, 3 rematerialised, 3 computed again by the rule and 6
+    transposed.  One slab traced, however many run."""
+    from tpu_mx.parallel import moe
+    S, E, U, F, k = 512, 64, 16, 8, 4
+    counts = {}
+    for held in (8, 16):
+        shapes = [(S, U), (E, U), (held, U, F), (held, U, F), (held, F, U)]
+        args = [jnp.ones(s, jnp.float32) for s in shapes]
+
+        @jax.checkpoint
+        def layer(x, gw, w1, w3, w2):
+            return moe._dropless_forward(
+                x, x, gw, jnp.zeros(E), w1, w3, w2, top_k=k, lo=0,
+                scaling=1.0)[0]
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(layer(*a)), argnums=(0, 1, 2, 3, 4)))(*args)
+        slabs = S * k // moe.head_rows(S * k, held, E)
+        counts[slabs] = _grouped_products(jaxpr.jaxpr)
+    assert sorted(counts) == [2, 4]
+    assert counts[2] == counts[4] <= 15, counts
+
+
+def test_the_census_reports_the_head_and_the_history_gives_the_tails_share():
+    """`head_rows` beside the rows routed here: the share of the history's
+    steps whose rows exceeded it is 0 for a balanced layer and 1 for one
+    whose bias sends every token here."""
+    from tpu_mx.parallel import moe
+    x = nd.array(np.random.RandomState(4).randn(384, 32).astype(np.float32))
+    bias = np.zeros(8, np.float32)
+    for on_held, steps, share in ((0.0, 2, 0.0), (100.0, 3, 1.0)):
+        bias[2:4] = on_held
+        layer = _layer(held=range(2, 4), shared=False)
+        layer.select_bias.set_data(bias)
+        for _ in range(steps):
+            with autograd.record():
+                layer(x)
+        census, = load_census(layer)
+        assert census["head_rows"] == moe.head_rows(768, 2, 8) == 512
+        history = census["rows_routed_here_history"]
+        assert sum(h > 512 for h in history) / len(history) == share
+        assert mx.telemetry.gauge("moe.head_rows",
+                                  layer=layer.name).value == 512
+        assert mx.telemetry.gauge("moe.tail_steps",
+                                  layer=layer.name).value == share * steps
+    assert {"moe.head_rows", "moe.tail_steps"} <= mx.telemetry.KNOWN_METRICS
+
+
 def test_selection_bias_changes_the_choice_and_not_the_weights():
     layer = _layer(held=range(0, 8), shared=False)
     x = np.random.RandomState(2).randn(16, 32).astype(np.float32)

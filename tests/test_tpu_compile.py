@@ -11,6 +11,8 @@ The kernels pick interpret mode from `jax.default_backend()` at trace time;
 the fixture turns that off for the kernels under test, so what is lowered is
 what a TPU process lowers.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -121,3 +123,40 @@ def test_paged_decode_compiles_for_v5e(v5e, mosaic, tq):
                         ((b, tq, h, d), jnp.bfloat16), pool, pool,
                         ((b, nb), jnp.int32), ((b,), jnp.int32))
     assert _mosaic_calls(compiled) >= 1
+
+
+@pytest.mark.parametrize("cell", ["glm-4.7-flash.pretrain4k",
+                                  "smallthinker-21ba3b.extend16k"])
+def test_the_expert_layers_loop_over_slabs_compiles_for_v5e(v5e, cell):
+    """The dropless expert layer at the two decoder cells' shapes (bf16,
+    under a checkpoint, every gradient): XLA:TPU takes the grouped products
+    inside a loop with a dynamic trip count, forward and in the layer's own
+    backward rule, and builds the slab once for the loop, not once a slab."""
+    from tpu_mx.parallel import moe
+    (S, k, H, U, F), kw = {
+        "glm-4.7-flash.pretrain4k": (
+            (8192, 4, 8, 2048, 1536), dict(scoring="sigmoid")),
+        "smallthinker-21ba3b.extend16k": (
+            (16384, 6, 16, 2560, 768),
+            dict(scoring="softmax", activation="relu"))}[cell]
+    E, bf16 = 64, jnp.bfloat16
+
+    @jax.checkpoint
+    def layer(x, gw, w1, w3, w2):
+        return moe._dropless_forward(x, x, gw, jnp.zeros(E), w1, w3, w2,
+                                     top_k=k, lo=0, scaling=1.8, **kw)[0]
+
+    def loss(*a):
+        return layer(*a).astype(jnp.float32).sum()
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), v5e, ((S, U), bf16),
+        ((E, U), bf16), ((H, U, F), bf16), ((H, U, F), bf16),
+        ((H, F, U), bf16))
+    text = compiled.as_text()
+    assert S * k // moe.head_rows(S * k, H, E) in (2, 4)
+    # the loop is there, and XLA:TPU's kernel stands in it once a product
+    # of ONE slab: the rule's 3 computed again and 6 transposed (nothing
+    # reads the forward pass of a program that returns gradients alone)
+    assert " while(" in text and " conditional(" not in text
+    assert len(re.findall(r"^\s*%?ragged-dot-none[.\d]* = ", text,
+                          re.M)) == 9

@@ -32,12 +32,18 @@ design debt, ROADMAP): it is TOLD which experts it holds
 (`held_experts=range(lo, hi)` of `num_experts`), routes over all of them,
 drops nothing, and runs grouped matrix products (`jax.lax.ragged_dot`) over
 its own: one chip's part of an expert-parallel layer, without the exchange.
-Shapes stay static: the (token, choice) rows are sorted by held expert into
-a buffer of S·k rows, the worst case, and the grouped products are handed
-the real group sizes, so their cost follows the rows that are real.  Each
-layer counts the rows every expert was chosen for, step by step, into a
-non-trainable buffer of the last LOAD_HISTORY training steps, written
-through the path BatchNorm's running statistics take, and
+Shapes stay static: the S·k (token, choice) pairs are sorted by held expert
+and the sorted order is walked in slabs of C rows, twice the mean that the
+held experts draw (head_rows()), for as many slabs as hold rows routed here:
+one traced slab under a loop whose trip count is read from the router's
+choice, forward and, by the layer's own rule, backward.  The grouped
+products are handed the real group sizes, so their cost follows the rows
+that are real, and everything around them follows the slabs that ran: C
+while a router stays within twice the mean, one more C for each C of rows
+past it; nothing is dropped at any load.  Each layer counts the rows every
+expert was chosen for, step by step, into a non-trainable buffer of the
+last LOAD_HISTORY training steps, written through the path BatchNorm's
+running statistics take, and
 `load_census(net)` reads it back when asked.
 """
 from __future__ import annotations
@@ -54,7 +60,7 @@ from ..gluon.block import HybridBlock
 from ..ndarray import ops
 
 __all__ = ["MoEFFN", "DroplessMoE", "moe_sharding_rules", "dropless_route",
-           "load_census", "MOE_SCOPES", "LOAD_HISTORY"]
+           "load_census", "head_rows", "MOE_SCOPES", "LOAD_HISTORY"]
 
 # training steps of expert load a DroplessMoE keeps: a router that learns
 # moves its load from step to step, and a trace is of some steps ago
@@ -203,39 +209,17 @@ class MoEFFN(HybridBlock):
 
 
 # -- the dropless layer ---------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_of(x, order, inv, here, k):
-    """x[order // k]: row r of the sorted buffer is the token of the r-th
-    (token, choice) pair in expert order.  `order` is a permutation of the
-    S·k pairs and `inv` its inverse, so the transpose is a gather too (JAX
-    would scatter-add); rows of pairs that are not `here` carry nothing
-    back."""
-    return x[order // k]
+# rows of one tile of XLA:TPU's grouped product: a slab is a whole number of
+# them
+_ROW_TILE = 512
 
 
-def _rows_of_fwd(x, order, inv, here, k):
-    return x[order // k], (inv, here)
-
-
-def _rows_of_bwd(k, res, g):
-    inv, here = res
-    back = jnp.where(here[..., None], g[inv].reshape(here.shape + g.shape[1:]),
-                     0)
-    return jnp.sum(back, axis=1), None, None, None
-
-
-_rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
-
-
-@jax.custom_vjp
-def _unsort(ys, order, inv):
-    """ys[inv]: the sorted buffer back in (token, choice) order; transpose
-    by the inverse permutation, a gather again."""
-    return ys[inv]
-
-
-_unsort.defvjp(lambda ys, order, inv: (ys[inv], (order,)),
-               lambda res, g: (g[res[0]], None, None))
+def head_rows(rows, held, experts):
+    """C, the rows of one slab of the sorted order: twice the mean that
+    `held` of `experts` experts draw from `rows` (token, choice) pairs, in
+    whole tiles, and never more than `rows`."""
+    twice = -(-2 * rows * held // experts)
+    return min(rows, -(-twice // _ROW_TILE) * _ROW_TILE)
 
 
 def dropless_route(x, gate_weight, select_bias, top_k, scaling=1.0,
@@ -271,44 +255,127 @@ def dropless_route(x, gate_weight, select_bias, top_k, scaling=1.0,
 _GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
+def _over_slabs(order, sizes, rows, body, carry):
+    """body(j, carry) for the slabs j of `rows` rows of the sorted order
+    that hold rows routed here, one at least: a loop with a dynamic trip
+    count, and no loop where the order is one slab."""
+    if order.shape[0] == rows:
+        return body(0, carry)
+    n = jnp.maximum(1, -(-jnp.sum(sizes) // rows))
+    return jax.lax.while_loop(
+        lambda c: c[0] < n, lambda c: (c[0] + 1, body(c[0], c[1])),
+        (jnp.int32(0), carry))[1]
+
+
+def _slab_rows(j, x, k, order, sizes, rows):
+    """Slab j of the sorted order of (token, choice) pairs, k a token: (its
+    pairs, their tokens, the held experts' group sizes clipped to its edges
+    (a group may straddle one), which of its rows a group owns (they come
+    first), x's rows for them)."""
+    with jax.named_scope(_ROUTE):
+        start = j * rows
+        pairs = jax.lax.dynamic_slice(order, (start,), (rows,))
+        ends = jnp.clip(jnp.cumsum(sizes) - start, 0, rows)
+        tok = pairs // k
+        real = (jnp.arange(rows) < ends[-1])[:, None]
+        return pairs, tok, jnp.diff(ends, prepend=0), real, x[tok]
+
+
+def _slab_experts(xs, w1, w3, w2, sizes, real, activation):
+    """A slab's rows through their experts, (rows, U)."""
+    with jax.named_scope(_EXPERTS):
+        def grouped(a, w):
+            # XLA:TPU's kernel leaves the rows that no group owns UNWRITTEN,
+            # whatever the buffer held before (seen on the chip, PR 27: NaN
+            # by the third step of a toy decoder); they are cleared before
+            # anything reads them, forward and, by where()'s transpose,
+            # backward.  The select fuses into the gated activation that
+            # follows.
+            return jnp.where(real, jax.lax.ragged_dot(a, w, sizes), 0)
+        h = _GATES[activation](grouped(xs, w1)) * grouped(xs, w3)
+        return grouped(h, w2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(rows, activation, x, weights, w1, w3, w2, order, sizes):
+    """What the held experts add to every token's result, (S, U): each slab's
+    rows times their pairs' weights, added into their tokens in f32.  The
+    backward rule walks the same slabs again; it keeps the operands and
+    nothing of a slab's inside.  `order` is a whole number of slabs."""
+    def slab(j, y):
+        pairs, tok, slab_sizes, real, xs = _slab_rows(
+            j, x, weights.shape[1], order, sizes, rows)
+        ys = _slab_experts(xs, w1, w3, w2, slab_sizes, real, activation)
+        with jax.named_scope(_COMBINE):
+            scale = weights.reshape(-1)[pairs]
+            return y.at[tok].add(scale[:, None] * ys.astype(jnp.float32))
+    return _over_slabs(order, sizes, rows, slab,
+                       jnp.zeros(x.shape, jnp.float32)).astype(x.dtype)
+
+
+def _held_experts_fwd(rows, activation, *operands):
+    return _held_experts(rows, activation, *operands), operands
+
+
+def _held_experts_bwd(rows, activation, operands, g):
+    x, weights, w1, w3, w2, order, sizes = operands
+
+    def slab(j, grads):
+        dx, dweights, dws = grads
+        pairs, tok, slab_sizes, real, xs = _slab_rows(
+            j, x, weights.shape[1], order, sizes, rows)
+        ys, back = jax.vjp(
+            lambda *a: _slab_experts(*a, slab_sizes, real, activation),
+            xs, w1, w3, w2)
+        with jax.named_scope(_COMBINE):
+            gs = g[tok].astype(jnp.float32)
+            # a row no group owns holds zeros, and adds them
+            dweights = dweights.at[pairs].add(
+                jnp.sum(gs * ys.astype(jnp.float32), axis=-1))
+            gys = (weights.reshape(-1)[pairs][:, None] * gs).astype(ys.dtype)
+        gxs, *gws = back(gys)
+        with jax.named_scope(_ROUTE):
+            # the grouped products' transposes leave the rows that no group
+            # owns unwritten too
+            dx = dx.at[tok].add(jnp.where(real, gxs, 0).astype(jnp.float32))
+        return dx, dweights, [d + g for d, g in zip(dws, gws)]
+    # x's and the pair weights' gradients add up in f32; the expert weights'
+    # in their own type, as a sum of per-slab gradients would: f32 sums of
+    # them cost glm-4.7-flash.pretrain4k 0.69 GiB of the step's temporaries
+    # (PERF.md section 6, PR 33)
+    dx, dweights, dws = _over_slabs(order, sizes, rows, slab, (
+        jnp.zeros(x.shape, jnp.float32), jnp.zeros(weights.size, jnp.float32),
+        [jnp.zeros_like(w) for w in (w1, w3, w2)]))
+    return (dx.astype(x.dtype), dweights.reshape(weights.shape), *dws,
+            None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
 def _dropless_forward(x, router_x, gw, bias, w1, w3, w2, *, top_k, lo,
                       scaling, scoring="sigmoid", activation="silu"):
     """Routing over all E experts (on `router_x`, the rows the router reads:
     x itself unless the model feeds its router from elsewhere) and the held
     experts' part of the result, on flattened tokens (S, U).  Returns
     (y (S, U), expert_load (E,) f32)."""
-    S, U = x.shape
-    E, H, k = gw.shape[0], w1.shape[0], top_k
+    S, k = x.shape[0], top_k
+    E, H = gw.shape[0], w1.shape[0]
     with jax.named_scope(_ROUTE):
         chosen, weights = dropless_route(router_x, gw, bias, k, scaling,
                                          scoring)
         flat = chosen.reshape(-1)
         load = jnp.sum(flat[:, None] == jnp.arange(E)[None, :], axis=0)
         here = (chosen >= lo) & (chosen < lo + H)             # (S, k)
+        # the pairs routed here first, in expert order, the others after
         key = jnp.where(here, chosen - lo, H).reshape(-1)
         order = jnp.argsort(key, stable=True)
-        inv = jnp.argsort(order)
         sizes = load[lo:lo + H].astype(jnp.int32)
-        xs = _rows_of(x, order, inv, here, k)                 # (S·k, U)
-    with jax.named_scope(_EXPERTS):
-        # the rows the buffer really holds come first
-        real = (jnp.arange(S * k) < jnp.sum(sizes))[:, None]
-
-        def grouped(a, w):
-            # XLA:TPU's kernel leaves the rows that no group owns UNWRITTEN,
-            # whatever the buffer held before (seen on the chip, PR 27: NaN
-            # by the third step of a toy decoder); they are cleared before
-            # anything reads them, forward and, by where()'s transpose,
-            # backward.  The select fuses into the SwiGLU that follows.
-            return jnp.where(real, jax.lax.ragged_dot(a, w, sizes), 0)
-        h = _GATES[activation](grouped(xs, w1)) * grouped(xs, w3)
-        ys = grouped(h, w2)                                   # (S·k, U)
-    with jax.named_scope(_COMBINE):
-        y_tok = _unsort(ys, order, inv).reshape(S, k, U)
-        # where(), not a zero weight: select is safe whatever a row holds
-        y = jnp.sum(jnp.where(here[..., None], weights[..., None]
-                              * y_tok.astype(jnp.float32), 0.0), axis=1)
-    return y.astype(x.dtype), load.astype(jnp.float32)
+        # in whole slabs: the padding's pairs come after all that a group owns
+        rows = head_rows(S * k, H, E)
+        order = jnp.pad(order, (0, -(S * k) % rows))
+    y = _held_experts(rows, activation, x, weights, w1, w3, w2, order, sizes)
+    return y, load.astype(jnp.float32)
 
 
 class DroplessMoE(HybridBlock):
@@ -427,8 +494,12 @@ def load_census(net):
     "rows_routed_here_history": the last LOAD_HISTORY steps' at most, the
     oldest first}] in the order the blocks were added.  A net that a
     CompiledTrainStep trains holds the step's values after
-    `step.sync_to_net()`.  Also sets the gauges moe.rows_routed_here and
-    moe.max_expert_load, a layer each."""
+    `step.sync_to_net()`.  "head_rows" is the layer's C for the (token,
+    choice) pairs of the step last run (head_rows(); 0 before any step): a
+    step of the history whose rows exceeded it ran more than one slab, one
+    for each C its rows reached.  Also sets the gauges moe.rows_routed_here,
+    moe.max_expert_load, moe.head_rows and moe.tail_steps (such steps in
+    the history), a layer each."""
     layers = []
     net.apply_fn(lambda b: isinstance(b, DroplessMoE) and layers.append(b))
     out = []
@@ -444,11 +515,18 @@ def load_census(net):
         entry = {"layer": layer.name, "held": (held.start, held.stop),
                  "expert_load": load, "rows_routed_here": sum(mine),
                  "max_expert_load": max(mine),
+                 "head_rows": head_rows(int(sum(load)), len(held),
+                                        len(load)),
                  "rows_routed_here_history":
                      history[:, held.start:held.stop].sum(axis=1).tolist()}
         _telemetry.gauge("moe.rows_routed_here", layer=layer.name).set(
             entry["rows_routed_here"])
         _telemetry.gauge("moe.max_expert_load", layer=layer.name).set(
             entry["max_expert_load"])
+        _telemetry.gauge("moe.head_rows", layer=layer.name).set(
+            entry["head_rows"])
+        _telemetry.gauge("moe.tail_steps", layer=layer.name).set(
+            sum(rows > entry["head_rows"]
+                for rows in entry["rows_routed_here_history"]))
         out.append(entry)
     return out

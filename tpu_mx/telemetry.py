@@ -142,8 +142,11 @@ KNOWN_METRICS = frozenset({
     # dropless expert layer (tpu_mx/parallel/moe.py DroplessMoE; gauges,
     # a layer each, set by load_census(net) from the counter the last
     # training step wrote on the device): rows routed to the experts held
-    # here, and the fullest held expert's rows
+    # here, and the fullest held expert's rows; the rows of one slab of the
+    # sorted order (C: the first always runs) and the steps of the kept
+    # history whose rows exceeded them, so that the loop ran more than once
     "moe.rows_routed_here", "moe.max_expert_load",
+    "moe.head_rows", "moe.tail_steps",
     # attention dispatch (tpu_mx/parallel/ring_attention.py; label `kind`):
     # for every flash call with a window, as it is traced, the (q block, k
     # block) pairs of one head's grid (`grid`) and those that run (`run`)
