@@ -13,9 +13,11 @@ serving's device arm a short leg:
         layers, 512 wide — the only serving model there is), its tokens
         compared with the XLA twin of the paged kernel on the same device.
   D     a toy causal decoder (models/decoder.py CausalLM: latent attention
-        with heads of 64, its last layer grouped-query attention, 4 query
-        heads over 2 key/value heads, with a window of 128; dropless
-        experts, 2 of 8 held, multi-token head; seq 512),
+        with heads of 64; then two head-gated grouped-query layers over 2
+        key/value heads: 4 query heads with a window of 128, narrower than
+        the kernel's key block, and 6 that see the whole past and turn half
+        of each head by YaRN's frequencies; dropless experts, 2 of 8 held,
+        multi-token head; seq 512),
         TPUMX_ATTENTION=flash, 10 AdamW steps: the flash kernel's causal
         path, its window arm and its dk/dv group sum, and XLA:TPU's grouped
         product outside the benchmark.  Every loss finite, the loss
@@ -342,13 +344,22 @@ def phase_d(tiny, platform, compiles):
           f"TPUMX_ATTENTION=flash, expecting {want}")
     latent = dict(num_heads=2, q_rank=64, kv_rank=64, nope_dim=48,
                   rope_dim=16, v_dim=64, rope_theta=1e6)
-    # the third layer's queries see a quarter of the sequence
+    # the third layer's queries see a quarter of the sequence, a window
+    # narrower than the kernel's key block (the whole sequence here); it and
+    # the fourth, which sees all of the past and turns half of each head by
+    # YaRN's frequencies, gate every head's output (ISSUE 34)
     windowed = dict(kind="grouped_query", num_heads=4, num_kv_heads=2,
-                    head_dim=64, rope_theta=1e6, window=seq_len // 4)
+                    head_dim=64, rope_theta=1e6, window=seq_len // 4,
+                    gate=True)
+    half_turned = dict(kind="grouped_query", num_heads=6, num_kv_heads=2,
+                       head_dim=64, rope_theta=5e5, rotary_dim=32,
+                       yarn=dict(factor=8, original_length=seq_len // 4,
+                                 attention_factor=1.2), gate=True)
     cfg = dict(
-        vocab_size=1024, units=256, num_layers=3, num_dense_layers=1,
+        vocab_size=1024, units=256, num_layers=4, num_dense_layers=1,
         dense_hidden=512, epsilon=1e-5,
-        attention=[latent, latent, windowed, latent],  # the last: the module's
+        # the last: the multi-token module's
+        attention=[latent, latent, windowed, half_turned, latent],
         moe=dict(hidden_size=128, num_experts=8, top_k=2,
                  held_experts=(0, 2), scaling=1.8, shared_hidden=128),
         mtp_depth=1, mtp_weight=0.3)
@@ -377,6 +388,15 @@ def phase_d(tiny, platform, compiles):
     check(any(path == want and detail in said
               for path, said in _seen_signatures),
           f"D: the grouped window layer ({detail}) dispatched to {want}")
+    check(any(path == want and f"shape=(2, 6, {seq_len}, 64)" in said
+              and "kv_heads=2" in said and "window" not in said
+              for path, said in _seen_signatures),
+          f"D: the half-turned full layer (6 heads over 2) dispatched to "
+          f"{want}")
+    gates = [l.attention.gate_weight for l in net.decoder_layers()
+             if hasattr(l.attention, "gate_weight")]
+    check([g.shape[0] for g in gates] == [4, 6],
+          "D: two layers gate their heads, 4 and 6 of them")
     grid, run = (window_blocks[k] - blocks[k] for k in ("grid", "run"))
     # at this length the default blocks make a grid of one: counted, and
     # nothing to skip (the benchmark's 16k cell is where blocks are skipped)
@@ -385,7 +405,7 @@ def phase_d(tiny, platform, compiles):
           f"{grid} run")
     step.sync_to_net()
     census = load_census(net)
-    check(len(census) == 3 and all(
+    check(len(census) == 4 and all(
         c["rows_routed_here"] > 0
         and sum(c["expert_load"]) == 2 * seq_len * 2 for c in census),
         "D: every expert layer counted a choice for every token, rows "

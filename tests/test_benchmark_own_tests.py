@@ -44,6 +44,7 @@ TestDecoderCell = type("TestDecoderCell", (), _cases(_load("test_decoder_cell"))
 TestWindowedCell = type("TestWindowedCell", (),
                         _cases(_load("test_windowed_cell")))
 TestExpertHead = type("TestExpertHead", (), _cases(_load("test_expert_head")))
+TestGatedCell = type("TestGatedCell", (), _cases(_load("test_gated_cell")))
 grown = _benchmark.grown        # test_benchmark.py's one fixture
 
 

@@ -30,6 +30,12 @@ def test_tiny_flag_runs_all_phases_on_cpu(tmp_path):
     # phase D's toy decoder holds a grouped-query layer with a window
     assert any("D: the grouped window layer (kv_heads=2 window=16) "
                "dispatched to xla_dense" in ln for ln in lines)
+    # and, since ISSUE 34, a full layer that turns half of each head, both
+    # with a gate on every head
+    assert any("D: the half-turned full layer (6 heads over 2) dispatched "
+               "to xla_dense" in ln for ln in lines)
+    assert any("D: two layers gate their heads, 4 and 6 of them" in ln
+               for ln in lines)
     assert json.loads(lines[-1]) == {
         "ok": True, "tiny_cpu": True,
         "device": {"platform": "cpu", "kind": "cpu", "count": 4}}

@@ -160,3 +160,68 @@ def test_the_expert_layers_loop_over_slabs_compiles_for_v5e(v5e, cell):
     assert " while(" in text and " conditional(" not in text
     assert len(re.findall(r"^\s*%?ragged-dot-none[.\d]* = ", text,
                           re.M)) == 9
+
+
+@pytest.mark.parametrize("heads,window", [(48, None), (72, 512)])
+def test_narrow_window_flash_compiles_for_v5e(v5e, mosaic, heads, window):
+    """laguna-s-2.1.pretrain8k's attention (bf16, causal, T = 8,192, heads
+    of 128): 48 query heads over 8 on the full layers (groups of 6), 72
+    over 8 with a window of 512, half a key block, on the others (groups of
+    9), forward and backward."""
+    q, kv = ((1, heads, 8192, 128), jnp.bfloat16), \
+        ((1, 8, 8192, 128), jnp.bfloat16)
+    assert fa.supported(q[0], q[1], kv_heads=8, window=window)
+
+    def loss(q, k, v):
+        return fa.mha_flash_attention(q, k, v, causal=True,
+                                      window=window).astype(jnp.float32).sum()
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e, q, kv, kv)
+    assert _mosaic_calls(compiled) >= 3  # forward, dq, dk/dv
+
+
+def test_the_gated_mixed_decoders_step_compiles_for_v5e(v5e, mosaic,
+                                                        monkeypatch):
+    """The whole train step of a small head-gated mixed decoder (ISSUE 34:
+    a full layer of 12 gated heads over 2 that turns half of each head by
+    YaRN's frequencies, two layers of 18 with a window of 256, a quarter of
+    a key block; a dense layer, then sigmoid-routed experts with a shared
+    one; the head's loss in chunks; bf16, AdamW with f32 masters, one
+    checkpoint a layer) as a TPU process builds it: the dispatch takes the
+    flash kernels, and XLA:TPU and Mosaic take the step."""
+    import tpu_mx as mx
+    from tpu_mx import gluon, nd
+    from tpu_mx.models.decoder import CausalLM
+    from tpu_mx.parallel import CompiledTrainStep
+    gated = lambda heads, **kw: dict(
+        kind="grouped_query", num_heads=heads, num_kv_heads=2, head_dim=64,
+        gate=True, **kw)
+    full = gated(12, rope_theta=5e5, rotary_dim=32, yarn=dict(
+        factor=128, original_length=1024, attention_factor=1.485))
+    window = gated(18, rope_theta=1e4, window=256)
+    net = CausalLM(dict(
+        vocab_size=1024, units=256, num_layers=3, num_dense_layers=1,
+        dense_hidden=512, attention=[full, window, window],
+        moe=dict(hidden_size=128, num_experts=16, top_k=3,
+                 held_experts=(0, 2), scaling=2.5, shared_hidden=128,
+                 scoring="sigmoid"), loss_chunk=256, logits_stride=16),
+        dtype="bfloat16", remat=True)
+    net.initialize(mx.init.Normal(0.02))
+    step = CompiledTrainStep(
+        net, gluon.loss.PassThrough(), mx.optimizer.create(
+            "adamw", learning_rate=3e-6, multi_precision=True))
+    tokens = nd.zeros((1, 1024), dtype="int32")._data
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step._build(2)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=SingleDeviceSharding(v5e)), tree)
+    compiled = step._jitted.lower(*on_chip((
+        step.values, step.masters, step.opt_states, step._efs, {},
+        jnp.float32(1), jnp.float32(3e-6), jax.random.PRNGKey(0),
+        tokens, tokens))).compile()
+    # a layer's forward kernel, the same again under its checkpoint, and
+    # its two backward kernels, jitted once a signature: the full layer's
+    # and the window layers'
+    assert _mosaic_calls(compiled) >= 8
+    assert compiled.memory_analysis().temp_size_in_bytes > 0

@@ -10,7 +10,10 @@ sections 2.1 and 2.2); tests/references/latent_moe_decoder.py is their plain
 float32 form, and tests/test_latent_moe_decoder.py holds the two together.
 The grouped-query layers, the router fed from before the attention and the
 chunked head (ISSUE 31) follow tests/references/windowed_gqa_decoder.py,
-held together by tests/test_windowed_gqa_decoder.py.
+held together by tests/test_windowed_gqa_decoder.py; the per-head gate,
+the rotary turn over a part of the head and YaRN's frequencies (ISSUE 34)
+follow tests/references/gated_mixed_decoder.py
+(tests/test_gated_mixed_decoder.py).
 Training form only: nothing is absorbed, there is no cache (serving is
 ROADMAP M4-M8).  No bias and no dropout anywhere.
 
@@ -21,6 +24,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..gluon import nn
 from ..gluon.block import HybridBlock
@@ -28,8 +32,9 @@ from ..ndarray import ops
 from ..parallel import DroplessMoE, attention as _attention
 from ..parallel.moe import MOE_SCOPES
 
-__all__ = ["rotary", "GatedMLP", "LatentAttention", "GroupedQueryAttention",
-           "DecoderLayer", "CausalLM", "DECODER_SCOPES", "ATTENTION_SCOPES"]
+__all__ = ["rotary", "yarn_inv_freq", "GatedMLP", "LatentAttention",
+           "GroupedQueryAttention", "DecoderLayer", "CausalLM",
+           "DECODER_SCOPES", "ATTENTION_SCOPES", "ATTENTION_GATE_SCOPES"]
 
 # jax.named_scope names inside these blocks (HLO metadata only), beside
 # train_step.STEP_SCOPES; benchmark/decoder_scopes.py holds them as literals
@@ -41,18 +46,46 @@ _MTP, _LM_HEAD = DECODER_SCOPES[-2:]
 # literals): its projections, and its attention by the layer's kind
 ATTENTION_SCOPES = ("attn.project", "attn.window", "attn.full")
 _GQ_PROJECT, _GQ_WINDOW, _GQ_FULL = ATTENTION_SCOPES
+# and its per-head gate's: projection, sigmoid and multiply
+# (benchmark/gate_scopes.py holds the literal)
+ATTENTION_GATE_SCOPES = ("attn.gate",)
+_GQ_GATE, = ATTENTION_GATE_SCOPES
 
 
-def rotary(x, theta, pairs="interleaved"):
+def yarn_inv_freq(theta, dim, factor, original_length, beta_fast=32.0,
+                  beta_slow=1.0):
+    """YaRN's inverse frequencies (Peng et al., arXiv:2309.00071, "NTK by
+    parts") for `dim` rotary dimensions, d/2 numbers in float64: pair i of
+    plain frequency f_i = theta^(-2i/dim) keeps it where it turns more than
+    beta_fast times over the original length, takes f_i / factor where it
+    turns less than beta_slow times, and a linear blend of the two by its
+    index between.  c(n) = dim · ln(original_length / (2 pi n)) / (2 ln
+    theta) is the index of the pair that turns n times."""
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    turns = lambda n: dim * np.log(original_length / (2 * np.pi * n)) \
+        / (2 * np.log(theta))
+    low = max(np.floor(turns(beta_fast)), 0)
+    high = min(np.ceil(turns(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return f / factor * ramp + f * (1 - ramp)
+
+
+def rotary(x, theta, pairs="interleaved", inv_freq=None, factor=1.0):
     """Rotary positions (Su et al., arXiv:2104.09864) over the whole last
-    axis of x (..., T, d): pair i turns by position · theta^(-2i/d).
+    axis of x (..., T, d): pair i turns by position · theta^(-2i/d), or by
+    position · inv_freq[i] where the d/2 frequencies are given (`theta` is
+    then not read); cos and sin are multiplied by `factor` (YaRN's
+    attention factor).
     `pairs` "interleaved": pair i is (x[2i], x[2i+1]); "halves": (x[i],
     x[i + d/2]), the `rotate_half` convention.  Angles and the turn in
     f32."""
     t, d = x.shape[-2], x.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
     if pairs == "halves":
         a, b = xf[..., :d // 2], xf[..., d // 2:]
@@ -167,15 +200,22 @@ class GroupedQueryAttention(HybridBlock):
     """Causal attention with `num_heads` query heads over `num_kv_heads`
     key/value heads of `head_dim` (query head h reads key/value head
     h // (num_heads / num_kv_heads)), no bias.  `rope_theta` None: no
-    positions at all; else q and k turn by rotary positions over the whole
-    head (`rotary_pairs` as `rotary` takes them).  `window` None: query i
+    positions at all; else q and k turn by rotary positions
+    (`rotary_pairs` as `rotary` takes them) over the first `rotary_dim`
+    dimensions of a head, the whole head by default, the rest passing
+    unturned; `yarn` (factor, original_length, beta_fast, beta_slow,
+    attention_factor) blends the frequencies as `yarn_inv_freq` does and
+    scales cos and sin by the attention factor.  `window` None: query i
     sees every key j <= i; else the last `window` of them, i - window < j
-    <= i.  Through `parallel.attention`, which hands k and v to the flash
-    kernel with the heads they have."""
+    <= i.  `gate`: each head's output is multiplied by a scalar of its own
+    a token, sigmoid(x W_g), before the output projection (the head-wise
+    form of Qiu et al., arXiv:2505.06708).  Through `parallel.attention`,
+    which hands k and v to the flash kernel with the heads they have."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
                  rope_theta=None, window=None, rotary_pairs="halves",
-                 mesh=None, **kwargs):
+                 rotary_dim=None, yarn=None, gate=False, mesh=None,
+                 **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError(f"{num_heads} query heads over {num_kv_heads} "
@@ -183,6 +223,15 @@ class GroupedQueryAttention(HybridBlock):
         self._h, self._hkv, self._d = num_heads, num_kv_heads, head_dim
         self._theta = None if rope_theta is None else float(rope_theta)
         self._window, self._pairs, self._mesh = window, rotary_pairs, mesh
+        self._rot = head_dim if rotary_dim is None else int(rotary_dim)
+        if not 0 < self._rot <= head_dim or self._rot % 2:
+            raise ValueError(f"rotary_dim {rotary_dim} of a head of "
+                             f"{head_dim}")
+        self._inv_freq, self._factor = None, 1.0
+        if yarn is not None:
+            yarn = dict(yarn)
+            self._factor = float(yarn.pop("attention_factor", 1.0))
+            self._inv_freq = yarn_inv_freq(self._theta, self._rot, **yarn)
         self.q_weight = self.params.get(
             "q_weight", shape=(num_heads * head_dim, units))
         self.k_weight = self.params.get(
@@ -191,13 +240,29 @@ class GroupedQueryAttention(HybridBlock):
             "v_weight", shape=(num_kv_heads * head_dim, units))
         self.o_weight = self.params.get(
             "o_weight", shape=(units, num_heads * head_dim))
+        if gate:
+            self.gate_weight = self.params.get(
+                "gate_weight", shape=(num_heads, units))
 
     def _heads(self, x, n, turn):
         b, t = x.shape[:2]
         x = x.reshape(b, t, n, self._d).transpose(0, 2, 1, 3)
-        return rotary(x, self._theta, self._pairs) if turn else x
+        if not turn:
+            return x
+        turned = lambda a: rotary(a, self._theta, self._pairs,
+                                  self._inv_freq, self._factor)
+        return turned(x) if self._rot == self._d else jnp.concatenate(
+            [turned(x[..., :self._rot]), x[..., self._rot:]], axis=-1)
 
-    def hybrid_forward(self, F, x, q_weight, k_weight, v_weight, o_weight):
+    def _gated(self, out, z):
+        """out (B, T, H·d) times sigmoid(z) (B, T, H), a head at a time;
+        the sigmoid in f32."""
+        g = jax.nn.sigmoid(z.astype(jnp.float32)).astype(out.dtype)
+        return (out.reshape(*z.shape, self._d) * g[..., None]).reshape(
+            out.shape)
+
+    def hybrid_forward(self, F, x, q_weight, k_weight, v_weight, o_weight,
+                       gate_weight=None):
         b, t = x.shape[:2]
         turn = self._theta is not None
         with jax.named_scope(_GQ_PROJECT):
@@ -218,6 +283,11 @@ class GroupedQueryAttention(HybridBlock):
             out = ops._apply(
                 lambda o: o.transpose(0, 2, 1, 3).reshape(
                     b, t, self._h * self._d), [out], "merge_heads")
+        if gate_weight is not None:
+            with jax.named_scope(_GQ_GATE):
+                out = ops._apply(self._gated,
+                                 [out, _linear(F, x, gate_weight)],
+                                 "head_gate")
         with jax.named_scope(_GQ_PROJECT):
             return _linear(F, out, o_weight)
 
