@@ -21,7 +21,10 @@ serving's device arm a short leg:
         TPUMX_ATTENTION=flash, 10 AdamW steps: the flash kernel's causal
         path, its window arm and its dk/dv group sum, and XLA:TPU's grouped
         product outside the benchmark.  Every loss finite, the loss
-        falling, no row dropped, the window's blocks counted.
+        falling, no row dropped, the window's blocks counted.  Then the
+        flash arm alone against the dense arm at the benchmark's two window
+        shapes (W 512 at T 8,192, W 4,096 at T 16,384): the band's index
+        maps on Mosaic (ISSUE 35).
 
     python chip_smoke.py              # needs a TPU; exits non-zero without
     python chip_smoke.py --tiny-cpu   # same control flow, toy sizes, CPU
@@ -412,6 +415,60 @@ def phase_d(tiny, platform, compiles):
         "routed here " + ", ".join(str(int(c["rows_routed_here"]))
                                    for c in census))
     forced_past_the_first_slab(net, cfg)
+    the_band_against_the_dense_arm(tiny, platform)
+
+
+def the_band_against_the_dense_arm(tiny, platform):
+    """The flash arm under a window alone, at the two benchmark cells' window
+    layers (one key/value head of each: 9 query heads over it at T 8,192
+    under W 512, 7 at T 16,384 under W 4,096; heads of 128, bf16): output
+    and the three gradients against the dense arm, which runs a query head
+    at a time in f32 at the highest precision (seven heads' scores at
+    16,384 would not fit).  Mosaic lowers the band's index maps only here;
+    the dispatch's count of the steps walked says the band engaged."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from tpu_mx.parallel.ring_attention import attention, window_blocks
+    for t, window, group, blocks in (
+            ((256, 64, 9, None), (512, 128, 7, None)) if tiny else
+            ((8192, 512, 9, (256, 31, 32)),
+             (16384, 4096, 7, (512, 140, 160)))):
+        keys = jax.random.split(jax.random.key(SEED), 4)
+        q, k, v, do = (jax.random.normal(key, (1, heads, t, 128), dtype)
+                       for key, heads, dtype in zip(
+                           keys, (group, 1, 1, group),
+                           (jnp.bfloat16,) * 3 + (jnp.float32,)))
+
+        def arm(q, k, v, do):
+            out, pull = jax.vjp(lambda *a: attention(
+                *a, causal=True, window=window).astype(jnp.float32), q, k, v)
+            return (out,) + pull(do)
+        before = dict(window_blocks)
+        with env(TPUMX_ATTENTION="flash"):
+            got = [np.asarray(a, np.float32)
+                   for a in jax.jit(arm)(q, k, v, do)]
+        counted = tuple(window_blocks[kind] - before[kind]
+                        for kind in ("grid", "run", "walked"))
+        with env(TPUMX_ATTENTION="dense"), \
+                jax.default_matmul_precision("highest"):
+            dense = jax.jit(arm)
+            heads = [[np.asarray(a) for a in dense(
+                q[:, h:h + 1].astype(jnp.float32), k.astype(jnp.float32),
+                v.astype(jnp.float32), do[:, h:h + 1])] for h in range(group)]
+        want = [np.concatenate([h[i] for h in heads], 1) for i in (0, 1)] \
+            + [sum(h[i] for h in heads) for i in (2, 3)]
+        errors = [float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
+                  for a, b in zip(got, want)]
+        # bf16 against f32: some 2e-3 on the output (the benchmark's
+        # attend_window reads 0.0022), a few times that on the gradients;
+        # a block of the band left out or run twice reads 1e-1 and more
+        check(max(errors) < 2e-2,
+              f"D: T {t} under W {window}, {group} query heads a key/value "
+              "head: the flash arm is the dense arm, relative error of out, "
+              "dq, dk, dv: " + " ".join(f"{e:.2e}" for e in errors))
+        check(platform != "tpu" or counted == blocks,
+              f"D: its grid is the band: (square, run, walked) {counted}")
 
 
 def forced_past_the_first_slab(net, cfg):

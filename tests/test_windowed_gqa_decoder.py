@@ -165,9 +165,12 @@ def test_the_flash_arm_takes_window_and_groups_and_counts_its_blocks(
         == before[0]["pallas_flash"] + 1
     assert dispatch.dispatch_counts["xla_dense"] == before[0]["xla_dense"]
     grid, run = fa.blocks_run(1024, 1024, True, 300)
-    assert (grid, run) == (2, 2)
+    # key blocks of 256, no wider than the window (ISSUE 35): 6 of the
+    # square's 2 x 4 run, and the band walks all 8 (4 key blocks a row)
+    assert (grid, run) == (8, 6)
     assert dispatch.window_blocks["grid"] == before[1]["grid"] + grid
     assert dispatch.window_blocks["run"] == before[1]["run"] + run
+    assert dispatch.window_blocks["walked"] == before[1]["walked"] + 8
     monkeypatch.undo()
     want = attention(q, k, v, causal=True, window=300)
     assert float(jnp.max(jnp.abs(out - want))) < 1e-4

@@ -27,16 +27,33 @@ probabilities (standard inverted dropout on the probs).  The TPU PRNG has
 no CPU/interpret lowering, so dropout>0 requires a real TPU; callers gate
 via `supported()`.
 
-Window and grouped heads (ISSUE 31).  With `causal` and `window=W`, query i
-sees keys i - W < j <= i: blocks that lie wholly before every query's window
-are skipped like those above the diagonal, edge blocks are masked, and the
-index maps clamp a skipped step to the nearest block that runs, so that its
-K/V (or, in the dk/dv kernel, q/do) tiles are not copied in again.  With k
-and v of BH / G rows (`G` query heads share a key/value head, row b reads
-row b // G), K and V are never repeated in HBM: the block maps send the
-query row to its key/value row, and the dk/dv kernel walks the G query
-heads of its row on its innermost grid axis and sums them in its scratch.
-Without a window and with G = 1 the three kernels are the ones they were.
+Window and grouped heads (ISSUE 31; the band, ISSUE 35).  With `causal` and
+`window=W`, query i sees keys i - W < j <= i, and the grid is the band the
+window allows and not the square: the innermost axis of the forward and dq
+grids has `nb` steps, the most key blocks any query block's windows touch
+(`_band_steps`), and step j of query block i is key block lo(i) + j, lo(i)
+the first block that holds a key the block's first query sees (`_k_band`);
+the dk/dv grid walks, for each of a key block's query heads, the `nbq` query
+blocks from the first that sees into it (`_q_band`).  At T 8,192 under W 512
+that is 32 steps a head and kernel of which 31 run, where the square of 512 x
+1,024 blocks walked 128 for 23.  The few steps of the band that still lie
+outside the mask (past the diagonal, or past the sequence's end) are skipped,
+and the index maps clamp them to the nearest block that runs, so that their
+K/V (or, in the dk/dv kernel, q/do) tiles are not copied in again; edge blocks
+are masked.  The blocks that run, their order and their arithmetic are the
+square grid's: at equal blocks not a bit of output or gradient differs.  The
+key block is no wider than the window (`_blocks`: the largest power of two <=
+min(1024, W) that divides Tk, at least 128; W 512 -> 512 x 512, W 4,096 ->
+512 x 1,024): what an edge block holds beyond the window is computed and
+masked away, and the arms read on the chip are in docs/performance.md.  A
+bias keeps the square under a window (its blocks, and its gradient's, are
+streamed by grid position).  With k and v of BH / G rows (`G` query heads
+share a key/value head, row b reads row b // G), K and V are never repeated
+in HBM: the block maps send the query row to its key/value row, and the
+dk/dv kernel walks the G query heads of its row on its innermost grid axis
+and sums them in its scratch.  Without a window the three kernels keep the
+grids (BH, T/Bq, Tk/Bk) and (BH_kv, Tk/Bk, G·T/Bq) and the index maps they
+had.
 
 Falls back to interpret mode off-TPU so tests run anywhere.
 """
@@ -47,11 +64,12 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "mha_flash_attention", "supported",
-           "blocks_run"]
+           "blocks_run", "steps_walked"]
 
 NEG_INF = -1e30
 # Largest (Bq × Bk) f32 score block we let the kernel materialize in VMEM:
@@ -127,18 +145,20 @@ def _run_cond(causal, valid, qi, ki, block_q, block_k, window=None):
 # forward
 # ----------------------------------------------------------------------------
 def _fwd_kernel(*refs, scale, causal, masked, rate, biased, block_q,
-                block_k, window=None):
+                block_k, window=None, band=None):
     (q_ref, k_ref, v_ref), bias_ref, valid_ref, seed_ref, tail = \
         _split_refs(refs, 3, masked, rate, biased)
     o_ref, lse_ref, m_scr, l_scr, acc_scr = tail
 
     b = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    nsteps = pl.num_programs(2)
+    ki = step if band is None else \
+        _k_band(qi, window, block_q, block_k, band)[0] + step
     valid = valid_ref[jax.lax.rem(b, _VALID_BLOCK)] if masked else None
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -176,12 +196,14 @@ def _fwd_kernel(*refs, scale, causal, masked, rate, biased, block_q,
         l_scr[:] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
 
     run = _run_cond(causal, valid, qi, ki, block_q, block_k, window)
+    if band is not None:
+        run = run & (ki < band)
     if run is True:
         _compute()
     else:
         pl.when(run)(_compute)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nsteps - 1)
     def _finalize():
         l = l_scr[:, 0]
         l_safe = jnp.maximum(l, 1e-30)
@@ -262,60 +284,112 @@ def _extra_specs_and_args(kv_valid, seed):
     return specs, args
 
 
-def _kv_index(group, window, bq, bk, nk):
-    """Index map (b, qblk, kblk) -> K/V block, for the forward and dq grids:
-    query row b reads key/value row b // group; with a window a step that
-    is skipped stays on the nearest block that runs, and a block that does
-    not change is not copied in again."""
+def _k_band(qi, window, bq, bk, nk, xp=jnp):
+    """(first, last) of the key blocks that hold a key some query of block
+    qi sees under the window; first > last where there is none (T > Tk)."""
+    return (xp.maximum(qi * bq - window + 1, 0) // bk,
+            xp.minimum((qi * bq + bq - 1) // bk, nk - 1))
+
+
+def _q_band(ki, window, bq, bk, nq, xp=jnp):
+    """(first, last) of the query blocks that hold a query which sees a key
+    of block ki under the window; first > last where there is none."""
+    return ((ki * bk) // bq,
+            xp.minimum((ki * bk + bk + window - 2) // bq, nq - 1))
+
+
+def _banded(window, biased):
+    """Whether a call's innermost grid axis walks the window's band and not
+    the whole square: a bias keeps the square, because its blocks, and its
+    gradient's, are streamed by grid position and every one has to be
+    written."""
+    return window is not None and not biased
+
+
+def _band_steps(t, tk, window, bq, bk):
+    """(nb, nbq): the most key blocks a query block's windows touch, and the
+    most query blocks that see into one key block: the innermost extents of
+    the forward and dq grids and, a query head, of the dk/dv grid."""
+    nq, nk = _cdiv(t, bq), _cdiv(tk, bk)
+    lo, hi = _k_band(np.arange(nq), window, bq, bk, nk, np)
+    qlo, qhi = _q_band(np.arange(nk), window, bq, bk, nq, np)
+    return (max(int(np.max(hi - lo)) + 1, 1),
+            max(int(np.max(qhi - qlo)) + 1, 1))
+
+
+def _kv_index(group, window, bq, bk, nk, banded=False):
+    """Index map (b, qblk, step) -> K/V block, for the forward and dq grids:
+    query row b reads key/value row b // group.  With a window, step is the
+    key block itself on the square grid and counts from the band's first
+    block on the banded one; either way a step that is skipped stays on the
+    nearest block that runs, and a block that does not change is not copied
+    in again."""
     if group == 1 and window is None:
         return lambda b, i, j: (b, j, 0)
 
     def index(b, i, j):
         if window is not None:
-            lo = jnp.maximum(i * bq - window + 1, 0) // bk
-            hi = jnp.minimum((i * bq + bq - 1) // bk, nk - 1)
-            j = jnp.clip(j, lo, hi)
+            lo, hi = _k_band(i, window, bq, bk, nk)
+            j = jnp.clip(lo + j if banded else j, lo, hi)
         return (b // group, j, 0)
     return index
 
 
-def _q_index(group, window, bq, bk, nq):
+def _q_index(group, window, bq, bk, nq, nbq=None):
     """Index map (b, kblk, step) -> block of q, do, lse or delta, for the
     dk/dv grid: key/value row b is read by the query rows b·group …
-    b·group + group - 1, whose q blocks the innermost axis walks in turn;
-    the window's clamp as in _kv_index."""
+    b·group + group - 1, of each of which the innermost axis walks the q
+    blocks in turn: all nq of them on the square grid, on the banded one
+    nbq, from the band's first; the window's clamp as in _kv_index."""
     if group == 1 and window is None:
         return lambda b, j, i: (b, i, 0)
+    per_head = nbq or nq
 
     def index(b, j, step):
         i = step
         if group > 1:
-            b, i = b * group + step // nq, jax.lax.rem(step, nq)
+            b, i = b * group + step // per_head, jax.lax.rem(step, per_head)
         if window is not None:
-            lo = (j * bk) // bq
-            hi = jnp.minimum((j * bk + bk + window - 2) // bq, nq - 1)
-            i = jnp.clip(i, lo, hi)
+            lo, hi = _q_band(j, window, bq, bk, nq)
+            i = jnp.clip(lo + i if nbq else i, lo, hi)
         return (b, i, 0)
     return index
 
 
-def _blocks(t, tk, block_q=None, block_k=None):
+def _blocks(t, tk, block_q=None, block_k=None, window=None):
     """The (q, k) block sizes a call runs in: the caller's, or the tuned
-    defaults, never beyond the sequence."""
+    defaults, never beyond the sequence.  Under a window the key block is no
+    wider than the window (down to 128): what an edge block holds beyond
+    the window is computed and masked away (docs/performance.md has the
+    arms read on the chip)."""
+    prefer_k = 1024
+    if window is not None:
+        prefer_k = min(1024, max(128, 1 << (int(window).bit_length() - 1)))
     return (min(block_q or _pick_block(t, 512), t),
-            min(block_k or _pick_block(tk, 1024), tk))
+            min(block_k or _pick_block(tk, prefer_k), tk))
 
 
 def blocks_run(t, tk, causal=True, window=None, block_q=None, block_k=None):
-    """(blocks in the (q block, k block) grid of one head, blocks of it that
-    run), by the kernels' own _run_cond on the whole grid at once; at the
-    block sizes flash_attention() would take."""
-    import numpy as np
-    bq, bk = _blocks(t, tk, block_q, block_k)
+    """(blocks in the (q block, k block) square of one head, blocks of it
+    that run), by the kernels' own _run_cond on the whole square at once; at
+    the block sizes flash_attention() would take."""
+    bq, bk = _blocks(t, tk, block_q, block_k, window)
     qi, ki = np.meshgrid(np.arange(_cdiv(t, bq)), np.arange(_cdiv(tk, bk)),
                          indexing="ij")
     run = _run_cond(causal, None, qi, ki, bq, bk, window)
     return qi.size, qi.size if run is True else int(np.sum(run))
+
+
+def steps_walked(t, tk, window=None, block_q=None, block_k=None,
+                 biased=False):
+    """The steps the forward grid walks a head, at the block sizes
+    flash_attention() would take: under a window the band, T/bq rows of the
+    most key blocks a row's windows touch; without one, or with a bias
+    (whose blocks are streamed by grid position), the whole square."""
+    bq, bk = _blocks(t, tk, block_q, block_k, window)
+    nb = _band_steps(t, tk, window, bq, bk)[0] \
+        if _banded(window, biased) else _cdiv(tk, bk)
+    return _cdiv(t, bq) * nb
 
 
 # The kernel calls are jitted with `interpret` among the static arguments: a
@@ -331,14 +405,18 @@ def _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate, block_q,
     tk = k.shape[1]
     block_q = min(block_q, t)
     block_k = min(block_k, tk)
-    grid = (bh, _cdiv(t, block_q), _cdiv(tk, block_k))
+    nq, nk = _cdiv(t, block_q), _cdiv(tk, block_k)
     masked = kv_valid is not None
     biased = bias is not None
+    banded = _banded(window, biased)
+    grid = (bh, nq, _band_steps(t, tk, window, block_q, block_k)[0]
+            if banded else nk)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                masked=masked, rate=rate, biased=biased,
                                block_q=block_q, block_k=block_k,
-                               window=window)
-    kv_index = _kv_index(bh // k.shape[0], window, block_q, block_k, grid[2])
+                               window=window, band=nk if banded else None)
+    kv_index = _kv_index(bh // k.shape[0], window, block_q, block_k, nk,
+                         banded)
     bias_specs, bias_args = ([], [])
     if biased:
         bias_specs = [_bias_spec(bias, bh, block_q, block_k)]
@@ -382,7 +460,7 @@ def _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate, block_q,
 # backward: dq kernel (grid k-innermost, accumulate dq over k blocks)
 # ----------------------------------------------------------------------------
 def _bwd_dq_kernel(*refs, scale, causal, masked, rate, biased, block_q,
-                   block_k, window=None):
+                   block_k, window=None, band=None):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, \
         valid_ref, seed_ref, tail = _split_refs(refs, 6, masked, rate,
                                                 biased)
@@ -394,11 +472,13 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, rate, biased, block_q,
 
     b = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    nsteps = pl.num_programs(2)
+    ki = step if band is None else \
+        _k_band(qi, window, block_q, block_k, band)[0] + step
     valid = valid_ref[jax.lax.rem(b, _VALID_BLOCK)] if masked else None
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -438,12 +518,14 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, rate, biased, block_q,
             preferred_element_type=jnp.float32)
 
     run = _run_cond(causal, valid, qi, ki, block_q, block_k, window)
+    if band is not None:
+        run = run & (ki < band)
     if run is True:
         _compute()
     else:
         pl.when(run)(_compute)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nsteps - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -452,7 +534,7 @@ def _bwd_dq_kernel(*refs, scale, causal, masked, rate, biased, block_q,
 # backward: dk/dv kernel (grid q-innermost, accumulate dk,dv over q blocks)
 # ----------------------------------------------------------------------------
 def _bwd_dkv_kernel(*refs, scale, causal, masked, rate, biased, block_q,
-                    block_k, window=None, group=1):
+                    block_k, window=None, group=1, band=None):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, \
         valid_ref, seed_ref, tail = _split_refs(refs, 6, masked, rate,
                                                 biased)
@@ -465,6 +547,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, rate, biased, block_q,
     step = pl.program_id(2)
     nsteps = pl.num_programs(2)
     qi = step if group == 1 else jax.lax.rem(step, nsteps // group)
+    if band is not None:
+        qi = _q_band(ki, window, block_q, block_k, band)[0] + qi
     valid = valid_ref[jax.lax.rem(b, _VALID_BLOCK)] if masked else None
 
     @pl.when(step == 0)
@@ -507,6 +591,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, masked, rate, biased, block_q,
             preferred_element_type=jnp.float32)
 
     run = _run_cond(causal, valid, qi, ki, block_q, block_k, window)
+    if band is not None:
+        run = run & (qi < band)
     if run is True:
         _compute()
     else:
@@ -530,6 +616,9 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do,
     nq, nk = _cdiv(t, bq), _cdiv(tk, bk)
     masked = kv_valid is not None
     biased = bias is not None
+    # the band: dq walks its key blocks as _fwd does, dk/dv its query blocks
+    banded = _banded(window, biased)
+    nb, nbq = _band_steps(t, tk, window, bq, bk) if banded else (nk, None)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[..., None]                        # (BH, T, 1)
     extra_specs, extra_args = _extra_specs_and_args(
@@ -539,7 +628,8 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do,
 
     qspec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((1, bk, d), _kv_index(group, window, bq, bk, nk),
+    kspec = pl.BlockSpec((1, bk, d),
+                         _kv_index(group, window, bq, bk, nk, banded),
                          memory_space=pltpu.VMEM)
     rowq = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0),
                         memory_space=pltpu.VMEM)
@@ -557,8 +647,9 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do,
     dq_out = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           masked=masked, rate=rate, biased=biased,
-                          block_q=bq, block_k=bk, window=window),
-        grid=(bh, nq, nk),
+                          block_q=bq, block_k=bk, window=window,
+                          band=nk if banded else None),
+        grid=(bh, nq, nb),
         in_specs=[qspec, kspec, kspec, qspec, rowq, rowq] + bias_specs
         + extra_specs,
         out_specs=out_specs,
@@ -583,7 +674,7 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do,
     # dk/dv: swap grid so q is innermost; index maps take (b, kblk, qblk),
     # b the key/value row, whose `group` query heads the innermost axis
     # walks one after the other: dk and dv come out summed over them
-    q_index = _q_index(group, window, bq, bk, nq)
+    q_index = _q_index(group, window, bq, bk, nq, nbq)
     qspec2 = pl.BlockSpec((1, bq, d), q_index, memory_space=pltpu.VMEM)
     kspec2 = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0),
                           memory_space=pltpu.VMEM)
@@ -592,8 +683,9 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           masked=masked, rate=rate, biased=biased,
-                          block_q=bq, block_k=bk, window=window, group=group),
-        grid=(bhk, nk, group * nq),
+                          block_q=bq, block_k=bk, window=window, group=group,
+                          band=nq if banded else None),
+        grid=(bhk, nk, group * (nbq or nq)),
         # the SMEM scalar index maps only use the leading batch axis, so the
         # same specs serve both backward grids
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2]
@@ -644,7 +736,8 @@ def flash_attention(q, k, v, scale=None, causal=False, kv_valid=None,
     blocks — partial K blocks would feed padded garbage into the softmax.
 
     window: with `causal`, query i sees only the keys i - window < j <= i
-    (itself among them); blocks wholly before every window are skipped.
+    (itself among them); the grid walks the band of blocks the window
+    allows, at a default key block no wider than the window.
     Grouped heads: k/v may have BH / G rows, query row b then reads
     key/value row b // G, and dk, dv come out summed over the G query rows
     (no padding mask, dropout or bias with G > 1).
@@ -678,13 +771,11 @@ def flash_attention(q, k, v, scale=None, causal=False, kv_valid=None,
         raise ValueError(
             "flash_attention: grouped heads take no padding mask, dropout "
             "or bias; gate callers with supported(..., kv_heads=...)")
-    block_q = block_q or _pick_block(t, 512)
-    block_k = block_k or _pick_block(tk, 1024)
-    bq, bk = _blocks(t, tk, block_q, block_k)
+    bq, bk = _blocks(t, tk, block_q, block_k, window)
     if t % bq or tk % bk:
         raise ValueError(
             f"flash_attention: seq lens (q={t}, kv={tk}) must be divisible "
-            f"by the block sizes ({block_q}, {block_k}); gate callers with "
+            f"by the block sizes ({bq}, {bk}); gate callers with "
             "kernels.flash_attention.supported()")
     if bq * bk > MAX_BLOCK_ELEMS:
         raise ValueError(
@@ -719,7 +810,7 @@ def flash_attention(q, k, v, scale=None, causal=False, kv_valid=None,
                 f"bias_groups and dividing BH={bh} — a bare divisor is "
                 "ambiguous between per-head and per-batch")
     return _flash_core(q, k, v, kv_valid, dropout_seed, bias, scale,
-                       causal, float(dropout_rate), block_q, block_k,
+                       causal, float(dropout_rate), bq, bk,
                        None if window is None else int(window))
 
 

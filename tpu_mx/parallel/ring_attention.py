@@ -50,11 +50,13 @@ _logger = logging.getLogger(__name__)
 # weak#6).  Mirrored into profiler counters.
 dispatch_counts = {"ring": 0, "ulysses": 0, "pallas_flash": 0,
                    "xla_dense": 0}
-# For every flash call with a window, as it is traced: the (q block, k block)
-# pairs of one head's grid and those of them that run (the rest lie above
-# the diagonal or wholly before every query's window and are skipped).
-# Mirrored into the telemetry counter attention.window_blocks{kind=grid|run}.
-window_blocks = {"grid": 0, "run": 0}
+# For every flash call with a window, as it is traced, a head: the (q block,
+# k block) pairs of the square at the blocks the call takes ("grid"), those
+# of them that run ("run": the rest lie above the diagonal or wholly before
+# every query's window), and the steps the forward kernel's grid walks
+# ("walked": the band the window allows, so all but a few of them run).
+# Mirrored into the telemetry counter attention.window_blocks{kind=...}.
+window_blocks = {"grid": 0, "run": 0, "walked": 0}
 
 
 def _auto_prefers_flash(q_len, kv_len, dropped, on_tpu):
@@ -403,7 +405,9 @@ def local_flash_attention(q, k, v, causal=False, valid_length=None,
         _count("pallas_flash", f"shape={q.shape}{new}")
         if window is not None:
             grid, run = fa.blocks_run(q.shape[2], k.shape[2], causal, window)
-            for kind, n in (("grid", grid), ("run", run)):
+            walked = fa.steps_walked(q.shape[2], k.shape[2], window,
+                                     biased=bias is not None)
+            for kind, n in (("grid", grid), ("run", run), ("walked", walked)):
                 window_blocks[kind] += n
                 _telemetry.counter("attention.window_blocks",
                                    kind=kind).inc(n)

@@ -149,7 +149,8 @@ KNOWN_METRICS = frozenset({
     "moe.head_rows", "moe.tail_steps",
     # attention dispatch (tpu_mx/parallel/ring_attention.py; label `kind`):
     # for every flash call with a window, as it is traced, the (q block, k
-    # block) pairs of one head's grid (`grid`) and those that run (`run`)
+    # block) pairs of one head's square (`grid`), those that run (`run`) and
+    # the steps the forward kernel's grid walks (`walked`: the window's band)
     "attention.window_blocks",
     # kvstore eager path (tpu_mx/kvstore.py).  checksums counts payload
     # digests recorded at push time, checksum_failures the pulls whose
