@@ -24,7 +24,11 @@ serving's device arm a short leg:
         falling, no row dropped, the window's blocks counted.  Then the
         flash arm alone against the dense arm at the benchmark's two window
         shapes (W 512 at T 8,192, W 4,096 at T 16,384): the band's index
-        maps on Mosaic (ISSUE 35).
+        maps on Mosaic (ISSUE 35).  Last, two steps of the same decoder
+        under the profiler, from a program compiled past the persistent
+        cache: the three kernels stand in the device's op paths under the
+        names the program gives them (`flash.fwd`, forward and recomputed,
+        `flash.dq`, `flash.dkv`; ISSUE 36), each missing one named.
 
     python chip_smoke.py              # needs a TPU; exits non-zero without
     python chip_smoke.py --tiny-cpu   # same control flow, toy sizes, CPU
@@ -34,13 +38,18 @@ last line {"ok": true, "device": {...}} only when everything passed.  The
 times it prints are facts for CHANGES.md, not benchmark metrics.
 """
 import argparse
+import contextlib
+import functools
+import glob
 import importlib.metadata
 import json
 import logging
 import math
 import os
+import shutil
 import statistics
 import sys
+import tempfile
 import time
 from unittest import mock
 
@@ -79,6 +88,24 @@ class Compiles:
     def _on(self, event, duration, **kwargs):
         if event.endswith("backend_compile_duration"):
             self.n += 1
+
+
+@contextlib.contextmanager
+def past_the_compile_cache():
+    """Programs built inside are compiled, never loaded.  The persistent
+    cache keys a program on its text without debug information, so one that
+    differs from a cached program only in its names is served that one's
+    executable, op paths and all."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    try:
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
 
 
 def cache_entries(directory):
@@ -371,11 +398,12 @@ def phase_d(tiny, platform, compiles):
     net.initialize(mx.init.Normal(0.02))
     tokens = nd.array(np.random.RandomState(SEED).randint(
         0, cfg["vocab_size"], (2, seq_len)), dtype="int32")
-    opt = mx.optimizer.create("adamw", learning_rate=3e-4, beta2=0.95,
-                              wd=0.1, multi_precision=True)
+    adamw = functools.partial(mx.optimizer.create, "adamw",
+                              learning_rate=3e-4, beta2=0.95, wd=0.1,
+                              multi_precision=True)
     before, blocks = dict(dispatch_counts), dict(window_blocks)
     with env(TPUMX_ATTENTION="flash"):
-        step = CompiledTrainStep(net, gluon.loss.PassThrough(), opt)
+        step = CompiledTrainStep(net, gluon.loss.PassThrough(), adamw())
         losses = [float(step.step(tokens, tokens).asscalar())
                   for _ in range(steps)]
     print("  losses: " + " ".join(f"{l:.4f}" for l in losses))
@@ -416,6 +444,7 @@ def phase_d(tiny, platform, compiles):
                                    for c in census))
     forced_past_the_first_slab(net, cfg)
     the_band_against_the_dense_arm(tiny, platform)
+    the_kernels_names_on_a_trace(net, tokens, adamw(), platform)
 
 
 def the_band_against_the_dense_arm(tiny, platform):
@@ -469,6 +498,60 @@ def the_band_against_the_dense_arm(tiny, platform):
               "dq, dk, dv: " + " ".join(f"{e:.2e}" for e in errors))
         check(platform != "tpu" or counted == blocks,
               f"D: its grid is the band: (square, run, walked) {counted}")
+
+
+def the_kernels_names_on_a_trace(net, tokens, opt, platform):
+    """Two steps of phase D's decoder under the profiler: the device's
+    operations carry the flash kernels' own names in their op paths, the
+    forward kernel's in the forward pass and again under the checkpoint,
+    and the dense layer's.  The guard against a jax or Mosaic that drops a
+    scope round a `pallas_call`; off the chip there is no kernel to find,
+    and only the control flow runs."""
+    import jax
+    from tpu_mx import gluon
+    from tpu_mx.kernels.flash_attention import FLASH_SCOPES
+    from tpu_mx.parallel import CompiledTrainStep
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), "benchmark"))
+    import xplane       # the yardstick's own view of a trace
+    fwd, dq, dkv = FLASH_SCOPES
+    trace_dir = tempfile.mkdtemp(prefix="chip_smoke_trace_")
+    try:
+        with env(TPUMX_ATTENTION="flash"), past_the_compile_cache():
+            step = CompiledTrainStep(net, gluon.loss.PassThrough(), opt)
+            step.step(tokens, tokens).asscalar()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        for _ in range(2):
+            step.step(tokens, tokens).asscalar()
+        jax.profiler.stop_trace()
+        trace = xplane.load(glob.glob(os.path.join(
+            trace_dir, "plugins", "profile", "*", "*.xplane.pb"))[0])
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    dev = xplane.first_device(trace)
+    paths = [dev["meta"].get(n, {}).get("tf_op", "")
+             for n, _, _ in xplane.stretch(dev)[1]] if dev else []
+    kernels = [p for p in paths if "pallas_call" in p]
+    print(f"  traced {len(paths)} device operations, {len(kernels)} of them "
+          "kernels")
+    again = "rematted_computation"
+    for what, found in (
+            (f"{fwd} in the forward pass",
+             [p for p in kernels if f"/{fwd}/" in p and again not in p]),
+            (f"{fwd} under {again}",
+             [p for p in kernels if f"/{fwd}/" in p and again in p]),
+            (dq, [p for p in kernels if f"/{dq}/" in p]),
+            (dkv, [p for p in kernels if f"/{dkv}/" in p]),
+            ("mlp.dense", [p for p in paths if "mlp.dense" in p])):
+        check(platform != "tpu" or found,
+              f"D: the trace's op paths name {what}"
+              + (f", {len(found)} operations, e.g. {found[0]}"
+                 if found else ""))
+    check(platform != "tpu" or not [
+        p for p in kernels if not any(f"/{s}/" in p for s in FLASH_SCOPES)],
+        "D: no kernel of the traced steps is without a name")
 
 
 def forced_past_the_first_slab(net, cfg):

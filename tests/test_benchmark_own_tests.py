@@ -45,6 +45,8 @@ TestWindowedCell = type("TestWindowedCell", (),
                         _cases(_load("test_windowed_cell")))
 TestExpertHead = type("TestExpertHead", (), _cases(_load("test_expert_head")))
 TestGatedCell = type("TestGatedCell", (), _cases(_load("test_gated_cell")))
+TestNamedPasses = type("TestNamedPasses", (),
+                       _cases(_load("test_named_passes")))
 grown = _benchmark.grown        # test_benchmark.py's one fixture
 
 

@@ -225,3 +225,20 @@ def test_the_gated_mixed_decoders_step_compiles_for_v5e(v5e, mosaic,
     # and the window layers'
     assert _mosaic_calls(compiled) >= 8
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+    # ISSUE 36: XLA:TPU keeps the scope round a Mosaic call: every kernel
+    # of the step stands under its own name, the forward one again under
+    # the checkpoint (chip_smoke.py phase D reads the same from a trace)
+    paths = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # (XLA:TPU's own grouped product is such a call too, without a path)
+    paths = [p for p in paths if "pallas_call" in p]
+    assert len(paths) >= 8 and all(p.endswith("/pallas_call")
+                                   and p.split("/")[-2].startswith("flash.")
+                                   for p in paths)
+    for scope in ("attn.full", "attn.window"):
+        for inside in (f"({scope})/jit(_fwd)/flash.fwd",
+                       f"rematted_computation/{scope}/jit(_fwd)/flash.fwd",
+                       f"/{scope}/jit(_bwd_call)/flash.dq",
+                       f"/{scope}/jit(_bwd_call)/flash.dkv"):
+            assert [p for p in paths if inside + "/pallas_call" in p], inside

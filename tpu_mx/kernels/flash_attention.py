@@ -69,7 +69,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "mha_flash_attention", "supported",
-           "blocks_run", "steps_walked"]
+           "blocks_run", "steps_walked", "FLASH_SCOPES"]
+
+# jax.named_scope names around the three kernel calls (HLO metadata only):
+# an operation's op path ends `.../jit(_bwd_call)/flash.dq/pallas_call`,
+# whoever calls; benchmark/pass_scopes.py holds them as literals
+FLASH_SCOPES = ("flash.fwd", "flash.dq", "flash.dkv")
+_FWD_SCOPE, _DQ_SCOPE, _DKV_SCOPE = FLASH_SCOPES
 
 NEG_INF = -1e30
 # Largest (Bq × Bk) f32 score block we let the kernel materialize in VMEM:
@@ -425,7 +431,7 @@ def _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate, block_q,
         kv_valid, seed if rate > 0.0 else None)
     extra_specs = bias_specs + extra_specs
     extra_args = bias_args + extra_args
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -452,7 +458,9 @@ def _fwd(q, k, v, kv_valid, seed, bias, scale, causal, rate, block_q,
             pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
         ],
         interpret=interpret,
-    )(q, k, v, *extra_args)
+    )
+    with jax.named_scope(_FWD_SCOPE):
+        out, lse = call(q, k, v, *extra_args)
     return out, lse
 
 
@@ -644,7 +652,7 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do,
         out_specs = [qspec, dbspec]
         out_shape = [out_shape,
                      jax.ShapeDtypeStruct((bh, t, tk), jnp.float32)]
-    dq_out = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           masked=masked, rate=rate, biased=biased,
                           block_q=bq, block_k=bk, window=window,
@@ -656,7 +664,9 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, do, lse, delta, *bias_args, *extra_args)
+    )
+    with jax.named_scope(_DQ_SCOPE):
+        dq_out = dq_call(q, k, v, do, lse, delta, *bias_args, *extra_args)
     if biased:
         dq, db_full = dq_out
         bhb = bias.shape[0]
@@ -680,7 +690,7 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do,
                           memory_space=pltpu.VMEM)
     rowq2 = pl.BlockSpec((1, bq, 1), q_index, memory_space=pltpu.VMEM)
     bias_specs2 = [_bias_spec(bias, bh, bq, bk, swap=True)] if biased else []
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           masked=masked, rate=rate, biased=biased,
                           block_q=bq, block_k=bk, window=window, group=group,
@@ -696,7 +706,9 @@ def _bwd_call(scale, causal, rate, block_q, block_k, interpret, res, do,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, do, lse, delta, *bias_args, *extra_args)
+    )
+    with jax.named_scope(_DKV_SCOPE):
+        dk, dv = dkv_call(q, k, v, do, lse, delta, *bias_args, *extra_args)
     return dq, dk, dv, None, None, db
 
 

@@ -34,7 +34,8 @@ from ..parallel.moe import MOE_SCOPES
 
 __all__ = ["rotary", "yarn_inv_freq", "GatedMLP", "LatentAttention",
            "GroupedQueryAttention", "DecoderLayer", "CausalLM",
-           "DECODER_SCOPES", "ATTENTION_SCOPES", "ATTENTION_GATE_SCOPES"]
+           "DECODER_SCOPES", "ATTENTION_SCOPES", "ATTENTION_GATE_SCOPES",
+           "OWNER_SCOPES"]
 
 # jax.named_scope names inside these blocks (HLO metadata only), beside
 # train_step.STEP_SCOPES; benchmark/decoder_scopes.py holds them as literals
@@ -50,6 +51,14 @@ _GQ_PROJECT, _GQ_WINDOW, _GQ_FULL = ATTENTION_SCOPES
 # (benchmark/gate_scopes.py holds the literal)
 ATTENTION_GATE_SCOPES = ("attn.gate",)
 _GQ_GATE, = ATTENTION_GATE_SCOPES
+# every name a block here gives a part of the step: the three tuples above
+# and GatedMLP's own (a dense layer; as DroplessMoE's shared expert it lies
+# under `moe.shared`, and a reader takes the outer name).  The flash kernels'
+# names are kernels/flash_attention.py's FLASH_SCOPES;
+# benchmark/pass_scopes.py holds both as literals
+_MLP_DENSE = "mlp.dense"
+OWNER_SCOPES = DECODER_SCOPES + ATTENTION_SCOPES + ATTENTION_GATE_SCOPES \
+    + (_MLP_DENSE,)
 
 
 def yarn_inv_freq(theta, dim, factor, original_length, beta_fast=32.0,
@@ -116,10 +125,11 @@ class GatedMLP(HybridBlock):
 
     def hybrid_forward(self, F, x, gate_proj_weight, up_proj_weight,
                        down_proj_weight):
-        h = ops._apply(lambda g, u: jax.nn.silu(g) * u,
-                       [_linear(F, x, gate_proj_weight),
-                        _linear(F, x, up_proj_weight)], "swiglu")
-        return _linear(F, h, down_proj_weight)
+        with jax.named_scope(_MLP_DENSE):
+            h = ops._apply(lambda g, u: jax.nn.silu(g) * u,
+                           [_linear(F, x, gate_proj_weight),
+                            _linear(F, x, up_proj_weight)], "swiglu")
+            return _linear(F, h, down_proj_weight)
 
 
 class LatentAttention(HybridBlock):
