@@ -24,11 +24,14 @@ serving's device arm a short leg:
         falling, no row dropped, the window's blocks counted.  Then the
         flash arm alone against the dense arm at the benchmark's two window
         shapes (W 512 at T 8,192, W 4,096 at T 16,384): the band's index
-        maps on Mosaic (ISSUE 35).  Last, two steps of the same decoder
-        under the profiler, from a program compiled past the persistent
-        cache: the three kernels stand in the device's op paths under the
-        names the program gives them (`flash.fwd`, forward and recomputed,
-        `flash.dq`, `flash.dkv`; ISSUE 36), each missing one named.
+        maps on Mosaic (ISSUE 35), and at heads of 128 under a window, of
+        256 and of 64: the forward's lane-replicated row statistics and
+        their repeat over the key block and the head (ISSUE 38).  Last, two
+        steps of the same decoder under the profiler, from a program
+        compiled past the persistent cache: the three kernels stand in the
+        device's op paths under the names the program gives them
+        (`flash.fwd`, forward and recomputed, `flash.dq`, `flash.dkv`;
+        ISSUE 36), each missing one named.
 
     python chip_smoke.py              # needs a TPU; exits non-zero without
     python chip_smoke.py --tiny-cpu   # same control flow, toy sizes, CPU
@@ -444,51 +447,58 @@ def phase_d(tiny, platform, compiles):
                                    for c in census))
     forced_past_the_first_slab(net, cfg)
     the_band_against_the_dense_arm(tiny, platform)
+    the_rows_layout_against_the_dense_arm(tiny)
     the_kernels_names_on_a_trace(net, tokens, adamw(), platform)
+
+
+def flash_against_the_dense_arm(t, window, group, d=128):
+    """One key/value head of `d` under `group` query heads at length t, bf16,
+    causal: relative error of the flash arm's output and three gradients
+    against the dense arm, which runs a query head at a time in f32 at the
+    highest precision (seven heads' scores at 16,384 would not fit), and
+    what the dispatch counted of the window's blocks (grid, run, walked)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from tpu_mx.parallel.ring_attention import attention, window_blocks
+    keys = jax.random.split(jax.random.key(SEED), 4)
+    q, k, v, do = (jax.random.normal(key, (1, heads, t, d), dtype)
+                   for key, heads, dtype in zip(
+                       keys, (group, 1, 1, group),
+                       (jnp.bfloat16,) * 3 + (jnp.float32,)))
+
+    def arm(q, k, v, do):
+        out, pull = jax.vjp(lambda *a: attention(
+            *a, causal=True, window=window).astype(jnp.float32), q, k, v)
+        return (out,) + pull(do)
+    before = dict(window_blocks)
+    with env(TPUMX_ATTENTION="flash"):
+        got = [np.asarray(a, np.float32) for a in jax.jit(arm)(q, k, v, do)]
+    counted = tuple(window_blocks[kind] - before[kind]
+                    for kind in ("grid", "run", "walked"))
+    with env(TPUMX_ATTENTION="dense"), \
+            jax.default_matmul_precision("highest"):
+        dense = jax.jit(arm)
+        heads = [[np.asarray(a) for a in dense(
+            q[:, h:h + 1].astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), do[:, h:h + 1])] for h in range(group)]
+    want = [np.concatenate([h[i] for h in heads], 1) for i in (0, 1)] \
+        + [sum(h[i] for h in heads) for i in (2, 3)]
+    return [float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
+            for a, b in zip(got, want)], counted
 
 
 def the_band_against_the_dense_arm(tiny, platform):
     """The flash arm under a window alone, at the two benchmark cells' window
     layers (one key/value head of each: 9 query heads over it at T 8,192
-    under W 512, 7 at T 16,384 under W 4,096; heads of 128, bf16): output
-    and the three gradients against the dense arm, which runs a query head
-    at a time in f32 at the highest precision (seven heads' scores at
-    16,384 would not fit).  Mosaic lowers the band's index maps only here;
-    the dispatch's count of the steps walked says the band engaged."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from tpu_mx.parallel.ring_attention import attention, window_blocks
+    under W 512, 7 at T 16,384 under W 4,096; heads of 128, bf16).  Mosaic
+    lowers the band's index maps only here; the dispatch's count of the
+    steps walked says the band engaged."""
     for t, window, group, blocks in (
             ((256, 64, 9, None), (512, 128, 7, None)) if tiny else
             ((8192, 512, 9, (256, 31, 32)),
              (16384, 4096, 7, (512, 140, 160)))):
-        keys = jax.random.split(jax.random.key(SEED), 4)
-        q, k, v, do = (jax.random.normal(key, (1, heads, t, 128), dtype)
-                       for key, heads, dtype in zip(
-                           keys, (group, 1, 1, group),
-                           (jnp.bfloat16,) * 3 + (jnp.float32,)))
-
-        def arm(q, k, v, do):
-            out, pull = jax.vjp(lambda *a: attention(
-                *a, causal=True, window=window).astype(jnp.float32), q, k, v)
-            return (out,) + pull(do)
-        before = dict(window_blocks)
-        with env(TPUMX_ATTENTION="flash"):
-            got = [np.asarray(a, np.float32)
-                   for a in jax.jit(arm)(q, k, v, do)]
-        counted = tuple(window_blocks[kind] - before[kind]
-                        for kind in ("grid", "run", "walked"))
-        with env(TPUMX_ATTENTION="dense"), \
-                jax.default_matmul_precision("highest"):
-            dense = jax.jit(arm)
-            heads = [[np.asarray(a) for a in dense(
-                q[:, h:h + 1].astype(jnp.float32), k.astype(jnp.float32),
-                v.astype(jnp.float32), do[:, h:h + 1])] for h in range(group)]
-        want = [np.concatenate([h[i] for h in heads], 1) for i in (0, 1)] \
-            + [sum(h[i] for h in heads) for i in (2, 3)]
-        errors = [float(np.sqrt(np.mean((a - b) ** 2) / np.mean(b ** 2)))
-                  for a, b in zip(got, want)]
+        errors, counted = flash_against_the_dense_arm(t, window, group)
         # bf16 against f32: some 2e-3 on the output (the benchmark's
         # attend_window reads 0.0022), a few times that on the gradients;
         # a block of the band left out or run twice reads 1e-1 and more
@@ -498,6 +508,26 @@ def the_band_against_the_dense_arm(tiny, platform):
               "dq, dk, dv: " + " ".join(f"{e:.2e}" for e in errors))
         check(platform != "tpu" or counted == blocks,
               f"D: its grid is the band: (square, run, walked) {counted}")
+
+
+def the_rows_layout_against_the_dense_arm(tiny):
+    """The forward kernel keeps a row's maximum and sum as (rows, 128)
+    tiles with every lane alike and repeats whole registers over the key
+    block and the head (ISSUE 38; `kernels/flash_attention.py` `_lanes`):
+    a Mosaic that lowers the repeat, or the leading lanes of a head of 64,
+    otherwise fails here by name.  Heads of 128 under a window (key blocks
+    of 512: four repeats), of 256 (key blocks of 1,024: eight, and two over
+    the accumulator) and of 64 (the leading half of the lanes)."""
+    for t, window, group, d in (
+            ((256, 128, 2, 128), (256, None, 1, 256), (256, None, 1, 64))
+            if tiny else
+            ((2048, 512, 2, 128), (2048, None, 1, 256), (2048, None, 1, 64))):
+        errors, _ = flash_against_the_dense_arm(t, window, group, d)
+        check(max(errors) < 2e-2,
+              f"D: the rows' layout, heads of {d} at T {t}"
+              + (f" under W {window}" if window else "")
+              + ": the flash arm is the dense arm, relative error of out, "
+              "dq, dk, dv: " + " ".join(f"{e:.2e}" for e in errors))
 
 
 def the_kernels_names_on_a_trace(net, tokens, opt, platform):
