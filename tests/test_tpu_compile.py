@@ -207,6 +207,24 @@ def test_the_short_conv_cells_attention_and_filter_compile_for_v5e(v5e,
     assert _mosaic_calls(compiled) == 0
 
 
+@pytest.mark.parametrize("b,t,u,v", [(8, 4096, 2048, 8192),
+                                     (1, 8192, 3072, 12544)])
+def test_the_chunked_heads_log_softmax_has_no_reduce_window(v5e, b, t, u, v):
+    """The head's loss in chunks of 2,048 positions, value and gradients
+    (bf16, as lfm2-24b-a2b.pretrain4k-b8 and laguna-s-2.1.pretrain8k run
+    it): with a chunk's (batch, position) pairs as one axis of rows,
+    XLA:TPU takes the max over the vocabulary as a reduction, never as a
+    reduce-window whose every element compares V of them."""
+    from tpu_mx.models.decoder import _chunked_next_token_loss
+
+    def loss(h, w, tokens):
+        return _chunked_next_token_loss(h, w, tokens, 1, 2048, 16)[1]
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1)), v5e,
+                        ((b, t, u), jnp.bfloat16), ((v, u), jnp.bfloat16),
+                        ((b, t), jnp.int32))
+    assert "reduce-window" not in compiled.as_text()
+
+
 def test_the_gated_mixed_decoders_step_compiles_for_v5e(v5e, mosaic,
                                                         monkeypatch):
     """The whole train step of a small head-gated mixed decoder (ISSUE 34:

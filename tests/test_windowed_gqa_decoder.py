@@ -350,20 +350,24 @@ def test_the_four_shares_expert_parts_add_up_to_the_uncut_layers():
 
 
 # -- the head in chunks --------------------------------------------------------------------
-def test_the_chunked_heads_loss_and_gradients_are_the_whole_heads():
+@pytest.mark.parametrize("batch,shift", [(2, 1), (3, 2)])
+def test_the_chunked_heads_loss_and_gradients_are_the_whole_heads(batch,
+                                                                   shift):
+    """At batch 3 and a shift of 2 the mask of the last positions reaches
+    a chunk's rows of every sequence."""
     keys = jax.random.split(jax.random.key(5), 3)
-    hidden = jax.random.normal(keys[0], (2, 64, 16))
+    hidden = jax.random.normal(keys[0], (batch, 64, 16))
     head = jax.random.normal(keys[1], (40, 16))
-    tokens = jax.random.randint(keys[2], (2, 64), 0, 40)
+    tokens = jax.random.randint(keys[2], (batch, 64), 0, 40)
 
     def whole(h, w):
-        return decoder._next_token_loss(h, w, tokens, 1)
+        return decoder._next_token_loss(h, w, tokens, shift)
 
     def chunked(h, w):
-        return decoder._chunked_next_token_loss(h, w, tokens, 1, 16, 4)
+        return decoder._chunked_next_token_loss(h, w, tokens, shift, 16, 4)
     (logits, loss), (strided, chunk_loss) = whole(hidden, head), \
         chunked(hidden, head)
-    assert strided.shape == (2, 16, 40)
+    assert strided.shape == (batch, 16, 40)
     assert np.allclose(strided, logits[:, ::4], atol=1e-6)
     assert float(abs(loss - chunk_loss)) < 1e-6
     grads = jax.grad(lambda h, w: whole(h, w)[1], (0, 1))(hidden, head)

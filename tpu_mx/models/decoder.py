@@ -438,7 +438,11 @@ def _chunked_next_token_loss(hidden, head, tokens, shift, chunk, stride):
     body is under `jax.checkpoint`, so that forward and backward each hold
     one chunk's logits at a time; the logits returned are those of every
     `stride`-th position only, from a product of their own (a step that
-    does not read them does not compute them)."""
+    does not read them does not compute them).  A chunk's positions of
+    every sequence are one axis of rows before the product: with a batch
+    above 1, XLA:TPU lowers the log-softmax's max over a (B, chunk, V)
+    block into a reduce-window as wide as twice the vocabulary (16,383 at
+    V 8,192), quadratic in V."""
     b, t = tokens.shape
     if t % chunk:
         raise ValueError(f"loss_chunk {chunk} does not divide T {t}")
@@ -447,7 +451,10 @@ def _chunked_next_token_loss(hidden, head, tokens, shift, chunk, stride):
 
     @jax.checkpoint
     def one(h, l, ok, w):
-        return jnp.sum(jnp.where(ok, _nll(_logits(h, w), l), 0.0))
+        logits = jnp.einsum("nu,vu->nv", h.reshape(-1, h.shape[-1]), w,
+                            preferred_element_type=jnp.float32)
+        ok = jnp.broadcast_to(ok, l.shape).reshape(-1)
+        return jnp.sum(jnp.where(ok, _nll(logits, l.reshape(-1)), 0.0))
     split = lambda a: jnp.moveaxis(
         a.reshape(b, t // chunk, chunk, *a.shape[2:]), 1, 0)
     sums = jax.lax.map(lambda a: one(*a, head),
