@@ -186,7 +186,13 @@ def test_persistent_compilation_cache(tmp_path):
         entries = list(d.rglob("*")) if d.exists() else []
         assert entries, "no cache entries written"
     finally:
+        # jax latched the cache at the first compile: without a reset the
+        # process goes on reading and writing it, and a later test in the
+        # same worker gets executables whose op names are another
+        # program's (tests/test_named_passes.py)
+        from jax.experimental.compilation_cache import compilation_cache
         jax.config.update("jax_compilation_cache_dir", None)
+        compilation_cache.reset_cache()
 
 
 def test_mcc_and_nll_metrics():

@@ -48,6 +48,7 @@ TestGatedCell = type("TestGatedCell", (), _cases(_load("test_gated_cell")))
 TestNamedPasses = type("TestNamedPasses", (),
                        _cases(_load("test_named_passes")))
 TestConvCell = type("TestConvCell", (), _cases(_load("test_conv_cell")))
+TestKdaCell = type("TestKdaCell", (), _cases(_load("test_kda_cell")))
 grown = _benchmark.grown        # test_benchmark.py's one fixture
 
 

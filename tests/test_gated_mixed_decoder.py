@@ -439,11 +439,22 @@ def test_the_shares_parts_add_up_to_the_uncut_references_layer():
     Summed over the four shares, with what every chip computes alike
     counted once, that is the uncut reference's layer, whose experts are
     all held."""
-    units, hidden, experts, top_k, t = 32, 16, 16, 5, 24
+    the_shares_add_up(experts=16, top_k=5, scaling=2.5, per_chip=4)
+
+
+def test_the_shares_parts_add_up_at_kimis_routing():
+    """The same at Kimi-Linear's routing: sigmoid scores, the
+    top 8 renormalised over the chosen and times 2.446, a shared expert;
+    four chips of 8 experts of 32."""
+    the_shares_add_up(experts=32, top_k=8, scaling=2.446, per_chip=8)
+
+
+def the_shares_add_up(experts, top_k, scaling, per_chip):
+    units, hidden, t = 32, 16, 24
     hp = dict(heads=(6,), kv_heads=2, head_dim=8, sliding=(True,), window=7,
               rope={"sliding": dict(theta=1e4, rotary_dim=8, yarn=None),
                     "full": dict(theta=5e5, rotary_dim=4, yarn=None)},
-              eps=1e-6, top_k=top_k, scaling=2.5, n_experts=experts)
+              eps=1e-6, top_k=top_k, scaling=scaling, n_experts=experts)
     keys = iter(jax.random.split(jax.random.key(11), 20))
     draw = lambda *shape: 0.3 * jax.random.normal(next(keys), shape)
     x = jax.random.normal(next(keys), (1, t, units))
@@ -465,7 +476,8 @@ def test_the_shares_parts_add_up_to_the_uncut_references_layer():
             units, GroupedQueryAttention(units, 6, 2, 8, rope_theta=1e4,
                                          window=7, gate=True),
             decoder.DroplessMoE(units, hidden, experts, top_k,
-                                held_experts=range(lo, hi), scaling=2.5,
+                                held_experts=range(lo, hi),
+                                scaling=scaling,
                                 shared=decoder.GatedMLP(units, hidden),
                                 scoring="sigmoid"))
         layer.initialize()
@@ -490,7 +502,8 @@ def test_the_shares_parts_add_up_to_the_uncut_references_layer():
             return np.asarray(reference.layer(
                 x, dict(p, moe=held), hp, (lo, hi), 6, True))
     with jax.default_matmul_precision("highest"):
-        shares = [share(lo, lo + 4) for lo in range(0, experts, 4)]
+        shares = [share(lo, lo + per_chip)
+                  for lo in range(0, experts, per_chip)]
     # what every chip computes alike: the reference's layer holding no
     # expert at all is residual + attention + shared expert
     whole, alike = plain(0, experts), plain(0, 0)
@@ -498,8 +511,8 @@ def test_the_shares_parts_add_up_to_the_uncut_references_layer():
     assert all(np.abs(part).max() > 1e-3 for part in parts)
     assert np.allclose(alike + sum(parts), whole, atol=2e-5)
     # and each share is the reference handed the same share
-    for lo, got in zip(range(0, experts, 4), shares):
-        assert np.allclose(got, plain(lo, lo + 4), atol=2e-5)
+    for lo, got in zip(range(0, experts, per_chip), shares):
+        assert np.allclose(got, plain(lo, lo + per_chip), atol=2e-5)
 
 
 # -- the cell's arithmetic -------------------------------------------------------------------

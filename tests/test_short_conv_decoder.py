@@ -355,9 +355,10 @@ def test_the_shares_parts_add_up_to_the_uncut_references_layer():
 
 # -- what was there keeps its parameters -------------------------------------------------
 # The three decoder cells' rehearsal nets as the parent of ISSUE 39 built
-# them: every parameter by its path in the block tree, and its shape.
-# (Their lowered train steps were also checked byte for byte at parent and
-# change when ISSUE 39 was done, CHANGES.md, PR 39.)
+# them, and lfm2-24b-a2b's as it was before Kimi Delta Attention came: every
+# parameter by its path in the block tree, and its shape.  (Their lowered
+# train steps were also checked byte for byte at parent and change when
+# each of those two changes was made, CHANGES.md.)
 PARENT_PARAMS = {
     "glm-4.7-flash": """
 embed_weight:512x128 head_weight:512x128 final_norm.gamma:128
@@ -475,6 +476,39 @@ layers.4.ffn.steps_counted:1 layers.4.ffn.shared.gate_proj_weight:32x96
 layers.4.ffn.shared.up_proj_weight:32x96
 layers.4.ffn.shared.down_proj_weight:96x32
 """,
+    "lfm2-24b-a2b": """
+embed_weight:512x64 final_norm.gamma:64 layers.0.ln1.gamma:64
+layers.0.attention.in_proj_weight:192x64 layers.0.attention.conv_weight:64x3
+layers.0.attention.out_proj_weight:64x64 layers.0.ln2.gamma:64
+layers.0.ffn.gate_proj_weight:128x64 layers.0.ffn.up_proj_weight:128x64
+layers.0.ffn.down_proj_weight:64x128 layers.1.ln1.gamma:64
+layers.1.attention.q_weight:64x64 layers.1.attention.k_weight:32x64
+layers.1.attention.v_weight:32x64 layers.1.attention.o_weight:64x64
+layers.1.attention.q_norm.gamma:16 layers.1.attention.k_norm.gamma:16
+layers.1.ln2.gamma:64 layers.1.ffn.gate_weight:8x64 layers.1.ffn.select_bias:8
+layers.1.ffn.expert_w1:2x64x32 layers.1.ffn.expert_w3:2x64x32
+layers.1.ffn.expert_w2:2x32x64 layers.1.ffn.expert_load:64x8
+layers.1.ffn.steps_counted:1 layers.2.ln1.gamma:64
+layers.2.attention.in_proj_weight:192x64 layers.2.attention.conv_weight:64x3
+layers.2.attention.out_proj_weight:64x64 layers.2.ln2.gamma:64
+layers.2.ffn.gate_weight:8x64 layers.2.ffn.select_bias:8
+layers.2.ffn.expert_w1:2x64x32 layers.2.ffn.expert_w3:2x64x32
+layers.2.ffn.expert_w2:2x32x64 layers.2.ffn.expert_load:64x8
+layers.2.ffn.steps_counted:1 layers.3.ln1.gamma:64
+layers.3.attention.in_proj_weight:192x64 layers.3.attention.conv_weight:64x3
+layers.3.attention.out_proj_weight:64x64 layers.3.ln2.gamma:64
+layers.3.ffn.gate_weight:8x64 layers.3.ffn.select_bias:8
+layers.3.ffn.expert_w1:2x64x32 layers.3.ffn.expert_w3:2x64x32
+layers.3.ffn.expert_w2:2x32x64 layers.3.ffn.expert_load:64x8
+layers.3.ffn.steps_counted:1 layers.4.ln1.gamma:64
+layers.4.attention.in_proj_weight:192x64 layers.4.attention.conv_weight:64x3
+layers.4.attention.out_proj_weight:64x64 layers.4.ln2.gamma:64
+layers.4.ffn.gate_weight:8x64 layers.4.ffn.select_bias:8
+layers.4.ffn.expert_w1:2x64x32 layers.4.ffn.expert_w3:2x64x32
+layers.4.ffn.expert_w2:2x32x64 layers.4.ffn.expert_load:64x8
+layers.4.ffn.steps_counted:1
+"""
+,
 }
 
 
@@ -488,12 +522,13 @@ def block_tree(block, prefix=""):
 
 @pytest.mark.parametrize("name,traffic", [
     ("glm-4.7-flash", "pretrain4k"), ("smallthinker-21ba3b", "extend16k"),
-    ("laguna-s-2.1", "pretrain8k")])
+    ("laguna-s-2.1", "pretrain8k"), ("lfm2-24b-a2b", "pretrain4k-b8")])
 def test_the_decoder_cells_that_were_there_keep_their_parameters(name,
                                                                  traffic):
     """With `tie_embeddings`, `qk_norm` and the conv kind absent, each net
     has the parent's parameters, names and shapes, in the parent's order:
-    an untied head, no q/k norm, no conv weight."""
+    an untied head, no q/k norm, no conv weight; and with `q_rank` given,
+    `rotary` and the kda kind absent, the same again."""
     with open(os.path.join(BENCH, "configs", name + ".json")) as f:
         cfg = json.load(f)
     with open(os.path.join(BENCH, "traffic", traffic + ".json")) as f:

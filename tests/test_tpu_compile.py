@@ -112,6 +112,24 @@ def test_windowed_grouped_flash_compiles_for_v5e(v5e, mosaic, window):
         == [q[0], kv[0], kv[0]]     # dk, dv per key/value head
 
 
+def test_the_latent_head_of_192_compiles_for_v5e(v5e, mosaic):
+    """kimi-linear-48b-a3b.pretrain8k-b2's latent layer: 32
+    heads whose q.k spans 128 + 64 and whose values of 128 ride
+    zero-padded to the same 192, T = 8,192, bf16, causal; the three
+    kernels at a head that is no multiple of 128 lanes (`_lanes`' branch
+    that broadcasts one lane column)."""
+    qkv = ((2, 32, 8192, 192), jnp.bfloat16)
+    assert fa.supported(qkv[0], qkv[1])
+
+    def loss(q, k, v):
+        return fa.mha_flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e, qkv, qkv,
+                        qkv)
+    assert _mosaic_calls(compiled) >= 3  # forward, dq, dk/dv
+
+
 @pytest.mark.parametrize("tq", [1, 4])
 def test_paged_decode_compiles_for_v5e(v5e, mosaic, tq):
     """The paged decode kernel at a decoder-sized geometry (16 heads of

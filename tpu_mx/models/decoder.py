@@ -1,8 +1,9 @@
 """Causal decoder blocks, named by mechanism and not by model (ROADMAP M1):
 rotary positions, a gated MLP, latent attention, grouped-query attention
 with an optional window, optional positions and optional q/k norms, a
-gated short convolution, a pre-norm layer, and a causal language model
-with its next-token loss inside the forward (whole, or in chunks of
+gated short convolution, Kimi Delta Attention (a gated delta rule with a
+decay per key channel, in chunks), a pre-norm layer, and a causal language
+model with its next-token loss inside the forward (whole, or in chunks of
 positions), an optional tied head and an optional multi-token-prediction
 module.
 The expert layer is `parallel.DroplessMoE`.
@@ -17,9 +18,13 @@ the rotary turn over a part of the head and YaRN's frequencies (ISSUE 34)
 follow tests/references/gated_mixed_decoder.py
 (tests/test_gated_mixed_decoder.py); the third token mixer, the gated short
 convolution, with the q/k norms and the tied head (ISSUE 39), follows
-tests/references/short_conv_decoder.py (tests/test_short_conv_decoder.py).
+tests/references/short_conv_decoder.py (tests/test_short_conv_decoder.py);
+the fourth, Kimi Delta Attention, with latent attention without a query
+rank or positions, follows tests/references/kda_decoder.py
+(tests/test_kda_decoder.py).
 Training form only: nothing is absorbed, there is no cache (serving is
-ROADMAP M4-M8).  No bias and no dropout anywhere.
+ROADMAP M4-M8).  No dropout anywhere, and no bias but Kimi Delta
+Attention's decay offset and output-gate bias.
 
 bf16-friendly like models/bert.py: norm and softmax statistics, the
 router's scores and the logits are f32 whatever the model's type.
@@ -35,11 +40,14 @@ from ..gluon.block import HybridBlock
 from ..ndarray import ops
 from ..parallel import DroplessMoE, attention as _attention
 from ..parallel.moe import MOE_SCOPES
+from ..parallel.ring_attention import dispatch_counts
+from ..random import host_rng
 
 __all__ = ["rotary", "yarn_inv_freq", "GatedMLP", "LatentAttention",
            "GroupedQueryAttention", "short_conv_mix", "ShortConv",
-           "DecoderLayer", "CausalLM", "DECODER_SCOPES", "ATTENTION_SCOPES",
-           "ATTENTION_GATE_SCOPES", "CONV_SCOPES", "OWNER_SCOPES"]
+           "kda_chunked", "KimiDeltaAttention", "DecoderLayer", "CausalLM",
+           "DECODER_SCOPES", "ATTENTION_SCOPES", "ATTENTION_GATE_SCOPES",
+           "CONV_SCOPES", "KDA_SCOPES", "OWNER_SCOPES"]
 
 # jax.named_scope names inside these blocks (HLO metadata only), beside
 # train_step.STEP_SCOPES; benchmark/decoder_scopes.py holds them as literals
@@ -69,6 +77,12 @@ OWNER_SCOPES = DECODER_SCOPES + ATTENTION_SCOPES + ATTENTION_GATE_SCOPES \
 # benchmark's accepted owners (ROADMAP B1)
 CONV_SCOPES = ("conv.project", "conv.mix")
 _CONV_PROJECT, _CONV_MIX = CONV_SCOPES
+# KimiDeltaAttention's own (benchmark/kda_scopes.py holds them as literals):
+# its projections with the output norm and gate, the short convolutions with
+# the L2 norms, and the decays with the chunked rule; not in OWNER_SCOPES
+# either
+KDA_SCOPES = ("kda.project", "kda.conv", "kda.scan")
+_KDA_PROJECT, _KDA_CONV, _KDA_SCAN = KDA_SCOPES
 
 
 def yarn_inv_freq(theta, dim, factor, original_length, beta_fast=32.0,
@@ -150,11 +164,14 @@ class LatentAttention(HybridBlock):
     heads.  Scores over nope_dim + rope_dim, causal, through
     `parallel.attention` (so the dispatch chooses dense or the flash kernel
     as for every caller); values of `v_dim` (at most the key's size: the
-    kernel takes one head size, so smaller values ride zero-padded)."""
+    kernel takes one head size, so smaller values ride zero-padded).
+    `q_rank` None: the query is x W_q, with no low-rank path and no norm;
+    `rotary` False: neither part of q or k turns (the "rotary" part is then
+    only the key's shared part)."""
 
     def __init__(self, units, num_heads, q_rank, kv_rank, nope_dim, rope_dim,
                  v_dim, rope_theta=10000.0, epsilon=1e-6, mesh=None,
-                 **kwargs):
+                 rotary=True, **kwargs):
         super().__init__(**kwargs)
         if v_dim > nope_dim + rope_dim:
             raise ValueError(f"v_dim {v_dim} exceeds the key size "
@@ -162,10 +179,17 @@ class LatentAttention(HybridBlock):
         self._h, self._dn, self._dr, self._dv = \
             num_heads, nope_dim, rope_dim, v_dim
         self._rkv, self._theta, self._mesh = kv_rank, float(rope_theta), mesh
-        self.q_a_weight = self.params.get("q_a_weight", shape=(q_rank, units))
-        self.q_a_norm = nn.RMSNorm(epsilon=epsilon, in_channels=q_rank)
-        self.q_b_weight = self.params.get(
-            "q_b_weight", shape=(num_heads * (nope_dim + rope_dim), q_rank))
+        self._rotary = rotary
+        if q_rank is None:
+            self.q_weight = self.params.get(
+                "q_weight", shape=(num_heads * (nope_dim + rope_dim), units))
+        else:
+            self.q_a_weight = self.params.get("q_a_weight",
+                                              shape=(q_rank, units))
+            self.q_a_norm = nn.RMSNorm(epsilon=epsilon, in_channels=q_rank)
+            self.q_b_weight = self.params.get(
+                "q_b_weight", shape=(num_heads * (nope_dim + rope_dim),
+                                     q_rank))
         self.kv_a_weight = self.params.get(
             "kv_a_weight", shape=(kv_rank + rope_dim, units))
         self.kv_a_norm = nn.RMSNorm(epsilon=epsilon, in_channels=kv_rank)
@@ -181,20 +205,24 @@ class LatentAttention(HybridBlock):
         h, dn, dr, dv = self._h, self._dn, self._dr, self._dv
         q = q.reshape(b, t, h, dn + dr).transpose(0, 2, 1, 3)
         kv = kv.reshape(b, t, h, dn + dv).transpose(0, 2, 1, 3)
-        q = jnp.concatenate([q[..., :dn], rotary(q[..., dn:], self._theta)],
-                            axis=-1)
-        k_r = rotary(k_r[:, None], self._theta)               # (B, 1, T, dr)
+        if self._rotary:
+            q = jnp.concatenate(
+                [q[..., :dn], rotary(q[..., dn:], self._theta)], axis=-1)
+            k_r = rotary(k_r[:, None], self._theta)           # (B, 1, T, dr)
+        else:
+            k_r = k_r[:, None]
         k = jnp.concatenate(
             [kv[..., :dn], jnp.broadcast_to(k_r, (b, h, t, dr))], axis=-1)
         v = jnp.pad(kv[..., dn:], [(0, 0)] * 3 + [(0, dn + dr - dv)])
         return q, k, v
 
-    def hybrid_forward(self, F, x, q_a_weight, q_b_weight, kv_a_weight,
-                       kv_b_weight, o_weight):
+    def hybrid_forward(self, F, x, kv_a_weight, kv_b_weight, o_weight,
+                       q_a_weight=None, q_b_weight=None, q_weight=None):
         b, t = x.shape[:2]
         rkv = self._rkv
         with jax.named_scope(_PROJECT):
-            q = _linear(F, self.q_a_norm(_linear(F, x, q_a_weight)),
+            q = _linear(F, x, q_weight) if q_weight is not None else \
+                _linear(F, self.q_a_norm(_linear(F, x, q_a_weight)),
                         q_b_weight)
             kv_a = _linear(F, x, kv_a_weight)
             c_kv = self.kv_a_norm(
@@ -336,12 +364,18 @@ def short_conv_mix(bcx, weight):
     sum in f32.  (lax.conv_general_dilated with H feature groups was faster
     on the chip because XLA:TPU ran it in bf16 passes, which the cell's
     comparison refused: PERF.md section 6, PR 39.)"""
-    taps, t = weight.shape[-1], bcx.shape[-2]
     b, c, x = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
-    v = jnp.pad(b * x, [(0, 0)] * (bcx.ndim - 2) + [(taps - 1, 0), (0, 0)])
+    return (c * _causal_filter(b * x, weight)).astype(bcx.dtype)
+
+
+def _causal_filter(v, weight):
+    """The causal depthwise filter over v (..., T, C) f32, weight (C, taps)
+    whose tap i reads position t - (taps - 1) + i: shifted multiply-adds
+    over the sequence padded on the left, in f32."""
+    taps, t = weight.shape[-1], v.shape[-2]
+    v = jnp.pad(v, [(0, 0)] * (v.ndim - 2) + [(taps - 1, 0), (0, 0)])
     w = weight.astype(jnp.float32)
-    z = sum(v[..., i:i + t, :] * w[:, i] for i in range(taps))
-    return (c * z).astype(bcx.dtype)
+    return sum(v[..., i:i + t, :] * w[:, i] for i in range(taps))
 
 
 class ShortConv(HybridBlock):
@@ -366,6 +400,230 @@ class ShortConv(HybridBlock):
             y = ops._apply(short_conv_mix, [bcx, conv_weight], "short_conv")
         with jax.named_scope(_CONV_PROJECT):
             return _linear(F, y, out_proj_weight)
+
+
+# the chunked rule's sub-chunk: pairs within one are decayed pairwise, pairs
+# across sub-chunks through the later one's first position
+_KDA_SUB = 16
+
+
+def _decayed_products(a, b, cum, sub, diagonal):
+    """M[i, j] = sum_d a[i, d] b[j, d] exp(cum[i, d] - cum[j, d]) over a
+    chunk's C positions for j < i (j <= i with `diagonal`), else 0: a, b,
+    cum (..., C, d) f32, cum not increasing along C.  No exponent exceeds
+    0, so nothing overflows however strong the decay: across sub-chunks of
+    `sub` positions the decay passes through the first position r of i's
+    sub-chunk, exp(cum_i - cum_r) exp(cum_r - cum_j), a product on the MXU;
+    within one it is taken pair by pair."""
+    *lead, c, d = a.shape
+    n = c // sub
+    at = lambda x: x.reshape(*lead, n, sub, d)
+    ref = cum[..., ::sub, :]                                  # (..., n, d)
+    left = at(a) * jnp.exp(at(cum) - ref[..., None, :])
+    before = jnp.arange(c)[None, :] < (jnp.arange(n) * sub)[:, None]
+    right = b[..., None, :, :] * jnp.exp(jnp.where(
+        before[:, :, None], ref[..., :, None, :] - cum[..., None, :, :],
+        -jnp.inf))                                            # (..., n, C, d)
+    across = jnp.einsum("...asd,...acd->...asc", left, right,
+                        precision=jax.lax.Precision.HIGHEST)
+    pos = jnp.arange(sub)
+    keep = pos[:, None] >= pos[None, :] if diagonal \
+        else pos[:, None] > pos[None, :]
+    ca = at(cum)
+    pair = jnp.exp(jnp.where(keep[:, :, None],
+                             ca[..., :, None, :] - ca[..., None, :, :],
+                             -jnp.inf))                       # (..., n, s, s, d)
+    within = jnp.sum(at(a)[..., :, None, :] * at(b)[..., None, :, :] * pair,
+                     axis=-1)                                 # (..., n, s, s)
+    out = across.reshape(*lead, n, sub, n, sub) \
+        + within[..., :, :, None, :] * jnp.eye(n)[:, None, :, None]
+    return out.reshape(*lead, c, c)
+
+
+def kda_chunked(q, k, v, g, beta, chunk=64):
+    """Kimi Delta Attention's recurrence, the gated delta rule with a decay
+    for each key channel (arXiv:2510.26692), a head at a time:
+
+        S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_(t-1) + beta_t k_t v_t^T
+        o_t = S_t^T q_t,   S_0 = 0, S (dk, dv) in f32
+
+    q, k (B, T, H, dk), v (B, T, H, dv), g (B, T, H, dk) the log of each
+    channel's decay (at most 0), beta (B, T, H); returns o (B, T, H, dv) in
+    v's type.  In chunks of `chunk` positions (T is padded up to a
+    multiple): inside a chunk the WY form, u = (I + A)^(-1) (beta v - beta
+    (k * exp G) S) with A[i, j] = beta_i sum_d k_i k_j exp(G_i - G_j) for j
+    < i and G the decays summed from the chunk's start, so that o = (q *
+    exp G) S + P u, P the same products of q and k for j <= i, and the state
+    leaves the chunk as exp(G_C) S + (k * exp(G_C - G))^T u; every exponent
+    is at most 0 (`_decayed_products`).  A `lax.scan` carries S from chunk
+    to chunk; its body is under `jax.checkpoint`, so the backward pass
+    keeps one state a chunk and recomputes the rest.  Products at the
+    precision "highest", for the state is f32."""
+    dispatch_counts["kda_chunked"] += 1
+    b, t, h, dk = k.shape
+    dv = v.shape[-1]
+    sub = min(_KDA_SUB, chunk)
+    if chunk % sub:
+        raise ValueError(f"chunk {chunk}: a multiple of {sub}")
+    pad = -t % chunk
+    n = (t + pad) // chunk
+    mm = lambda spec, x, y: jnp.einsum(spec, x, y,
+                                       precision=jax.lax.Precision.HIGHEST)
+
+    def chunks(x):
+        """(B, T, H, ...) -> (N, B, H, C, ...)."""
+        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape(b, n, chunk, h, *x.shape[3:]),
+                            (1, 3), (0, 2))
+
+    def one(state, xs):
+        qc, kc, vc, gc, bc = (x.astype(jnp.float32) for x in xs)
+        cum = jnp.cumsum(gc, axis=-2)
+        decay, bc = jnp.exp(cum), bc[..., None]
+        a = bc * _decayed_products(kc, kc, cum, sub, False)
+        solved = jax.scipy.linalg.solve_triangular(
+            jnp.eye(chunk) + a,
+            jnp.concatenate([bc * vc, bc * kc * decay], axis=-1),
+            lower=True, unit_diagonal=True)
+        u = solved[..., :dv] - mm("...cd,...de->...ce", solved[..., dv:],
+                                  state)
+        out = mm("...cd,...de->...ce", qc * decay, state) \
+            + mm("...ij,...je->...ie",
+                 _decayed_products(qc, kc, cum, sub, True), u)
+        last = cum[..., -1:, :]
+        state = jnp.exp(last)[..., 0, :, None] * state \
+            + mm("...cd,...ce->...de", kc * jnp.exp(last - cum), u)
+        return state, out
+
+    state = jnp.zeros((b, h, dk, dv), jnp.float32)
+    _, out = jax.lax.scan(jax.checkpoint(one), state,
+                          tuple(chunks(x) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(out, (0, 2), (1, 3)).reshape(
+        b, n * chunk, h, dv)[:, :t].astype(v.dtype)
+
+
+def _l2_normalised(x, eps=1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + eps)
+
+
+def _drawn(fn):
+    """An initializer that draws a parameter's values from the host's
+    generator, fn(rng, shape), whatever the parameter's name."""
+    from .. import initializer
+
+    class Drawn(initializer.Initializer):
+        def __call__(self, name, shape, dtype="float32"):
+            return np.asarray(fn(host_rng(), shape), dtype)
+    return Drawn()
+
+
+def _a_log(rng, shape):
+    """log A, A ~ U(1, 16) a head: decay rates exp(A_log) from 1 to 16."""
+    return np.log(rng.uniform(1.0, 16.0, size=shape))
+
+
+def _dt_bias(rng, shape, dt_min=1e-3, dt_max=0.1, floor=1e-4):
+    """softplus^(-1) of dt, dt log-uniform in [dt_min, dt_max] (at least
+    `floor`) a channel: the Mamba family's initial step sizes."""
+    dt = np.maximum(np.exp(rng.uniform(np.log(dt_min), np.log(dt_max),
+                                       size=shape)), floor)
+    return dt + np.log(-np.expm1(-dt))
+
+
+class KimiDeltaAttention(HybridBlock):
+    """Kimi Delta Attention (arXiv:2510.26692), the token mixer of
+    Kimi-Linear's `kda_layers`, per layer input x (B, T, units) and head:
+    q, k, v = x W_q, x W_k, x W_v (units -> heads·head_dim each), each
+    through a causal depthwise filter of `taps` positions and SiLU; q and k
+    L2-normalised a head (eps 1e-6), q scaled by head_dim^-1/2; decays g =
+    -exp(A_log) softplus(x W_f_down W_f_up + dt_bias) a key channel (rank
+    head_dim; A_log a head, dt_bias a channel); beta = sigmoid(x W_b) a
+    head; o from `kda_chunked`; y = W_o [RMS(o) * sigmoid(x W_g_down W_g_up +
+    b_g)], the norm's weight of head_dim shared by the heads (`epsilon`)
+    and the gate's path of rank head_dim with a bias on its up-projection.
+    No other bias."""
+
+    def __init__(self, units, num_heads=32, head_dim=128, taps=4, chunk=64,
+                 epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        self._h, self._d, self._chunk = num_heads, head_dim, chunk
+        width = num_heads * head_dim
+        for name in ("q", "k", "v"):
+            setattr(self, name + "_weight", self.params.get(
+                name + "_weight", shape=(width, units)))
+        for name in ("q", "k", "v"):
+            setattr(self, name + "_conv_weight", self.params.get(
+                name + "_conv_weight", shape=(width, taps)))
+        self.f_a_weight = self.params.get("f_a_weight",
+                                          shape=(head_dim, units))
+        self.f_b_weight = self.params.get("f_b_weight",
+                                          shape=(width, head_dim))
+        self.A_log = self.params.get("A_log", shape=(num_heads,),
+                                     init=_drawn(_a_log))
+        self.dt_bias = self.params.get("dt_bias", shape=(width,),
+                                       init=_drawn(_dt_bias))
+        self.b_weight = self.params.get("b_weight", shape=(num_heads, units))
+        self.g_a_weight = self.params.get("g_a_weight",
+                                          shape=(head_dim, units))
+        self.g_b_weight = self.params.get("g_b_weight",
+                                          shape=(width, head_dim))
+        self.g_b_bias = self.params.get("g_b_bias", shape=(width,),
+                                        init="zeros")
+        self.o_norm = nn.RMSNorm(epsilon=epsilon, in_channels=head_dim)
+        self.o_weight = self.params.get("o_weight", shape=(units, width))
+
+    def _conv(self, q, k, v, q_taps, k_taps, v_taps):
+        """The three filters with SiLU, heads split, q and k normalised and
+        q scaled; in the input's type."""
+        (b, t), dtype = q.shape[:2], q.dtype
+        heads = lambda a: a.reshape(b, t, self._h, self._d)
+        q, k, v = (heads(jax.nn.silu(_causal_filter(a.astype(jnp.float32),
+                                                    w)))
+                   for a, w in ((q, q_taps), (k, k_taps), (v, v_taps)))
+        return tuple(a.astype(dtype) for a in (
+            _l2_normalised(q) * self._d ** -0.5, _l2_normalised(k), v))
+
+    def _scan(self, q, k, v, f, a_log, dt_bias, z_beta):
+        """The decays, beta and the chunked rule: o (B, T, H, head_dim)."""
+        f32 = jnp.float32
+        b, t = f.shape[:2]
+        g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
+            (f.astype(f32) + dt_bias.astype(f32)).reshape(b, t, self._h,
+                                                          self._d))
+        return kda_chunked(q, k, v, g, jax.nn.sigmoid(z_beta.astype(f32)),
+                           self._chunk)
+
+    @staticmethod
+    def _gate(o, z, bias):
+        """RMS(o) (B, T, H, d) times sigmoid(z + b_g) (B, T, H·d), in f32,
+        heads merged."""
+        b, t = z.shape[:2]
+        gate = jax.nn.sigmoid(z.astype(jnp.float32)
+                              + bias.astype(jnp.float32))
+        return (o.reshape(b, t, -1).astype(jnp.float32) * gate).astype(
+            o.dtype)
+
+    def hybrid_forward(self, F, x, q_weight, k_weight, v_weight,
+                       q_conv_weight, k_conv_weight, v_conv_weight,
+                       f_a_weight, f_b_weight, A_log, dt_bias, b_weight,
+                       g_a_weight, g_b_weight, g_b_bias, o_weight):
+        with jax.named_scope(_KDA_PROJECT):
+            q, k, v = (_linear(F, x, w)
+                       for w in (q_weight, k_weight, v_weight))
+            f = _linear(F, _linear(F, x, f_a_weight), f_b_weight)
+            z_beta = _linear(F, x, b_weight)
+            z = _linear(F, _linear(F, x, g_a_weight), g_b_weight)
+        with jax.named_scope(_KDA_CONV):
+            q, k, v = ops._apply(self._conv, [q, k, v, q_conv_weight,
+                                              k_conv_weight, v_conv_weight],
+                                 "kda_conv")
+        with jax.named_scope(_KDA_SCAN):
+            o = ops._apply(self._scan, [q, k, v, f, A_log, dt_bias, z_beta],
+                           "kda_scan")
+        with jax.named_scope(_KDA_PROJECT):
+            o = ops._apply(self._gate, [self.o_norm(o), z, g_b_bias],
+                           "kda_gate")
+            return _linear(F, o, o_weight)
 
 
 class DecoderLayer(HybridBlock):
@@ -480,7 +738,8 @@ class CausalLM(HybridBlock):
     dict for every layer alike, or a list with one dict a layer, the
     multi-token module's last; `kind` "latent", the default, for
     LatentAttention, "grouped_query" for GroupedQueryAttention,
-    "short_conv" for ShortConv), tie_embeddings, moe
+    "short_conv" for ShortConv, "kda" for KimiDeltaAttention),
+    tie_embeddings, moe
     (hidden_size, num_experts, top_k,
     held_experts (lo, hi), scaling, shared_hidden, scoring, activation,
     router_before_attention), mtp_depth (0 or 1), mtp_weight, loss_chunk
@@ -509,9 +768,11 @@ class CausalLM(HybridBlock):
                 return LatentAttention(units, epsilon=eps, mesh=mesh, **att)
             if kind == "short_conv":
                 return ShortConv(units, **att)
+            if kind == "kda":
+                return KimiDeltaAttention(units, epsilon=eps, **att)
             if kind != "grouped_query":
                 raise ValueError(f"attention kind {kind!r}: latent, "
-                                 "grouped_query or short_conv")
+                                 "grouped_query, short_conv or kda")
             return GroupedQueryAttention(units, epsilon=eps, mesh=mesh,
                                          **att)
 

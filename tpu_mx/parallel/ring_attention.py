@@ -43,13 +43,15 @@ __all__ = ["ring_attention", "attention", "local_flash_attention",
 
 _logger = logging.getLogger(__name__)
 
-# Which attention path each distinct call signature took.  Deduplicated by
+# Which attention path each distinct call signature took (and, counted by
+# models/decoder.py's kda_chunked as it is traced, how often the chunked
+# delta rule was).  Deduplicated by
 # (path, detail): under jit this is once per compilation; on the eager path
 # it is once per new shape/dtype — so a shape regression that silently drops
 # the Pallas kernel shows up exactly once, not once per step (VERDICT r1
 # weak#6).  Mirrored into profiler counters.
 dispatch_counts = {"ring": 0, "ulysses": 0, "pallas_flash": 0,
-                   "xla_dense": 0}
+                   "xla_dense": 0, "kda_chunked": 0}
 # For every flash call with a window, as it is traced, a head: the (q block,
 # k block) pairs of the square at the blocks the call takes ("grid"), those
 # of them that run ("run": the rest lie above the diagonal or wholly before
