@@ -61,8 +61,7 @@ def model_config(cfg):
     def mixer(kind):
         if kind == KDA:
             return dict(kind="kda", num_heads=kda["heads"],
-                        head_dim=kda["head_dim"], taps=kda["taps"],
-                        chunk=cfg["system"]["kda_chunk"])
+                        head_dim=kda["head_dim"], taps=kda["taps"])
         return dict(kind="latent", num_heads=mla["heads"], q_rank=None,
                     kv_rank=mla["kv_rank"], nope_dim=mla["nope"],
                     rope_dim=mla["rope"], v_dim=mla["v"],
@@ -329,6 +328,43 @@ def scan_inputs(tokens, cfg):
     return q, k, v, g, beta
 
 
+def _moes(net):
+    return [l.ffn for l in _layers(net) if hasattr(l.ffn, "select_bias")]
+
+
+def tap_expert_inputs(net):
+    """Forward pre-hooks on each expert layer of the net that copy the
+    layer's input to the host when a program that runs the net runs (a
+    host callback, traced into the program like the layer itself): (a dict
+    that gets each layer's first input by its index, the hooks, to be
+    detached when done)."""
+    import jax
+    inputs = {}
+
+    def tap(i):
+        def hook(block, args):
+            x = args[0]
+            jax.debug.callback(
+                lambda v: inputs.setdefault(i, np.asarray(v)),
+                getattr(x, "_data", x))
+        return hook
+    return inputs, [m.register_forward_pre_hook(tap(i))
+                    for i, m in enumerate(_moes(net))]
+
+
+def route_rows(xs, routers, hp):
+    """The program's own routing function on each expert layer's rows xs
+    (S, U), with that layer's (router, selection bias): [(chosen (S, k),
+    weights (S, k))] a layer."""
+    import functools
+    import jax
+    from tpu_mx.parallel import dropless_route
+    route = jax.jit(functools.partial(
+        dropless_route, top_k=hp["top_k"], scaling=hp["scaling"],
+        scoring="sigmoid"))
+    return [route(x, *r) for x, r in zip(xs, routers)]
+
+
 def system_forward(net, batch, n):
     """What the system's forward gives for the batch, whose n sequences are
     all of it (the timed step trains on them all): (the compared outputs,
@@ -341,40 +377,25 @@ def system_forward(net, batch, n):
     `kda.scan`) is asked for its output alone on seeded inputs of the
     published shape: a chunk that forgets the state it was handed, or a
     decay taken a head at a time, reads off there at once."""
-    import functools
     import jax
     from tpu_mx.models.decoder import kda_chunked
     from tpu_mx.ndarray import NDArray
-    from tpu_mx.parallel import dropless_route
     cfg = net._bench_cfg
     hp, held = hyper(cfg)
     tokens = np.asarray(batch[0])[:n]
     assert len(tokens) == len(batch[0]), "compare the whole batch"
-    moes = [l.ffn for l in _layers(net) if hasattr(l.ffn, "select_bias")]
-    inputs = {}
-
-    def tap(i):
-        def hook(block, args):
-            x = args[0]
-            jax.debug.callback(
-                lambda v: inputs.__setitem__(i, np.asarray(v)),
-                getattr(x, "_data", x))
-        return hook
-    hooks = [m.register_forward_pre_hook(tap(i)) for i, m in enumerate(moes)]
+    inputs, hooks = tap_expert_inputs(net)
     net.hybridize()
     out = net(NDArray(tokens))
     net.hybridize(False)
     jax.effects_barrier()
     for h in hooks:
         h.detach()
-
-    route = jax.jit(functools.partial(
-        dropless_route, top_k=hp["top_k"], scaling=hp["scaling"],
-        scoring="sigmoid"))
+    moes = _moes(net)
     xs = [inputs[i].reshape(-1, inputs[i].shape[-1])
           for i in range(len(moes))]
-    routed = [route(x, m.gate_weight.data()._data, m.select_bias.data()._data)
-              for x, m in zip(xs, moes)]
+    routed = route_rows(xs, [(m.gate_weight.data()._data,
+                              m.select_bias.data()._data) for m in moes], hp)
     chosen = [np.asarray(c) for c, _ in routed]
     # the held expert of the last layer with the most rows: its down
     # projection is the one compared
@@ -386,8 +407,7 @@ def system_forward(net, batch, n):
     got["route_choice"] = _one_hot(chosen, hp["n_experts"])
     got["route_weights"] = np.concatenate([_f32(w) for _, w in routed])
     scanned = scan_inputs(tokens, cfg)
-    got["kda_scan"] = _f32(jax.jit(functools.partial(
-        kda_chunked, chunk=cfg["system"]["kda_chunk"]))(*scanned))
+    got["kda_scan"] = _f32(jax.jit(kda_chunked)(*scanned))
     return got, {"tokens": tokens, "expert": expert, "inputs": xs,
                  "chosen": chosen, "scan_inputs": scanned}
 
@@ -459,7 +479,7 @@ def _reference_programs(reference, cfg, low, expert):
 
 
 def reference_outputs(reference, net, aux, wrong=None, low=None,
-                      programs=None, first=None):
+                      programs=None, first=None, params=None):
     """The reference's side of the same outputs, on the system's weights
     (as they were before its step) and with the system's choice of experts
     in place of its own (the choice is a step function of the scores: a
@@ -472,7 +492,8 @@ def reference_outputs(reference, net, aux, wrong=None, low=None,
     the stated AdamW's first step from that gradient:
     -lr (g / (|g| + eps) + wd w).  `programs`, a dict, keeps the compiled
     programs between calls (a script that reads every wrong variant
-    compiles once)."""
+    compiles once).  `params`: the weights in weights()'s form, where the
+    net's own have moved on since (a copy taken before a step)."""
     cfg = net._bench_cfg
     hp, _ = hyper(cfg)
     sys_cfg = cfg["system"]
@@ -482,7 +503,7 @@ def reference_outputs(reference, net, aux, wrong=None, low=None,
         programs[key] = _reference_programs(reference, cfg, *key)
     model, routing = programs[key]
     index = np.int32(-1 if wrong is None else reference.WRONG.index(wrong))
-    weights_ = weights(net)
+    weights_ = weights(net) if params is None else params
     tokens = aux["tokens"]
     t, first = tokens.shape[1], first or len(tokens)
     logits, loss, grads = [], 0.0, {}

@@ -10,9 +10,27 @@ wrong variant of the reference (one compiled f32 program serves them all:
 all in bfloat16 in place of the f32 one, and two planted faults of the
 timed step, half of the batch left out and a state left unchanged, each
 put through run.py's own compare_with_reference at the limits in the
-file: the control and the faults have to come out as not correct (PERF.md,
-section 6, says how long it takes).  --rehearse-cpu runs the same control flow at the toy sizes on
-the CPU; its numbers are no readings.
+file: the control and the faults have to come out as not correct, and so
+does each wrong variant (PERF.md, section 6, says how long it takes).
+
+On every seed, besides, what the first updates' and the routing's errors
+are made of.  AdamW's first step is -lr (g / (|g| + eps) + wd w), nearly
+-lr sign(g): where the system's gradient and the reference's differ in
+sign, the two updates differ by 2 lr.  `flips_seed_<n>` gives, for each
+compared leaf, the share of elements whose gradient differs in sign, the
+share of those whose reference gradient lies within three times the RMS
+of the gradient's error, and the update's error from those elements alone
+and from all the others.  The timed step is a program of its own, and its
+expert layers' inputs may differ from the forward's by a rounding: a
+forward hook copies them out of the step (a host callback traced into it),
+the program's routing function is asked for the step's own choice, and
+`route_rows_differing_seed_<n>` counts, for each expert layer, the rows
+whose eight experts differ from the forward's; where any do, the step's
+outputs are read again against the reference handed the step's choice
+(after the step, whose state then leaves the chip: the net is not used
+again).
+--rehearse-cpu runs the same control flow at the toy sizes on the CPU; its
+numbers are no readings.
 """
 import argparse
 import gc
@@ -36,6 +54,10 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seeds", default="1,2")
     ap.add_argument("--out", default=None)
+    ap.add_argument("--wrong", default="all",
+                    help="the wrong variants to read, comma-separated, "
+                         "\"all\" or \"none\" (each is a pass of the "
+                         "reference over the whole batch)")
     ap.add_argument("--rehearse-cpu", action="store_true")
     args = ap.parse_args()
     import jax
@@ -77,6 +99,30 @@ def main():
             cfg, types.SimpleNamespace(compare=lambda *a: (got, want)),
             reference, None, None, False))
 
+    def flips(got, want):
+        """For each compared leaf: what the update's error is made of."""
+        lr = cfg["system"]["learning_rate"]
+        found = {}
+        for leaf in config_mod.LEAVES:
+            gs, gr = (np.asarray(d["grad_" + leaf], np.float64)
+                      for d in (got, want))
+            us, ur = got["update_" + leaf], want["update_" + leaf]
+            flip = np.sign(gs) != np.sign(gr)
+            noise = math.sqrt(np.mean((gs - gr) ** 2))
+            share = float(flip.mean())
+            found[leaf] = {
+                "flipped_share": share,
+                "flipped_within_3_noise": float(np.mean(
+                    np.abs(gr[flip]) <= 3 * noise)) if flip.any() else None,
+                "update": harness.relative_rms(us, ur),
+                "update_from_the_flipped": harness.relative_rms(
+                    np.where(flip, us, ur), ur),
+                "update_from_the_others": harness.relative_rms(
+                    np.where(flip, ur, us), ur),
+                "two_lr_over_the_flipped": 2 * lr * math.sqrt(share)
+                / math.sqrt(np.mean(np.square(ur, dtype=np.float64)))}
+        return found
+
     seeds = [int(s) for s in args.seeds.split(",")]
     for seed in seeds:
         net, _ = config_mod.build(cfg, mix, seed)
@@ -89,7 +135,8 @@ def main():
         if last:
             wrongs = {w: config_mod.reference_outputs(
                 reference, net, aux, wrong=w, programs=programs)
-                for w in reference.WRONG}
+                for w in reference.WRONG
+                if args.wrong == "all" or w in args.wrong.split(",")}
             low = config_mod.reference_outputs(reference, net, aux,
                                                low="all", programs=programs)
             # the timed step with half of the batch left out reads against
@@ -98,11 +145,43 @@ def main():
             half = config_mod.reference_outputs(
                 reference, net, aux, programs=programs,
                 first=len(aux["tokens"]) // 2)
+        # the weights the step starts from, and the rows its own expert
+        # layers read
+        before = jax.device_get(config_mod.weights(net))
+        inputs, hooks = config_mod.tap_expert_inputs(net)
         got.update(config_mod.system_step(net, batch, aux))
+        jax.effects_barrier()
+        for h in hooks:
+            h.detach()
         say(f"honest_seed_{seed}", errors(got, want))
+        say(f"flips_seed_{seed}", flips(got, want))
+        hp, _ = config_mod.hyper(cfg)
+        xs = [inputs[i].reshape(-1, inputs[i].shape[-1])
+              for i in range(len(inputs))]
+        chosen = [np.asarray(c) for c, _ in config_mod.route_rows(
+            xs, [(p["moe"]["router"], p["moe"]["bias"])
+                 for p in before["layers"] if "moe" in p], hp)]
+        differing = [int(np.any(np.sort(a, -1) != np.sort(b, -1), -1).sum())
+                     for a, b in zip(aux["chosen"], chosen)]
+        say(f"route_rows_differing_seed_{seed}",
+            {"rows": differing, "of": [len(c) for c in chosen]})
+        if any(differing):
+            # the step's state is read: the reference's programs get the
+            # chip's memory, with what they read on the host
+            scanned = jax.device_get(aux["scan_inputs"])
+            for a in jax.live_arrays():
+                a.delete()
+            stepped = config_mod.reference_outputs(
+                reference, net, dict(aux, chosen=chosen, inputs=xs,
+                                     scan_inputs=scanned),
+                programs=programs, params=before)
+            keys = [k for k in got
+                    if k == "loss" or k.startswith(("grad_", "update_"))]
+            say(f"honest_seed_{seed}_with_the_steps_choice", errors(
+                {k: got[k] for k in keys}, {k: stepped[k] for k in keys}))
         if last:
             for wrong, wrong_want in wrongs.items():
-                say(f"wrong_{wrong}", errors(got, wrong_want))
+                verdict(f"wrong_{wrong}", got, wrong_want)
             say("low_all_against_f32", errors(low, want))
             verdict("low_all", got, low)
             say("fault_half_batch", errors(got, half))
@@ -113,7 +192,7 @@ def main():
             say("fault_frozen", errors(frozen, want))
             verdict("fault_frozen", frozen, want)
         cfg.pop("live", None)
-        del net, batch, got, want, aux
+        del net, batch, got, want, aux, before, inputs, xs
         gc.collect()
 
 

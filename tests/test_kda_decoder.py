@@ -76,9 +76,10 @@ def rule_inputs(t, strength, seed=0, b=2, h=2, dk=16, dv=8):
     (256, 64, 12.0),    # decays summing below -100 inside a chunk
     (100, 64, 4.0),     # a chunk and a part: padded at the end
     (96, 32, 0.0),      # no decay at all, chunks of 32
-    (256, 64, 0.05)],   # a decay that hardly decays
+    (256, 64, 0.05),    # a decay that hardly decays
+    (1100, 64, 2.0)],   # 18 chunks: a whole group of 16 and a part
     ids=["t64", "t192", "t256-strong", "t100-padded", "t96-c32-nodecay",
-         "t256-weak"])
+         "t256-weak", "t1100-groups"])
 def test_the_chunked_rule_is_the_recurrence(t, chunk, strength):
     q, k, v, g, beta = rule_inputs(t, strength, seed=t)
     if strength > 10:
@@ -145,7 +146,7 @@ def by_end(params, end):
 
 
 def kda_block(seed=0):
-    block = KimiDeltaAttention(16, num_heads=2, head_dim=8, taps=4, chunk=16,
+    block = KimiDeltaAttention(16, num_heads=2, head_dim=8, taps=4,
                                epsilon=1e-5)
     params = drawn(block, seed)
     plain = {k: by_end(params, v)
@@ -159,11 +160,12 @@ def test_the_mixer_is_the_reference_forward_and_in_its_gradients():
     block, params, plain, hp = kda_block()
     assert float(by_end(params, "A_log").min()) >= 0.0
     assert float(by_end(params, "dt_bias").max()) < 0.0   # dt <= 0.1
-    x = jax.random.normal(jax.random.key(1), (2, 40, 16))
+    # a chunk of 64 and a part: the state crosses a boundary, the end padded
+    x = jax.random.normal(jax.random.key(1), (2, 100, 16))
     with jax.default_matmul_precision("highest"):
         got = jax.jit(lambda pm: system_call(block, pm, x))(params)
         want = reference.kda(x, plain, hp)
-        assert got.shape == (2, 40, 16)
+        assert got.shape == (2, 100, 16)
         assert rel(got, want) < 1e-5
         grads = jax.jit(jax.grad(lambda pm: jnp.sum(jnp.sin(system_call(
             block, pm, x)))))(params)
@@ -177,11 +179,12 @@ def test_the_mixer_is_the_reference_forward_and_in_its_gradients():
         assert rel(g, ref_grads[k]) < 1e-4, k
 
 
-@pytest.mark.parametrize("at", [0, 9, 30])
+@pytest.mark.parametrize("at", [0, 9, 30, 70])
 def test_the_mixer_is_causal(at):
-    """A change of the input at position s moves no output before s."""
+    """A change of the input at position s moves no output before s (70:
+    in the second chunk of 64)."""
     block, params, _, _ = kda_block(seed=3)
-    x = jax.random.normal(jax.random.key(2), (1, 40, 16))
+    x = jax.random.normal(jax.random.key(2), (1, 100, 16))
     run = jax.jit(lambda xx: system_call(block, params, xx))
     moved = np.abs(np.asarray(run(x.at[0, at].add(1.0)) - run(x))).max(-1)[0]
     assert (moved[:at] == 0).all() and moved[at] > 1e-4
@@ -244,15 +247,14 @@ def toy_cfg(chunk):
     """The configuration file at its rehearsal sizes, f32 so that the
     comparison is of the equations and not of bf16's rounding; the head's
     loss whole (chunk None: every position's logits) or in chunks; the
-    rule in chunks of 32, so that 128 positions cross three boundaries (and
-    the reference's 64 one)."""
+    rule's 128 positions cross one boundary of its chunks of 64."""
     with open(os.path.join(BENCH, "configs", NAME + ".json")) as f:
         cfg = json.load(f)
     cfg.update(cfg["rehearse"])
     # at a thirtieth of the width, five times the published initializer's
     # spread gives the mixers and the routers values of a size
     cfg["system"] = dict(cfg["system"], dtype="float32", init_sigma=0.1,
-                         loss_chunk=chunk, kda_chunk=32)
+                         loss_chunk=chunk)
     cfg["reference_comparison"] = dict(cfg["reference_comparison"],
                                        logit_stride=4 if chunk else 1)
     return cfg

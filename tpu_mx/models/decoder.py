@@ -405,6 +405,11 @@ class ShortConv(HybridBlock):
 # the chunked rule's sub-chunk: pairs within one are decayed pairwise, pairs
 # across sub-chunks through the later one's first position
 _KDA_SUB = 16
+# chunks whose products that need no state are formed at once: compiled for
+# a TPU v5e, a layer's backward over 2 sequences of 8,192 in 32 heads of 128
+# takes some 1.46 GiB of temporaries at groups of 8 chunks of 64, and 2.03
+# GiB at 16
+_KDA_GROUP = 8
 
 
 def _decayed_products(a, b, cum, sub, diagonal):
@@ -449,15 +454,18 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
 
     q, k (B, T, H, dk), v (B, T, H, dv), g (B, T, H, dk) the log of each
     channel's decay (at most 0), beta (B, T, H); returns o (B, T, H, dv) in
-    v's type.  In chunks of `chunk` positions (T is padded up to a
-    multiple): inside a chunk the WY form, u = (I + A)^(-1) (beta v - beta
-    (k * exp G) S) with A[i, j] = beta_i sum_d k_i k_j exp(G_i - G_j) for j
-    < i and G the decays summed from the chunk's start, so that o = (q *
-    exp G) S + P u, P the same products of q and k for j <= i, and the state
-    leaves the chunk as exp(G_C) S + (k * exp(G_C - G))^T u; every exponent
-    is at most 0 (`_decayed_products`).  A `lax.scan` carries S from chunk
-    to chunk; its body is under `jax.checkpoint`, so the backward pass
-    keeps one state a chunk and recomputes the rest.  Products at the
+    v's type.  In chunks of `chunk` positions, with G the decays summed
+    from a chunk's start and S the state the chunk is handed; T is padded
+    up to a whole number of groups of `_KDA_GROUP` chunks (fewer where T
+    has fewer).  A `lax.scan` over groups, its body under `jax.checkpoint`
+    (the backward pass keeps one state a group and recomputes the rest),
+    first forms what needs no S for the group's chunks at once: the WY
+    form, (I + A) [u0 | w] = [beta v | beta k exp G] with A[i, j] = beta_i
+    sum_d k_i k_j exp(G_i - G_j) for j < i, and P, the same products of q
+    and k for j <= i; every exponent is at most 0 (`_decayed_products`).
+    An inner `lax.scan` then carries S from chunk to chunk with four
+    products a chunk: u = u0 - w S, o = (q exp G) S + P u, and the state
+    it leaves, exp(G_C) S + (k exp(G_C - G))^T u.  Products at the
     precision "highest", for the state is f32."""
     dispatch_counts["kda_chunked"] += 1
     b, t, h, dk = k.shape
@@ -465,41 +473,44 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
     sub = min(_KDA_SUB, chunk)
     if chunk % sub:
         raise ValueError(f"chunk {chunk}: a multiple of {sub}")
-    pad = -t % chunk
-    n = (t + pad) // chunk
+    group = min(_KDA_GROUP, -(-t // chunk))
+    pad = -t % (chunk * group)
+    n = (t + pad) // (chunk * group)
     mm = lambda spec, x, y: jnp.einsum(spec, x, y,
                                        precision=jax.lax.Precision.HIGHEST)
 
-    def chunks(x):
-        """(B, T, H, ...) -> (N, B, H, C, ...)."""
+    def groups(x):
+        """(B, T, H, ...) -> (N, group, B, H, C, ...)."""
         x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-        return jnp.moveaxis(x.reshape(b, n, chunk, h, *x.shape[3:]),
-                            (1, 3), (0, 2))
+        return jnp.moveaxis(x.reshape(b, n, group, chunk, h, *x.shape[3:]),
+                            (1, 2, 4), (0, 1, 3))
+
+    def across(state, parts):
+        u0, w, p, qd, kd, decay = parts
+        u = u0 - mm("...cd,...de->...ce", w, state)
+        out = mm("...cd,...de->...ce", qd, state) \
+            + mm("...ij,...je->...ie", p, u)
+        state = decay[..., None] * state + mm("...cd,...ce->...de", kd, u)
+        return state, out.astype(v.dtype)
 
     def one(state, xs):
         qc, kc, vc, gc, bc = (x.astype(jnp.float32) for x in xs)
         cum = jnp.cumsum(gc, axis=-2)
-        decay, bc = jnp.exp(cum), bc[..., None]
-        a = bc * _decayed_products(kc, kc, cum, sub, False)
+        last, bc = cum[..., -1:, :], bc[..., None]
         solved = jax.scipy.linalg.solve_triangular(
-            jnp.eye(chunk) + a,
-            jnp.concatenate([bc * vc, bc * kc * decay], axis=-1),
+            jnp.eye(chunk) + bc * _decayed_products(kc, kc, cum, sub, False),
+            jnp.concatenate([bc * vc, bc * kc * jnp.exp(cum)], axis=-1),
             lower=True, unit_diagonal=True)
-        u = solved[..., :dv] - mm("...cd,...de->...ce", solved[..., dv:],
-                                  state)
-        out = mm("...cd,...de->...ce", qc * decay, state) \
-            + mm("...ij,...je->...ie",
-                 _decayed_products(qc, kc, cum, sub, True), u)
-        last = cum[..., -1:, :]
-        state = jnp.exp(last)[..., 0, :, None] * state \
-            + mm("...cd,...ce->...de", kc * jnp.exp(last - cum), u)
-        return state, out
+        return jax.lax.scan(across, state, (
+            solved[..., :dv], solved[..., dv:],
+            _decayed_products(qc, kc, cum, sub, True), qc * jnp.exp(cum),
+            kc * jnp.exp(last - cum), jnp.exp(last[..., 0, :])))
 
     state = jnp.zeros((b, h, dk, dv), jnp.float32)
     _, out = jax.lax.scan(jax.checkpoint(one), state,
-                          tuple(chunks(x) for x in (q, k, v, g, beta)))
-    return jnp.moveaxis(out, (0, 2), (1, 3)).reshape(
-        b, n * chunk, h, dv)[:, :t].astype(v.dtype)
+                          tuple(groups(x) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(out, (0, 1, 3), (1, 2, 4)).reshape(
+        b, n * group * chunk, h, dv)[:, :t]
 
 
 def _l2_normalised(x, eps=1e-6):
@@ -543,10 +554,10 @@ class KimiDeltaAttention(HybridBlock):
     and the gate's path of rank head_dim with a bias on its up-projection.
     No other bias."""
 
-    def __init__(self, units, num_heads=32, head_dim=128, taps=4, chunk=64,
+    def __init__(self, units, num_heads=32, head_dim=128, taps=4,
                  epsilon=1e-6, **kwargs):
         super().__init__(**kwargs)
-        self._h, self._d, self._chunk = num_heads, head_dim, chunk
+        self._h, self._d = num_heads, head_dim
         width = num_heads * head_dim
         for name in ("q", "k", "v"):
             setattr(self, name + "_weight", self.params.get(
@@ -590,8 +601,7 @@ class KimiDeltaAttention(HybridBlock):
         g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
             (f.astype(f32) + dt_bias.astype(f32)).reshape(b, t, self._h,
                                                           self._d))
-        return kda_chunked(q, k, v, g, jax.nn.sigmoid(z_beta.astype(f32)),
-                           self._chunk)
+        return kda_chunked(q, k, v, g, jax.nn.sigmoid(z_beta.astype(f32)))
 
     @staticmethod
     def _gate(o, z, bias):
