@@ -57,31 +57,37 @@ def system_call(block, params, *xs):
 
 
 # -- the chunked rule --------------------------------------------------------------------
-def rule_inputs(t, strength, seed=0, b=2, h=2, dk=16, dv=8):
+def rule_inputs(t, strength, seed=0, b=2, h=2, dk=16, dv=8, aligned=False):
     """q, k L2-normalised, v, g = -strength softplus(normal) (strength 0: a
-    decay of 1 everywhere), beta in (0, 1)."""
-    keys = jax.random.split(jax.random.key(seed), 5)
+    decay of 1 everywhere), beta in (0, 1).  `aligned`: the keys of a head
+    share one direction up to noise of 0.05 and beta lies in (0.9, 1), so
+    that I + A is as far from I as a trained model's can be."""
+    keys = jax.random.split(jax.random.key(seed), 6)
     l2 = lambda a: a / jnp.sqrt(jnp.sum(a * a, -1, keepdims=True))
     q = l2(jax.random.normal(keys[0], (b, t, h, dk)))
     k = l2(jax.random.normal(keys[1], (b, t, h, dk)))
     v = jax.random.normal(keys[2], (b, t, h, dv))
     g = -strength * jax.nn.softplus(jax.random.normal(keys[3], (b, t, h, dk)))
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (b, t, h)))
+    if aligned:
+        k = l2(l2(jax.random.normal(keys[5], (b, 1, h, dk))) + 0.05 * k)
+        beta = 0.9 + 0.1 * beta
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("t,chunk,strength", [
-    (64, 64, 1.0),      # one whole chunk
-    (192, 64, 1.0),     # three, the state carried over two boundaries
-    (256, 64, 12.0),    # decays summing below -100 inside a chunk
-    (100, 64, 4.0),     # a chunk and a part: padded at the end
-    (96, 32, 0.0),      # no decay at all, chunks of 32
-    (256, 64, 0.05),    # a decay that hardly decays
-    (1100, 64, 2.0)],   # 18 chunks: a whole group of 16 and a part
+@pytest.mark.parametrize("t,chunk,strength,aligned", [
+    (64, 64, 1.0, False),      # one whole chunk
+    (192, 64, 1.0, False),     # three, the state carried over two boundaries
+    (256, 64, 12.0, False),    # decays summing below -100 inside a chunk
+    (100, 64, 4.0, False),     # a chunk and a part: padded at the end
+    (96, 32, 0.0, False),      # no decay at all, chunks of 32
+    (256, 64, 0.05, False),    # a decay that hardly decays
+    (1100, 64, 2.0, False),    # 18 chunks: two whole groups of 8 and a part
+    (192, 64, 0.05, True)],    # keys that point alike, beta near 1
     ids=["t64", "t192", "t256-strong", "t100-padded", "t96-c32-nodecay",
-         "t256-weak", "t1100-groups"])
-def test_the_chunked_rule_is_the_recurrence(t, chunk, strength):
-    q, k, v, g, beta = rule_inputs(t, strength, seed=t)
+         "t256-weak", "t1100-groups", "t192-aligned"])
+def test_the_chunked_rule_is_the_recurrence(t, chunk, strength, aligned):
+    q, k, v, g, beta = rule_inputs(t, strength, seed=t, aligned=aligned)
     if strength > 10:
         # the sum of a chunk's decays from its start: exp of its negation,
         # a split form's factor, is past float32's range
@@ -102,6 +108,68 @@ def test_the_chunked_rule_is_the_recurrence(t, chunk, strength):
     for name, a, b in zip("qkvgb", grads, ref_grads):
         assert np.isfinite(np.asarray(a)).all(), name
         assert rel(a, b) < 1e-4, name
+
+
+def chunk_system(c, strength, seed=0, heads=6):
+    """A = beta k_i k_j exp(G_i - G_j) (j < i) of one chunk of c positions a
+    head, keys aligned as `rule_inputs` draws them: (A, I + A in float64)."""
+    _, k, _, g, beta = rule_inputs(c, strength, seed=seed, b=1, h=heads,
+                                   aligned=True)
+    k, g = (jnp.moveaxis(x[0], 1, 0) for x in (k, g))        # (H, c, d)
+    a = beta[0].T[..., None] * decoder._decayed_products(
+        k, k, jnp.cumsum(g, axis=-2), min(16, c), False)
+    return a, np.eye(c) + np.asarray(a, np.float64)
+
+
+@pytest.mark.parametrize("c", [16, 32, 64])
+@pytest.mark.parametrize("strength", [0.0, 4.0], ids=["nodecay", "strong"])
+def test_the_chunk_inverse_is_float64s(c, strength):
+    """The chunk's (I + A)^-1, by forward substitution in f32, against
+    NumPy's in float64, on keys that point alike: A's entries near 1 with
+    no decay, where a product form's powers of A would grow into the
+    thousands before they cancel."""
+    a, system = chunk_system(c, strength)
+    if strength == 0.0:
+        assert np.asarray(a)[:, -1, 0].min() > 0.8
+    t = np.asarray(decoder._unit_lower_inverse(a), np.float64)
+    eye = np.eye(c)
+    for ti, mi in zip(t, system):
+        assert np.linalg.norm(ti @ mi - eye) / np.linalg.norm(eye) < 1e-5
+        inv = np.linalg.inv(mi)
+        assert np.linalg.norm(ti - inv) / np.linalg.norm(inv) < 1e-5
+
+
+def test_the_solves_gradient_is_the_triangular_solves():
+    """(I + A)^-1 [beta v | beta k e^G] by the inverse and its two products
+    a step back: the same values and gradients as XLA's triangular solve."""
+    a, _ = chunk_system(64, 1.0, seed=3)
+    rhs = jax.random.normal(jax.random.key(4), a.shape[:-1] + (24,))
+    w = jax.random.normal(jax.random.key(5), rhs.shape)
+    eye = jnp.eye(64)
+    loss = lambda solve: lambda a, r: jnp.sum(w * jnp.sin(solve(a, r)))
+    solver = lambda a, r: jax.scipy.linalg.solve_triangular(
+        eye + jnp.tril(a, -1), r, lower=True, unit_diagonal=True)
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(loss(decoder._unit_lower_solve), (0, 1))(
+            a, rhs)
+        want = jax.value_and_grad(loss(solver), (0, 1))(a, rhs)
+    assert abs(float(got[0]) - float(want[0])) < 1e-5 * abs(float(want[0]))
+    for mine, xla in zip(got[1], want[1]):
+        assert rel(mine, xla) < 1e-5
+    # nothing flows to the entries the solve does not read
+    assert not np.asarray(jnp.triu(got[1][0])).any()
+
+
+def test_no_triangular_solve_is_left_in_the_gradient():
+    """The gradient of the rule under a checkpoint, as a layer's remat
+    takes it, holds no triangular solve: neither XLA's operation nor the
+    LAPACK call the CPU lowers it to (the solve form held four)."""
+    inputs = rule_inputs(192, 1.0)
+    rule = jax.checkpoint(lambda *a: jnp.sum(kda_chunked(*a)))
+    text = jax.jit(jax.grad(rule, argnums=range(5))).lower(*inputs).as_text()
+    assert "while" in text
+    assert "triangular_solve" not in text
+    assert not re.search(r"lapack_\w*trsm", text)
 
 
 def test_the_state_is_f32_and_the_output_in_vs_type():

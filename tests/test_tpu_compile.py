@@ -305,3 +305,24 @@ def test_the_gated_mixed_decoders_step_compiles_for_v5e(v5e, mosaic,
                        f"/{scope}/jit(_bwd_call)/flash.dq",
                        f"/{scope}/jit(_bwd_call)/flash.dkv"):
             assert [p for p in paths if inside + "/pallas_call" in p], inside
+
+
+def test_the_kda_rules_gradient_solves_no_triangle_for_v5e(v5e, monkeypatch):
+    """The chunked KDA rule's gradient under a layer's checkpoint, at the
+    kimi cell's widths (32 heads of 128; two groups of 8 chunks of 64):
+    each chunk's (I + A)^-1 is the Pallas substitution kernel, once in
+    each of the three forward passes, and XLA:TPU keeps no triangular
+    solve and no inversion of its own."""
+    from tpu_mx.models import decoder
+    monkeypatch.setattr(decoder, "_interpret", lambda: False)
+    rule = jax.checkpoint(lambda *a: jnp.sum(
+        decoder.kda_chunked(*a).astype(jnp.float32)))
+    shape = (1, 1024, 32, 128)
+    compiled = _compile(jax.value_and_grad(rule, argnums=range(5)), v5e,
+                        (shape, jnp.bfloat16), (shape, jnp.bfloat16),
+                        (shape, jnp.bfloat16), (shape, jnp.float32),
+                        (shape[:3], jnp.float32))
+    text = compiled.as_text()
+    assert _mosaic_calls(compiled) == 3
+    assert "triangular-solve" not in text
+    assert "InvertDiagBlocksLowerTriangular" not in text

@@ -34,6 +34,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 from ..gluon import nn
 from ..gluon.block import HybridBlock
@@ -410,6 +411,11 @@ _KDA_SUB = 16
 # takes some 1.46 GiB of temporaries at groups of 8 chunks of 64, and 2.03
 # GiB at 16
 _KDA_GROUP = 8
+# chunks a program of the inverse's Pallas call takes.  Mosaic unrolls the
+# substitution over the block's chunks, so its code grows with the block:
+# compiled for a TPU v5e, 0.40 MiB at 8 chunks of 64 and 2.95 MiB at 64, an
+# executable holding twelve of them
+_KDA_INVERSE_BLOCK = 8
 
 
 def _decayed_products(a, b, cum, sub, diagonal):
@@ -423,7 +429,9 @@ def _decayed_products(a, b, cum, sub, diagonal):
     *lead, c, d = a.shape
     n = c // sub
     at = lambda x: x.reshape(*lead, n, sub, d)
-    ref = cum[..., ::sub, :]                                  # (..., n, d)
+    # each sub-chunk's first position; a strided slice here lowers on
+    # XLA:TPU to a gather, and in the gradient to a scatter
+    ref = at(cum)[..., 0, :]                                  # (..., n, d)
     left = at(a) * jnp.exp(at(cum) - ref[..., None, :])
     before = jnp.arange(c)[None, :] < (jnp.arange(n) * sub)[:, None]
     right = b[..., None, :, :] * jnp.exp(jnp.where(
@@ -445,6 +453,73 @@ def _decayed_products(a, b, cum, sub, diagonal):
     return out.reshape(*lead, c, c)
 
 
+def _interpret():
+    return jax.default_backend() != "tpu"
+
+
+def _inverse_kernel(a_ref, t_ref):
+    """(I + A)^-1 for a block of chunks, A (block, C, C) strictly lower:
+    forward substitution a column at a time, every chunk of the block at
+    once.  Once row j of T is final, every later row takes its share of
+    it; row j is 0 past column j, so a step touches the columns up to the
+    next multiple of 8."""
+    c = t_ref.shape[-1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, t_ref.shape, 1)
+    cols = jax.lax.broadcasted_iota(jnp.int32, t_ref.shape, 2)
+    t_ref[...] = (rows == cols).astype(t_ref.dtype)
+    for j in range(c - 1):
+        width = min(c, -(-(j + 1) // 8) * 8)
+        t_ref[:, j + 1:, :width] -= (a_ref[:, j + 1:, j:j + 1]
+                                     * t_ref[:, j:j + 1, :width])
+
+
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for a (..., C, C) f32 strictly lower triangular, in one
+    Pallas call over blocks of `_KDA_INVERSE_BLOCK` chunks, f32 throughout."""
+    *lead, c, _ = a.shape
+    x = a.reshape(-1, c, c)
+    n = x.shape[0]
+    block = min(_KDA_INVERSE_BLOCK, n)
+    x = jnp.pad(x, [(0, -n % block), (0, 0), (0, 0)])
+    spec = pl.BlockSpec((block, c, c), lambda i: (i, 0, 0))
+    t = pl.pallas_call(
+        _inverse_kernel, grid=(x.shape[0] // block,), in_specs=[spec],
+        out_specs=spec, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=_interpret())(x)
+    return t[:n].reshape(*lead, c, c)
+
+
+def _product(t, rhs):
+    return jnp.einsum("...ij,...je->...ie", t, rhs,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+@jax.custom_vjp
+def _unit_lower_solve(a, rhs):
+    """(I + a)^-1 rhs, a (..., C, C) f32 strictly lower triangular: one
+    product with the inverse.  Its gradient is two more products with it,
+    as a triangular solve's is with a transposed solve: d rhs = T^T dx,
+    d a = -(T^T dx) x^T below the diagonal."""
+    return _product(_unit_lower_inverse(a), rhs)
+
+
+def _unit_lower_solve_fwd(a, rhs):
+    t = _unit_lower_inverse(a)
+    x = _product(t, rhs)
+    return x, (t, x)
+
+
+def _unit_lower_solve_bwd(res, dx):
+    t, x = res
+    d_rhs = _product(jnp.swapaxes(t, -1, -2), dx)
+    d_a = -_product(d_rhs, jnp.swapaxes(x, -1, -2))
+    below = jnp.tri(t.shape[-1], k=-1, dtype=bool)
+    return jnp.where(below, d_a, 0.0), d_rhs
+
+
+_unit_lower_solve.defvjp(_unit_lower_solve_fwd, _unit_lower_solve_bwd)
+
+
 def kda_chunked(q, k, v, g, beta, chunk=64):
     """Kimi Delta Attention's recurrence, the gated delta rule with a decay
     for each key channel (arXiv:2510.26692), a head at a time:
@@ -461,8 +536,9 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
     (the backward pass keeps one state a group and recomputes the rest),
     first forms what needs no S for the group's chunks at once: the WY
     form, (I + A) [u0 | w] = [beta v | beta k exp G] with A[i, j] = beta_i
-    sum_d k_i k_j exp(G_i - G_j) for j < i, and P, the same products of q
-    and k for j <= i; every exponent is at most 0 (`_decayed_products`).
+    sum_d k_i k_j exp(G_i - G_j) for j < i, solved as one product with
+    (I + A)^-1 (`_unit_lower_solve`), and P, the same products of q and k
+    for j <= i; every exponent is at most 0 (`_decayed_products`).
     An inner `lax.scan` then carries S from chunk to chunk with four
     products a chunk: u = u0 - w S, o = (q exp G) S + P u, and the state
     it leaves, exp(G_C) S + (k exp(G_C - G))^T u.  Products at the
@@ -497,10 +573,9 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
         qc, kc, vc, gc, bc = (x.astype(jnp.float32) for x in xs)
         cum = jnp.cumsum(gc, axis=-2)
         last, bc = cum[..., -1:, :], bc[..., None]
-        solved = jax.scipy.linalg.solve_triangular(
-            jnp.eye(chunk) + bc * _decayed_products(kc, kc, cum, sub, False),
-            jnp.concatenate([bc * vc, bc * kc * jnp.exp(cum)], axis=-1),
-            lower=True, unit_diagonal=True)
+        solved = _unit_lower_solve(
+            bc * _decayed_products(kc, kc, cum, sub, False),
+            jnp.concatenate([bc * vc, bc * kc * jnp.exp(cum)], axis=-1))
         return jax.lax.scan(across, state, (
             solved[..., :dv], solved[..., dv:],
             _decayed_products(qc, kc, cum, sub, True), qc * jnp.exp(cum),
