@@ -117,7 +117,7 @@ def chunk_system(c, strength, seed=0, heads=6):
                                    aligned=True)
     k, g = (jnp.moveaxis(x[0], 1, 0) for x in (k, g))        # (H, c, d)
     a = beta[0].T[..., None] * decoder._decayed_products(
-        k, k, jnp.cumsum(g, axis=-2), min(16, c), False)
+        k, k, jnp.cumsum(g, axis=-2), min(16, c))[0]
     return a, np.eye(c) + np.asarray(a, np.float64)
 
 
@@ -170,6 +170,222 @@ def test_no_triangular_solve_is_left_in_the_gradient():
     assert "while" in text
     assert "triangular_solve" not in text
     assert not re.search(r"lapack_\w*trsm", text)
+
+
+# -- the within-sub-chunk pairs -----------------------------------------------------------
+def xla_pairs(a, b, cum, diagonal):
+    """The pairwise form the Pallas calls replaced: sum_d a_i b_j
+    exp(cum_i - cum_j) over (..., n, s, d) for j < i (j <= i with
+    `diagonal`), a (..., n, s, s, d) exp of its own, gradient by autodiff."""
+    pos = np.arange(a.shape[-2])
+    keep = pos[:, None] >= pos[None, :] if diagonal \
+        else pos[:, None] > pos[None, :]
+    pair = jnp.exp(jnp.where(keep[:, :, None],
+                             cum[..., :, None, :] - cum[..., None, :, :],
+                             -jnp.inf))
+    return jnp.sum(a[..., :, None, :] * b[..., None, :, :] * pair, axis=-1)
+
+
+def numpy_pairs(a, b, cum, diagonal):
+    a, b, cum = (np.asarray(x, np.float64) for x in (a, b, cum))
+    s = a.shape[-2]
+    out = np.zeros(a.shape[:-1] + (s,))
+    for i in range(s):
+        for j in range(i + 1 if diagonal else i):
+            out[..., i, j] = np.sum(a[..., i, :] * b[..., j, :] * np.exp(
+                cum[..., i, :] - cum[..., j, :]), axis=-1)
+    return out
+
+
+def pair_inputs(case, n=6, d=16, seed=0):
+    """q, k, cum (2, n, 16, d) f32 from `rule_inputs`' draws, the decays
+    summed along each sub-chunk's positions: `nodecay`, `strong` (sums
+    below -100 inside a sub-chunk), `aligned` keys, `padded` (the last
+    sub-chunks zero, as kda_chunked pads its last group), `blocks` (more
+    sub-chunks than one program's block, and no whole number of them)."""
+    strength = {"nodecay": 0.0, "strong": 40.0}.get(case, 1.0)
+    n = 140 if case == "blocks" else n
+    q, k, _, g, _ = rule_inputs(16 * n, strength, seed=seed, b=1, h=2,
+                                dk=d, aligned=case == "aligned")
+    at = lambda x: jnp.moveaxis(x[0], 1, 0).reshape(2, n, 16, d)
+    q, k, g = at(q), at(k), at(g)
+    if case == "padded":
+        live = (jnp.arange(n) < n - 2)[:, None, None]
+        q, k, g = (jnp.where(live, x, 0.0) for x in (q, k, g))
+    return q, k, jnp.cumsum(g, axis=-2)
+
+
+PAIR_CASES = ["nodecay", "strong", "aligned", "padded", "blocks"]
+
+
+def pairwise_forms(q, k, cum):
+    return xla_pairs(k, k, cum, False), xla_pairs(q, k, cum, True)
+
+
+@jax.jit
+def pairs_and_grads(q, k, cum, ca, cp):
+    """(A, P) by the Pallas calls and by the pairwise form, and the
+    gradients of sum(ca A) + sum(cp P) by q, k and cum by each; one
+    program a shape for all the cases of that shape."""
+    loss = lambda fn: lambda *x: jnp.sum(ca * fn(*x)[0]) \
+        + jnp.sum(cp * fn(*x)[1])
+    return [(fn(q, k, cum), jax.grad(loss(fn), (0, 1, 2))(q, k, cum))
+            for fn in (decoder._sub_chunk_pairs, pairwise_forms)]
+
+
+@pytest.mark.parametrize("case", PAIR_CASES)
+def test_the_pairs_are_the_pairwise_forms_and_float64s(case):
+    """Both products of one Pallas call, values and the gradient by q, k
+    and the summed decays, against the pairwise form the call replaced
+    (autodiff, f32) and against float64 NumPy (values, and the gradient
+    along a random direction by central differences)."""
+    q, k, cum = pair_inputs(case)
+    if case == "strong":
+        span = np.asarray(cum[..., -1, :] - cum[..., 0, :])
+        assert span.min() < -100
+    keys = jax.random.split(jax.random.key(7), 5)
+    ca, cp = (jax.random.normal(key, q.shape[:-1] + (16,))
+              for key in keys[:2])
+    (got, grads), (want, ref_grads) = pairs_and_grads(q, k, cum, ca, cp)
+    exact = (numpy_pairs(k, k, cum, False), numpy_pairs(q, k, cum, True))
+    for mine, theirs, f64 in zip(got, want, exact):
+        assert mine.shape == q.shape[:-1] + (16,)
+        assert np.isfinite(np.asarray(mine)).all()
+        assert rel(mine, theirs) < 1e-6 and rel(mine, f64) < 1e-6
+    # nothing on or above the diagonal of A, nothing above it in P
+    upper = np.triu(np.ones((16, 16), bool), 1)
+    assert not np.asarray(got[0])[..., upper | np.eye(16, dtype=bool)].any()
+    assert not np.asarray(got[1])[..., upper].any()
+    for name, mine, theirs in zip("qkG", grads, ref_grads):
+        assert np.isfinite(np.asarray(mine)).all(), name
+        assert rel(mine, theirs) < 1e-5, name
+    f64 = lambda *x: np.sum(np.asarray(ca, np.float64) * numpy_pairs(
+        x[1], x[1], x[2], False)) + np.sum(np.asarray(cp, np.float64)
+                                         * numpy_pairs(*x, True))
+    base = [np.asarray(x, np.float64) for x in (q, k, cum)]
+    for at, key in zip(range(3), keys[2:]):
+        v = np.asarray(jax.random.normal(key, q.shape), np.float64)
+        h = 1e-4
+        up, down = list(base), list(base)
+        up[at], down[at] = base[at] + h * v, base[at] - h * v
+        slope = (f64(*up) - f64(*down)) / (2 * h)
+        mine = np.asarray(grads[at], np.float64)
+        scale = np.linalg.norm(mine) * np.linalg.norm(v)
+        assert abs(np.sum(mine * v) - slope) < 1e-6 * scale, "qkG"[at]
+
+
+@pytest.mark.parametrize("diagonal", [False, True], ids=["strict", "diag"])
+def test_the_decays_gradient_is_a_da_minus_b_db(diagonal):
+    """dW_ij / dG_i = a_i b_j E_ij = -dW_ij / dG_j, so the pairwise form's
+    gradient by the summed decays is a * da - b * db, by autodiff."""
+    q, k, cum = pair_inputs("aligned", seed=3)
+    c = jax.random.normal(jax.random.key(8), q.shape[:-1] + (16,))
+    loss = lambda a, b, g: jnp.sum(c * xla_pairs(a, b, g, diagonal))
+    da, db, dg = jax.grad(loss, (0, 1, 2))(q, k, cum)
+    assert rel(q * da - k * db, dg) < 1e-5
+
+
+def test_the_pairs_take_channels_eight_at_a_time():
+    """A head of 12 channels would leave 4 out of the kernels' sums."""
+    q, k, cum = pair_inputs("aligned", d=12)
+    with pytest.raises(ValueError, match="head_dim 12"):
+        decoder._sub_chunk_pairs(q, k, cum)
+
+
+def test_no_pairwise_decay_is_left_in_the_gradient():
+    """The gradient of the rule under a checkpoint, as a layer's remat
+    takes it, holds no value of (..., 16, 16, d): every pairwise decay is
+    formed inside the Pallas calls (the pairwise form held several)."""
+    inputs = rule_inputs(192, 1.0, dk=24)
+    rule = jax.checkpoint(lambda *a: jnp.sum(kda_chunked(*a)))
+    text = jax.jit(jax.grad(rule, argnums=range(5))).lower(
+        *inputs).as_text()
+    # the kernels' turned blocks, (16, d, 128) a program, are there
+    assert "16x24x128xf32" in text
+    assert not re.search(r"x16x16x24x", text)
+
+
+def test_the_pairs_lie_under_their_scope_inside_the_scan():
+    """`kda.pairs` inside `kda.scan`, forward, recomputed and backward."""
+    block, params, _, _ = kda_block()
+    f = lambda pm, xx: jnp.sum(system_call(block, pm, xx))
+    hlo = jax.jit(jax.grad(f)).lower(params, jnp.ones((2, 32, 16))).compile(
+    ).as_text()
+    paths = set(re.findall(r'op_name="([^"]*kda\.pairs)/', hlo))
+    assert any("jvp(kda.scan)/" in p and "transpose" not in p
+               for p in paths)
+    assert any("transpose(jvp(kda.scan))/" in p
+               and "rematted_computation" not in p for p in paths)
+    assert any("transpose(jvp(kda.scan))/" in p
+               and "rematted_computation" in p for p in paths)
+
+
+def test_the_pairs_literal_is_the_programs(monkeypatch):
+    """A name of its own, no owner's and none of the benchmark's three."""
+    monkeypatch.syspath_prepend(BENCH)
+    literals = _load(os.path.join(BENCH, "kda_scopes.py"),
+                     "kda_scopes_literals")
+    assert decoder.KDA_PAIRS == "kda.pairs"
+    assert decoder.KDA_PAIRS not in KDA_SCOPES + OWNER_SCOPES
+    assert decoder.KDA_PAIRS not in literals.SCOPES
+
+
+def pairs_step():
+    """Two steps of 1000 ns.  In each: the scan's body 30, of which its
+    pairs 8 (a call inside the loop); the projections 40; the scan again
+    under the checkpoint 30, its pairs 6; the scan transposed 60, its
+    pairs' gradient 14; the optimizer 45."""
+    g = "jit(tpumx_train_step)/train_step.grad/"
+    back = g + "transpose(jvp(train_step.grad))/jvp()/checkpoint/"
+    body = "while/body/closed_call/"
+    ops = {"scan": ("convolution fusion", g + "jvp(kda.scan)/" + body
+                    + "dot_general:"),
+           "pairs": ("custom-call", g + "jvp(kda.scan)/" + body
+                     + "kda.pairs/pallas_call:"),
+           "proj": ("convolution fusion", g + "jvp(kda.project)/"
+                    "dot_general:"),
+           "again": ("convolution fusion", back + "rematted_computation/"
+                     "kda.scan/" + body + "dot_general:"),
+           "pairs_again": ("custom-call", back + "rematted_computation/"
+                           "kda.scan/" + body + "kda.pairs/pallas_call:"),
+           "scan_back": ("convolution fusion", back + "transpose(jvp("
+                         "kda.scan))/" + body + "dot_general:"),
+           "pairs_back": ("custom-call", back + "transpose(jvp(kda.scan))/"
+                          + body + "checkpoint/kda.pairs/pallas_call:"),
+           "opt": ("loop fusion", "jit(tpumx_train_step)/"
+                   "train_step.optimizer/add:")}
+    name = {k: f"%{k} = f32[8] fusion(f32[8] %p)" for k in ops}
+    meta = {name[k]: {"hlo_category": c, "tf_op": t}
+            for k, (c, t) in ops.items()}
+    lines = {"XLA Modules": [], "XLA Ops": []}
+    for t0 in (0, 1000):
+        lines["XLA Modules"].append(("jit_tpumx_train_step(1)", t0, 1000))
+        at = t0
+        for k, d in (("scan", 22), ("pairs", 8), ("proj", 40),
+                     ("again", 24), ("pairs_again", 6), ("scan_back", 46),
+                     ("pairs_back", 14), ("opt", 45)):
+            lines["XLA Ops"].append((name[k], at, d))
+            at += d
+    return {"devices": {"/device:TPU:0": {"lines": lines, "meta": meta}},
+            "host": []}
+
+
+def test_the_pairs_scope_reads_its_calls_and_nothing_else(monkeypatch):
+    """The name splits out the pairs' calls, forward, recomputed and
+    backward, and the scan's own reader still takes them in."""
+    monkeypatch.syspath_prepend(BENCH)
+    import pass_scopes
+    import xplane
+    pairs = lambda trace: pass_scopes.scope_ms(trace, (decoder.KDA_PAIRS,))
+    assert pairs(pairs_step()) == pytest.approx(28e-6)
+    scan = _load(os.path.join(BENCH, "layer_metrics", "kda_scan_ms.py"),
+                 "kda_scan_ms_reader")
+    assert scan.read({"trace": pairs_step()}) == pytest.approx(120e-6)
+    # no trace, an empty one, and programs without the name: None, never 0
+    for trace in [None, {"devices": {}, "host": []}] + [
+            xplane.load(os.path.join(BENCH, "fixtures", f + ".xplane.pb.gz"))
+            for f in ("decoder_step", "conv_step")]:
+        assert pairs(trace) is None
 
 
 def test_the_state_is_f32_and_the_output_in_vs_type():

@@ -57,6 +57,12 @@ def _mosaic_calls(compiled):
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _mosaic_paths(compiled):
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
 def test_mosaic_really_runs(v5e):
     """A kernel Mosaic is known to refuse must fail here too — otherwise a
     green run below proves nothing."""
@@ -291,9 +297,7 @@ def test_the_gated_mixed_decoders_step_compiles_for_v5e(v5e, mosaic,
     # ISSUE 36: XLA:TPU keeps the scope round a Mosaic call: every kernel
     # of the step stands under its own name, the forward one again under
     # the checkpoint (chip_smoke.py phase D reads the same from a trace)
-    paths = [re.search(r'op_name="([^"]*)"', line).group(1)
-             for line in compiled.as_text().splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    paths = _mosaic_paths(compiled)
     # (XLA:TPU's own grouped product is such a call too, without a path)
     paths = [p for p in paths if "pallas_call" in p]
     assert len(paths) >= 8 and all(p.endswith("/pallas_call")
@@ -312,7 +316,9 @@ def test_the_kda_rules_gradient_solves_no_triangle_for_v5e(v5e, monkeypatch):
     kimi cell's widths (32 heads of 128; two groups of 8 chunks of 64):
     each chunk's (I + A)^-1 is the Pallas substitution kernel, once in
     each of the three forward passes, and XLA:TPU keeps no triangular
-    solve and no inversion of its own."""
+    solve and no inversion of its own.  Beside them, the within-sub-chunk
+    pairs: one Pallas call a forward pass, one in the backward, each
+    under `kda.pairs`."""
     from tpu_mx.models import decoder
     monkeypatch.setattr(decoder, "_interpret", lambda: False)
     rule = jax.checkpoint(lambda *a: jnp.sum(
@@ -323,6 +329,29 @@ def test_the_kda_rules_gradient_solves_no_triangle_for_v5e(v5e, monkeypatch):
                         (shape, jnp.bfloat16), (shape, jnp.float32),
                         (shape[:3], jnp.float32))
     text = compiled.as_text()
-    assert _mosaic_calls(compiled) == 3
+    paths = _mosaic_paths(compiled)
+    assert len(paths) == _mosaic_calls(compiled) == 7
+    assert len([p for p in paths if "/kda.pairs/" in p]) == 4
     assert "triangular-solve" not in text
     assert "InvertDiagBlocksLowerTriangular" not in text
+
+
+def test_the_kda_pairs_kernels_compile_for_v5e(v5e, monkeypatch):
+    """The within-sub-chunk pairs' two Pallas calls at the kimi cell's
+    group (8 chunks of 64 over 2 sequences and 32 heads of 128: 2,048
+    sub-chunks of 16, 16 programs of 128), forward and backward, in
+    f32."""
+    from tpu_mx.models import decoder
+    monkeypatch.setattr(decoder, "_interpret", lambda: False)
+    shape = (8, 2, 32, 4, 16, 128)
+    loss = lambda *x: sum(jnp.sum(y) for y in decoder._sub_chunk_pairs(*x))
+    compiled = _compile(jax.value_and_grad(loss, argnums=range(3)), v5e,
+                        *[(shape, jnp.float32)] * 3)
+    paths = _mosaic_paths(compiled)
+    # (called alone, the scope is the jvp's and its transpose's)
+    assert len(paths) == 2 and all(
+        re.search(r"\(kda\.pairs\)+/pallas_call$", p) for p in paths)
+    # the inputs and the gradients are the caller's arrays as they lie (a
+    # position a row): no copy or transpose around the calls
+    assert not re.search(r"= f32\[[\d,]+\]\S* (copy|transpose)\(",
+                         compiled.as_text())

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..gluon import nn
 from ..gluon.block import HybridBlock
@@ -84,6 +85,9 @@ _CONV_PROJECT, _CONV_MIX = CONV_SCOPES
 # either
 KDA_SCOPES = ("kda.project", "kda.conv", "kda.scan")
 _KDA_PROJECT, _KDA_CONV, _KDA_SCAN = KDA_SCOPES
+# inside `kda.scan`, the Pallas calls that form the within-sub-chunk pairs
+# (no reader of the benchmark takes this name yet; `kda_scan_ms` holds it)
+KDA_PAIRS = "kda.pairs"
 
 
 def yarn_inv_freq(theta, dim, factor, original_length, beta_fast=32.0,
@@ -418,39 +422,186 @@ _KDA_GROUP = 8
 _KDA_INVERSE_BLOCK = 8
 
 
-def _decayed_products(a, b, cum, sub, diagonal):
-    """M[i, j] = sum_d a[i, d] b[j, d] exp(cum[i, d] - cum[j, d]) over a
-    chunk's C positions for j < i (j <= i with `diagonal`), else 0: a, b,
-    cum (..., C, d) f32, cum not increasing along C.  No exponent exceeds
-    0, so nothing overflows however strong the decay: across sub-chunks of
-    `sub` positions the decay passes through the first position r of i's
-    sub-chunk, exp(cum_i - cum_r) exp(cum_r - cum_j), a product on the MXU;
-    within one it is taken pair by pair."""
-    *lead, c, d = a.shape
+# sub-chunks a program of the pairs' Pallas calls takes, on the lanes
+_KDA_PAIRS_BLOCK = 128
+# key channels the pairs' kernels take a step: one register's sublanes
+_KDA_PAIRS_ROWS = 8
+# the gradient's double-buffered blocks and its scratch take 16.4 MiB at
+# heads of 128, past the 16 MiB a TPU v5e kernel is given by default
+_KDA_PAIRS_VMEM = 32 << 20
+
+
+def _pairs_kernel(q_ref, k_ref, g_ref, a_ref, p_ref, qt, kt, gt, at, pt):
+    """The within-sub-chunk pairs of a block of sub-chunks: q, k, g (block
+    s, d) a position a row, s rows a sub-chunk; a (block, s s) = sum_d k_i
+    k_j exp(g_i - g_j) for j < i, p the same of q_i and k_j for j <= i, 0
+    elsewhere, a sub-chunk a row.  The inputs are turned so that the
+    sub-chunks lie on the lanes and the channels on the sublanes (qt, kt,
+    gt (s, d, block)); for each i the pairs (i, j < i) take one exp
+    between them and sum over the channels eight at a time, and the
+    diagonal of p is q_i k_i."""
+    s, d, block = qt.shape
+    rows = _KDA_PAIRS_ROWS
+    for ref, turned in ((q_ref, qt), (k_ref, kt), (g_ref, gt)):
+        for i in range(s):
+            turned[i] = ref[pl.ds(i, block, stride=s)].T
+    at[...] = jnp.zeros(at.shape, at.dtype)
+    pt[...] = jnp.zeros(pt.shape, pt.dtype)
+    for i in range(s):
+        def channels(c, acc, i=i):
+            ch = pl.ds(pl.multiple_of(c * rows, rows), rows)
+            qi, ki = qt[i, ch], kt[i, ch]
+            if not i:
+                return acc[0] + qi * ki,
+            ke = kt[:i, ch] * jnp.exp(gt[i, ch][None] - gt[:i, ch])
+            return (acc[0] + qi * ki, acc[1] + ki[None] * ke,
+                    acc[2] + qi[None] * ke)
+        pairs = (jnp.zeros((i, rows, block), jnp.float32),) * 2 if i else ()
+        acc = jax.lax.fori_loop(0, d // rows, channels,
+                                (jnp.zeros((rows, block), jnp.float32),)
+                                + pairs)
+        pt[i, i:i + 1] = jnp.sum(acc[0], axis=0, keepdims=True)
+        if i:
+            at[i, :i] = jnp.sum(acc[1], axis=1)
+            pt[i, :i] = jnp.sum(acc[2], axis=1)
+    a_ref[...] = at[...].reshape(s * s, block).T
+    p_ref[...] = pt[...].reshape(s * s, block).T
+
+
+def _pairs_grad_kernel(q_ref, k_ref, g_ref, da_ref, dp_ref, dq_ref, dk_ref,
+                       dg_ref, qt, kt, gt, dat, dpt, dkb, dqi, dkai):
+    """The gradient of `_pairs_kernel`'s a and p by q, k and g, the same
+    blocks and layouts, from the pairs (i, j < i) with E_ij = exp(g_i -
+    g_j): dq_i = sum_j dp_ij k_j E_ij; dk as a_i, sum_j da_ij k_j E_ij; dk
+    as b_j, sum_i (da_ij k_i + dp_ij q_i) E_ij (`dkb`); and since dW_ij /
+    dg_i = a_i b_j E_ij = -dW_ij / dg_j, dg = q dq + k (dk as a_i - dk as
+    b_j).  The diagonal of p adds dp_ii k_i to dq_i and dp_ii q_i to dk_i
+    and nothing to dg.  Rows i from the last, so that dkb_i is whole once
+    i is done."""
+    s, d, block = qt.shape
+    rows = _KDA_PAIRS_ROWS
+    for ref, turned in ((q_ref, qt), (k_ref, kt), (g_ref, gt)):
+        for i in range(s):
+            turned[i] = ref[pl.ds(i, block, stride=s)].T
+    dat[...] = da_ref[...].T.reshape(s, s, block)
+    dpt[...] = dp_ref[...].T.reshape(s, s, block)
+    dkb[...] = jnp.zeros(dkb.shape, dkb.dtype)
+    for i in reversed(range(s)):
+        def channels(c, carry, i=i):
+            ch = pl.ds(pl.multiple_of(c * rows, rows), rows)
+            da, dp = carry
+            e = jnp.exp(gt[i, ch][None] - gt[:i, ch])
+            ke = kt[:i, ch] * e
+            dqi[ch] = jnp.sum(dp * ke, axis=0)
+            dkai[ch] = jnp.sum(da * ke, axis=0)
+            dkb[:i, ch] += (da * kt[i, ch][None] + dp * qt[i, ch][None]) * e
+            return carry
+        if i:
+            spread = lambda x: jnp.broadcast_to(x[:, None, :],
+                                                (i, rows, block))
+            jax.lax.fori_loop(0, d // rows, channels,
+                              (spread(dat[i, :i]), spread(dpt[i, :i])))
+            dq, dka = dqi[...], dkai[...]
+        else:
+            dq = dka = jnp.zeros((d, block), jnp.float32)
+        q, k, diagonal, dkb_i = qt[i], kt[i], dpt[i, i:i + 1], dkb[i]
+        position = pl.ds(i, block, stride=s)
+        dq_ref[position] = (dq + diagonal * k).T
+        dk_ref[position] = (dka + dkb_i + diagonal * q).T
+        dg_ref[position] = (q * dq + k * (dka - dkb_i)).T
+
+
+def _pairs_call(kernel, n, ins, outs, scratch):
+    """One Pallas call over blocks of `_KDA_PAIRS_BLOCK` of n sub-chunks:
+    ins (n r, w), r rows a sub-chunk, padded with zeros to whole blocks;
+    outs the (r, w) of each output, (n r, w) f32; scratch the shapes of the
+    kernel's f32 scratch less their last axis, the block."""
+    block = _KDA_PAIRS_BLOCK
+    padded = -(-n // block) * block
+    spec = lambda r, w: pl.BlockSpec((block * r, w), lambda b: (b, 0))
+    shapes = [(x.shape[0] // n, x.shape[1]) for x in ins]
+    ins = [jnp.pad(x, [(0, (padded - n) * r), (0, 0)])
+           for x, (r, _) in zip(ins, shapes)]
+    ys = pl.pallas_call(
+        kernel, grid=(padded // block,),
+        in_specs=[spec(*shape) for shape in shapes],
+        out_specs=[spec(*shape) for shape in outs],
+        out_shape=[jax.ShapeDtypeStruct((padded * r, w), jnp.float32)
+                   for r, w in outs],
+        scratch_shapes=[pltpu.VMEM(shape + (block,), jnp.float32)
+                        for shape in scratch],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_KDA_PAIRS_VMEM),
+        interpret=_interpret())(*ins)
+    return [y[:n * r] for y, (r, _) in zip(ys, outs)]
+
+
+@jax.custom_vjp
+def _sub_chunk_pairs(q, k, cum):
+    """(A, P) within each sub-chunk: q, k, cum (..., n, s, d) f32, cum not
+    increasing along s; A[i, j] = sum_d k_i k_j exp(cum_i - cum_j) for
+    j < i and P[i, j] = sum_d q_i k_j exp(cum_i - cum_j) for j <= i
+    (..., n, s, s), 0 elsewhere.  One Pallas call forms both from one exp
+    a pair, every exponent at most 0, f32 throughout; the gradient is one
+    more."""
+    *lead, s, d = q.shape
+    if d % _KDA_PAIRS_ROWS:
+        raise ValueError(f"head_dim {d}: a multiple of {_KDA_PAIRS_ROWS}")
+    with jax.named_scope(KDA_PAIRS):
+        a, p = _pairs_call(_pairs_kernel, q.size // (s * d),
+                           [x.reshape(-1, d) for x in (q, k, cum)],
+                           [(1, s * s)] * 2, [(s, d)] * 3 + [(s, s)] * 2)
+        return a.reshape(*lead, s, s), p.reshape(*lead, s, s)
+
+
+def _sub_chunk_pairs_fwd(q, k, cum):
+    return _sub_chunk_pairs(q, k, cum), (q, k, cum)
+
+
+def _sub_chunk_pairs_bwd(res, cts):
+    *lead, s, d = res[0].shape
+    with jax.named_scope(KDA_PAIRS):
+        grads = _pairs_call(
+            _pairs_grad_kernel, res[0].size // (s * d),
+            [x.reshape(-1, d) for x in res]
+            + [x.reshape(-1, s * s) for x in cts], [(s, d)] * 3,
+            [(s, d)] * 3 + [(s, s)] * 2 + [(s, d), (d,), (d,)])
+        return tuple(x.reshape(*lead, s, d) for x in grads)
+
+
+_sub_chunk_pairs.defvjp(_sub_chunk_pairs_fwd, _sub_chunk_pairs_bwd)
+
+
+def _decayed_products(q, k, cum, sub):
+    """(A, P) over a chunk's C positions: A[i, j] = sum_d k[i, d] k[j, d]
+    exp(cum[i, d] - cum[j, d]) for j < i, P[i, j] the same of q_i and k_j
+    for j <= i, else 0: q, k, cum (..., C, d) f32, cum not increasing
+    along C.  No exponent exceeds 0, so nothing overflows however strong
+    the decay: across sub-chunks of `sub` positions the decay passes
+    through the first position r of i's sub-chunk, exp(cum_i - cum_r)
+    exp(cum_r - cum_j), a product on the MXU; within one it is taken pair
+    by pair (`_sub_chunk_pairs`)."""
+    *lead, c, d = k.shape
     n = c // sub
     at = lambda x: x.reshape(*lead, n, sub, d)
     # each sub-chunk's first position; a strided slice here lowers on
     # XLA:TPU to a gather, and in the gradient to a scatter
     ref = at(cum)[..., 0, :]                                  # (..., n, d)
-    left = at(a) * jnp.exp(at(cum) - ref[..., None, :])
     before = jnp.arange(c)[None, :] < (jnp.arange(n) * sub)[:, None]
-    right = b[..., None, :, :] * jnp.exp(jnp.where(
+    right = k[..., None, :, :] * jnp.exp(jnp.where(
         before[:, :, None], ref[..., :, None, :] - cum[..., None, :, :],
         -jnp.inf))                                            # (..., n, C, d)
-    across = jnp.einsum("...asd,...acd->...asc", left, right,
-                        precision=jax.lax.Precision.HIGHEST)
-    pos = jnp.arange(sub)
-    keep = pos[:, None] >= pos[None, :] if diagonal \
-        else pos[:, None] > pos[None, :]
-    ca = at(cum)
-    pair = jnp.exp(jnp.where(keep[:, :, None],
-                             ca[..., :, None, :] - ca[..., None, :, :],
-                             -jnp.inf))                       # (..., n, s, s, d)
-    within = jnp.sum(at(a)[..., :, None, :] * at(b)[..., None, :, :] * pair,
-                     axis=-1)                                 # (..., n, s, s)
-    out = across.reshape(*lead, n, sub, n, sub) \
-        + within[..., :, :, None, :] * jnp.eye(n)[:, None, :, None]
-    return out.reshape(*lead, c, c)
+    eye = jnp.eye(n)[:, None, :, None]
+
+    def whole(a, within):
+        left = at(a) * jnp.exp(at(cum) - ref[..., None, :])
+        across = jnp.einsum("...asd,...acd->...asc", left, right,
+                            precision=jax.lax.Precision.HIGHEST)
+        out = across.reshape(*lead, n, sub, n, sub) \
+            + within[..., :, :, None, :] * eye
+        return out.reshape(*lead, c, c)
+    within_a, within_p = _sub_chunk_pairs(at(q), at(k), at(cum))
+    return whole(k, within_a), whole(q, within_p)
 
 
 def _interpret():
@@ -538,7 +689,8 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
     form, (I + A) [u0 | w] = [beta v | beta k exp G] with A[i, j] = beta_i
     sum_d k_i k_j exp(G_i - G_j) for j < i, solved as one product with
     (I + A)^-1 (`_unit_lower_solve`), and P, the same products of q and k
-    for j <= i; every exponent is at most 0 (`_decayed_products`).
+    for j <= i; every exponent is at most 0 (`_decayed_products`; the pairs
+    within a sub-chunk of both from one Pallas call, `_sub_chunk_pairs`).
     An inner `lax.scan` then carries S from chunk to chunk with four
     products a chunk: u = u0 - w S, o = (q exp G) S + P u, and the state
     it leaves, exp(G_C) S + (k exp(G_C - G))^T u.  Products at the
@@ -573,12 +725,13 @@ def kda_chunked(q, k, v, g, beta, chunk=64):
         qc, kc, vc, gc, bc = (x.astype(jnp.float32) for x in xs)
         cum = jnp.cumsum(gc, axis=-2)
         last, bc = cum[..., -1:, :], bc[..., None]
+        a, p = _decayed_products(qc, kc, cum, sub)
         solved = _unit_lower_solve(
-            bc * _decayed_products(kc, kc, cum, sub, False),
+            bc * a,
             jnp.concatenate([bc * vc, bc * kc * jnp.exp(cum)], axis=-1))
         return jax.lax.scan(across, state, (
             solved[..., :dv], solved[..., dv:],
-            _decayed_products(qc, kc, cum, sub, True), qc * jnp.exp(cum),
+            p, qc * jnp.exp(cum),
             kc * jnp.exp(last - cum), jnp.exp(last[..., 0, :])))
 
     state = jnp.zeros((b, h, dk, dv), jnp.float32)
